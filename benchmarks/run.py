@@ -27,6 +27,8 @@ def _parse_row(row: str) -> dict:
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_efficiency, bench_kernels, bench_network,
                             bench_PR4, bench_PR5, bench_PR6, bench_PR7,
                             bench_PR8, bench_PR9, bench_PR10,

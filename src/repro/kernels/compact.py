@@ -3,9 +3,10 @@
 Turns a 0/1 keep mask into the write position of every record (``pos[i] =
 number of kept records before i``) plus the total kept count, in ONE
 sequential HBM pass: the TPU grid walks record tiles in order, each step
-computing the tile-local exclusive cumsum (lane-wise ``cumsum`` + row
-offsets) and adding the running carry held in SMEM scratch — the classic
-single-pass scan-with-carry, no second kernel launch and no host round-trip.
+computing the tile-local exclusive prefix sum (:func:`tile_prefix_sum`, an
+exact log-step scan) and adding the running carry held in SMEM scratch —
+the classic single-pass scan-with-carry, no second kernel launch and no
+host round-trip. Totals leave as lane-dense ``(1, 128)`` blocks.
 
 The caller turns positions into gathered kept-record *indices* with one XLA
 scatter (``zeros.at[pos[kept]].set(iota)``, see :func:`repro.kernels.ops.
@@ -16,11 +17,12 @@ bandwidth pass and keeps the whole NSA chain on device.
 Layout mirrors the other kernels: records padded to a multiple of the
 (8, 128) tile; padded entries must carry mask ``0``.
 
-``compact_positions_batched_pallas`` lifts the same scan to a 2-D
-``(row, record-tile)`` grid — the carry resets at each row's first tile
-(the :mod:`repro.kernels.trend_scan` pattern), so R rows' keep masks
-compact in ONE dispatch with per-row totals. This is the compaction leg of
-the range-padded NSA sweep: every (dataset × max_range) scenario is a row.
+The kernel's grid is 2-D, ``(row, record-tile)`` — the carry resets at
+each row's first tile (the :mod:`repro.kernels.trend_scan` pattern), so
+``compact_positions_batched_pallas`` compacts R rows' keep masks in ONE
+dispatch with per-row totals, and ``compact_positions_pallas`` is its
+one-row case. This is the compaction leg of the range-padded NSA sweep:
+every (dataset × max_range) scenario is a row.
 """
 
 from __future__ import annotations
@@ -40,25 +42,44 @@ SUBLANE = 8
 TILE = LANE * SUBLANE  # records per grid step with the default TileConfig
 
 
-def _kernel(mask_ref, pos_ref, total_ref, carry_ref):
-    i = pl.program_id(0)
+def tile_prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of a ``(sublane, LANE)`` int32 tile in
+    row-major record order, exact.
 
-    @pl.when(i == 0)
-    def _init():
+    Hillis-Steele log-step scan: 7 shifted adds along the lanes
+    (``pltpu.roll`` plus an iota mask that drops the wrapped-around
+    entries), then the row totals broadcast across lanes and scan the same
+    way along the sublanes (3 steps for 8 rows) into per-row offsets.
+    Integer adds only, so the result is exact — Mosaic has no ``cumsum``
+    lowering, and a matmul form would round on the MXU's f32 passes."""
+    sublane = x.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < LANE:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0)
+        shift *= 2
+    row_tot = jnp.broadcast_to(x[:, LANE - 1:], x.shape)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    off = row_tot
+    shift = 1
+    while shift < sublane:
+        off = off + jnp.where(row >= shift, pltpu.roll(off, shift, 0), 0)
+        shift *= 2
+    return x + off - row_tot                         # exclusive row offsets
+
+
+def _kernel(mask_ref, pos_ref, total_ref, carry_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _reset():                                    # new row: fresh carry
         carry_ref[0] = 0
 
-    m = mask_ref[...].astype(jnp.int32)              # (SUBLANE, LANE) 0/1
-    # tile-local exclusive cumsum in row-major record order:
-    # lane-wise inclusive scan, then per-row offsets from the row totals
-    row_incl = jnp.cumsum(m, axis=1)
-    row_tot = row_incl[:, -1:]
-    row_off = jnp.cumsum(row_tot, axis=0) - row_tot  # exclusive over rows
-    excl = row_incl - m + row_off
-
+    m = mask_ref[...].astype(jnp.int32)              # (sublane, LANE) 0/1
     carry = carry_ref[0]
-    pos_ref[...] = carry + excl
-    carry_ref[0] = carry + jnp.sum(m)
-    total_ref[0] = carry_ref[0]                      # last grid step wins
+    pos_ref[...] = carry + tile_prefix_sum(m) - m
+    total = carry + jnp.sum(m)
+    carry_ref[0] = total
+    # lane-dense total block; the row's last tile writes the final value
+    total_ref[...] = jnp.full(total_ref.shape, total, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "config"))
@@ -68,50 +89,12 @@ def compact_positions_pallas(mask: jnp.ndarray, *, interpret: bool = False,
 
     Returns ``(pos int32 (n,), total int32 (1,))`` where ``pos[i]`` is the
     exclusive prefix sum of the mask (the output slot of record ``i`` if it
-    is kept) and ``total`` the number of set mask entries.
+    is kept) and ``total`` the number of set mask entries — the one-row case
+    of :func:`compact_positions_batched_pallas`.
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
-    n = mask.shape[0]
-    assert n % cfg.record_tile == 0, \
-        f"pad records to a multiple of {cfg.record_tile}"
-    rows = n // LANE
-    m2 = mask.reshape(rows, LANE)
-    grid = (rows // sublane,)
-    pos, total = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((sublane, LANE), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((sublane, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(m2)
-    return pos.reshape(n), total
-
-
-def _kernel_batched(mask_ref, pos_ref, total_ref, carry_ref):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _reset():                                    # new row: fresh carry
-        carry_ref[0] = 0
-
-    m = mask_ref[0].astype(jnp.int32)                # (SUBLANE, LANE) 0/1
-    row_incl = jnp.cumsum(m, axis=1)
-    row_tot = row_incl[:, -1:]
-    row_off = jnp.cumsum(row_tot, axis=0) - row_tot  # exclusive over rows
-
-    carry = carry_ref[0]
-    pos_ref[0] = carry + row_incl - m + row_off
-    carry_ref[0] = carry + jnp.sum(m)
-    total_ref[0, 0] = carry_ref[0]                   # row's last tile wins
+    pos, totals = compact_positions_batched_pallas(
+        mask[None, :], interpret=interpret, config=config)
+    return pos[0], totals[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "config"))
@@ -136,19 +119,18 @@ def compact_positions_batched_pallas(mask: jnp.ndarray, *,
     rows = n // LANE
     m3 = mask.reshape(R, rows, LANE)
     grid = (R, rows // sublane)
+    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda r, i: (r, i, 0))
     pos, totals = pl.pallas_call(
-        _kernel_batched,
+        _kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, sublane, LANE), lambda r, i: (r, i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, sublane, LANE), lambda r, i: (r, i, 0)),
-            pl.BlockSpec((1, 1), lambda r, i: (r, 0)),
-        ],
+        in_specs=[tile_spec],
+        out_specs=[tile_spec,
+                   pl.BlockSpec((None, 1, LANE), lambda r, i: (r, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((R, rows, LANE), jnp.int32),
-            jax.ShapeDtypeStruct((R, 1), jnp.int32),
+            jax.ShapeDtypeStruct((R, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
     )(m3)
-    return pos.reshape(R, n), totals
+    return pos.reshape(R, n), totals[:, 0, :1]

@@ -1,4 +1,4 @@
-"""Pallas GPU (Triton) lowerings of the scan/accumulate stream kernels.
+"""Pallas GPU (Triton) lowerings of the stream kernels.
 
 The TPU kernels lean on two TPU-only guarantees: a SEQUENTIAL grid (later
 grid steps observe earlier steps' writes to the same output block — the
@@ -21,9 +21,10 @@ Contracts match the TPU kernels':
 - pair stats: one whole-axis f32 matmul per instance (vs. the TPU
   tile-accumulated MXU walk) — inside the documented 1e-3 tolerance.
 
-``stream_sample`` needs no lowering: its grid steps are independent (each
-reads and writes only its own record tile), so the TPU kernel compiles
-unchanged on GPU and :mod:`repro.kernels.ops` dispatches it directly.
+- stream sample: the same grid-parallel kernel body (each step reads and
+  writes only its own record tile), with the bucket tables as ordinary
+  row blocks read by ``jnp.take`` instead of the TPU kernel's SMEM walk —
+  scale stamps and keep mask bit-identical.
 
 Off-GPU these kernels still run under ``interpret=True`` — that is how the
 CPU test tier validates the lowering logic without the hardware
@@ -45,6 +46,76 @@ _GPU_BACKENDS = ("gpu", "cuda", "rocm")
 def _interp() -> bool:
     """interpret=True everywhere except a real GPU backend."""
     return jax.default_backend() not in _GPU_BACKENDS
+
+
+# ----------------------------------------------------------- stream sample
+_LANE = 128
+
+
+def _sample_kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
+                   keep_ref, *, sublane: int):
+    i = pl.program_id(1)
+    t = t_ref[0]                                  # (sublane, LANE) f32
+    t_min = scalar_ref[0, 0]
+    inv_span = scalar_ref[0, 1]
+    nb_f = scalar_ref[0, 2]
+    nb = nb_f.astype(jnp.int32)
+    starts, counts, ktab = starts_ref[0], counts_ref[0], k_ref[0]
+
+    g = jnp.floor((t - t_min) * inv_span * nb_f).astype(jnp.int32)
+    g = jnp.clip(g, 0, nb - 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (sublane, _LANE), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (sublane, _LANE), 1)
+    gidx = i * sublane * _LANE + row * _LANE + col
+
+    s_g = jnp.take(starts, g, axis=0)
+    c_g = jnp.take(counts, g, axis=0)
+    g = g + (gidx >= s_g + c_g).astype(jnp.int32) \
+          - (gidx < s_g).astype(jnp.int32)
+    ss = jnp.clip(g, 0, nb - 1)
+
+    start = jnp.take(starts, ss, axis=0)
+    c = jnp.take(counts, ss, axis=0)
+    k = jnp.take(ktab, ss, axis=0)
+    keep = ((gidx - start) * k) % jnp.maximum(c, 1) < k
+    ss_ref[0] = ss
+    keep_ref[0] = keep.astype(jnp.int32)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("max_range", "sublane", "interpret"))
+def stream_sample_gpu(t: jnp.ndarray, starts: jnp.ndarray,
+                      counts: jnp.ndarray, ktab: jnp.ndarray,
+                      scalars: jnp.ndarray, max_range: int, *,
+                      sublane: int = 8, interpret: bool = None):
+    """Grid-parallel fused NSA inner loop: (S, N) rebased timestamps plus
+    (S, max_range) tables and (S, 3) scalars -> (scale_stamp int32 (S, N),
+    keep int32 (S, N)) — the
+    :func:`repro.kernels.stream_sample.stream_sample_pallas` contract."""
+    if interpret is None:
+        interpret = _interp()
+    S, n = t.shape
+    assert n % (sublane * _LANE) == 0
+    rows = n // _LANE
+    tile_spec = pl.BlockSpec((1, sublane, _LANE), lambda s, i: (s, i, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((1, width), lambda s, i: (s, 0))
+
+    ss, keep = pl.pallas_call(
+        functools.partial(_sample_kernel, sublane=sublane),
+        grid=(S, rows // sublane),
+        in_specs=[tile_spec, row_spec(max_range), row_spec(max_range),
+                  row_spec(max_range), row_spec(3)],
+        out_specs=[tile_spec, tile_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((S, rows, _LANE), jnp.int32),
+            jax.ShapeDtypeStruct((S, rows, _LANE), jnp.int32),
+        ],
+        interpret=interpret,
+    )(t.reshape(S, rows, _LANE).astype(jnp.float32), starts, counts, ktab,
+      scalars.astype(jnp.float32))
+    return ss.reshape(S, n), keep.reshape(S, n)
 
 
 # ----------------------------------------------------------------- compact
@@ -299,5 +370,5 @@ def pair_stats_gpu(x: jnp.ndarray, *, interpret: bool = None):
 __all__ = [
     "compact_positions_batched_gpu", "compact_positions_gpu",
     "pair_stats_gpu", "stream_metrics_carry_gpu", "stream_metrics_gpu",
-    "trend_scan_carry_gpu", "trend_scan_gpu",
+    "stream_sample_gpu", "trend_scan_carry_gpu", "trend_scan_gpu",
 ]

@@ -29,12 +29,16 @@ Grid ``(stream, record-tile)`` — the same 2-D layout as
 histogram accumulates directly in the per-stream output block (int32 — counts
 are exact up to 2³¹, enforced by the ops wrapper), which stays VMEM-resident
 across the record-tile axis because its index map ignores the tile index.
+The histogram is laid out ``(S, 1, buckets)`` and the moments ``(S, 1,
+128)`` (lanes 0.. hold the values), so every block's last two dimensions
+equal the array's and every store is a whole vector.
 
 The bucket axis is processed in LANE-multiple blocks of ``BUCKET_BLOCK``
 inside the kernel, so the one-hot intermediate is a bounded
-``(TILE, BUCKET_BLOCK)`` tile no matter how large ``max_range`` is —
-``max_range`` up to the full 86 400-second day fits comfortably
-(86 528 int32 ≈ 340 KiB for the resident histogram block).
+``(LANE, BUCKET_BLOCK)`` tile per record column no matter how large
+``max_range`` is — ``max_range`` up to the full 86 400-second day fits
+comfortably (86 528 int32 ≈ 340 KiB for the resident histogram block). The
+record tile is transposed once so that records run down the sublanes.
 
 Cost is data-adaptive: scale stamps are non-decreasing (Min-Max normalize is
 monotone and streams are chronological), so each record tile spans a narrow
@@ -75,65 +79,92 @@ TILE = LANE * SUBLANE      # records per grid step (default TileConfig)
 BUCKET_BLOCK = 4 * LANE    # bucket columns per inner step (default config)
 
 
-def _kernel(ss_ref, hist_ref, mom_ref, *, buckets: int, sublane: int,
-            bucket_block: int):
+def _accumulate_hist(ss_ref, hist_ref, *, buckets: int, bucket_block: int):
+    """Fold one record tile's stamps into the resident ``(1, buckets)``
+    histogram block.
+
+    The tile is transposed once so records run down the sublanes; each of
+    its columns then compares against a lane-dense ``(LANE, bucket_block)``
+    iota of bucket ids, and the one-hot sums straight into the block's
+    lane slice. A ``fori_loop`` with traced bounds walks only the bucket
+    blocks the tile's stamps touch."""
     i = pl.program_id(1)
-    num_tiles = pl.num_programs(1)
 
     @pl.when(i == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
-        mom_ref[...] = jnp.zeros_like(mom_ref)
 
-    tile = sublane * LANE
-    ss = ss_ref[0].reshape(tile)                     # (tile,) int32
+    ss = ss_ref[...]                                 # (sublane, LANE) int32
     valid = ss < buckets                             # padding id >= buckets
 
     # data-adaptive bucket-block range: sorted stamps => a tile spans few
     # blocks; an all-padding tile runs zero iterations
     lo = jnp.min(jnp.where(valid, ss, buckets - 1)) // bucket_block
     hi = jnp.max(jnp.where(valid, ss, 0)) // bucket_block
-    upper = jnp.where(jnp.any(valid), hi + 1, lo)
+    upper = jnp.where(jnp.sum(valid.astype(jnp.int32)) > 0, hi + 1, lo)
+    cols = ss.T                                      # (LANE, sublane)
 
     def body(blk, carry):
-        base = blk * bucket_block
+        base = pl.multiple_of(blk * bucket_block, LANE)
         ids = base + jax.lax.broadcasted_iota(
-            jnp.int32, (tile, bucket_block), 1)
-        partial = jnp.sum((ss[:, None] == ids).astype(jnp.int32), axis=0,
-                          keepdims=True)             # (1, bucket_block) int32
-        cur = hist_ref[:, pl.ds(base, bucket_block)]
-        hist_ref[:, pl.ds(base, bucket_block)] = cur + partial
+            jnp.int32, (LANE, bucket_block), 1)
+        partial = jnp.zeros((1, bucket_block), jnp.int32)
+        for c in range(cols.shape[1]):
+            partial += jnp.sum((cols[:, c:c + 1] == ids).astype(jnp.int32),
+                               axis=0, keepdims=True)
+        hist_ref[:, pl.ds(base, bucket_block)] += partial
         return carry
 
     jax.lax.fori_loop(lo, upper, body, 0)
 
-    @pl.when(i == num_tiles - 1)
-    def _moments():
-        # pairwise-block + Kahan summation: each BUCKET_BLOCK slice reduces
-        # to one f32 partial (error ~ O(log BLOCK) ulp), and the partials
-        # accumulate through compensated addition — so the total error is
-        # independent of the bucket-axis length instead of growing O(B)·eps
-        # with a naive running f32 sum (a day-long axis has B = 86 400).
-        # Tightens the engine's moment agreement from ~1e-3 to ~1e-5.
-        def kahan(blk, carry):
-            s1, c1, s2, c2 = carry
-            q = hist_ref[:, pl.ds(blk * bucket_block, bucket_block)] \
-                .astype(jnp.float32)                 # padding buckets are 0
-            y1 = jnp.sum(q) - c1
-            t1 = s1 + y1
-            y2 = jnp.sum(q * q) - c2
-            t2 = s2 + y2
-            return t1, (t1 - s1) - y1, t2, (t2 - s2) - y2
 
-        zero = jnp.float32(0.0)
-        s1, _, s2, _ = jax.lax.fori_loop(
-            0, buckets // bucket_block, kahan, (zero, zero, zero, zero))
-        mom_ref[0, 0] = s1
-        mom_ref[0, 1] = s2
+def _kahan_fold(hist_ref, init, *, buckets: int, bucket_block: int):
+    """Pairwise-block + Kahan summation of ``[Σq, Σq²]`` over the resident
+    histogram: each ``bucket_block`` slice reduces to one f32 partial
+    (error ~ O(log BLOCK) ulp) and the partials accumulate through
+    compensated addition, so the total error is independent of the
+    bucket-axis length instead of growing O(B)·eps with a naive running f32
+    sum (a day-long axis has B = 86 400). ``init`` and the returned state
+    are ``(s1, c1, s2, c2)``, each a ``(1, 1)`` f32 vector."""
+    def kahan(blk, carry):
+        s1, c1, s2, c2 = carry
+        base = pl.multiple_of(blk * bucket_block, LANE)
+        q = hist_ref[:, pl.ds(base, bucket_block)] \
+            .astype(jnp.float32)                     # padding buckets are 0
+        y1 = jnp.sum(q, axis=1, keepdims=True) - c1
+        t1 = s1 + y1
+        y2 = jnp.sum(q * q, axis=1, keepdims=True) - c2
+        t2 = s2 + y2
+        return t1, (t1 - s1) - y1, t2, (t2 - s2) - y2
+
+    return jax.lax.fori_loop(0, buckets // bucket_block, kahan, init)
+
+
+def _lanes(mom_ref, values):
+    """Write ``values`` (``(1, 1)`` vectors) into lanes 0.. of the
+    lane-dense ``(1, LANE)`` moment block, zeros elsewhere."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, mom_ref.shape, 1)
+    out = jnp.zeros(mom_ref.shape, jnp.float32)
+    for j, v in enumerate(values):
+        out = jnp.where(lane == j, v, out)
+    mom_ref[...] = out
+
+
+def _kernel(ss_ref, hist_ref, mom_ref, *, buckets: int, bucket_block: int):
+    _accumulate_hist(ss_ref, hist_ref, buckets=buckets,
+                     bucket_block=bucket_block)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _moments():
+        zero = jnp.zeros((1, 1), jnp.float32)
+        s1, _, s2, _ = _kahan_fold(hist_ref, (zero, zero, zero, zero),
+                                   buckets=buckets,
+                                   bucket_block=bucket_block)
+        _lanes(mom_ref, (s1, s2))
 
 
 def _kernel_carry(ss_ref, mcar_ref, hist_ref, mom_ref, *, buckets: int,
-                  sublane: int, bucket_block: int):
+                  bucket_block: int):
     """Chunked variant of :func:`_kernel`: the final moment reduction seeds
     its pairwise+Kahan fold from a per-row carry-in ``[s1, c1, s2, c2]`` and
     emits the UPDATED 4-state instead of the bare ``[Σq, Σq²]`` pair, so
@@ -142,53 +173,15 @@ def _kernel_carry(ss_ref, mcar_ref, hist_ref, mom_ref, *, buckets: int,
     the compensation terms keeps the error O(1) ulp over any number of
     chunks). With a zero carry the fold is bit-identical to
     :func:`_kernel`'s."""
-    i = pl.program_id(1)
-    num_tiles = pl.num_programs(1)
+    _accumulate_hist(ss_ref, hist_ref, buckets=buckets,
+                     bucket_block=bucket_block)
 
-    @pl.when(i == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        mom_ref[...] = jnp.zeros_like(mom_ref)
-
-    tile = sublane * LANE
-    ss = ss_ref[0].reshape(tile)                     # (tile,) int32
-    valid = ss < buckets                             # padding id >= buckets
-
-    lo = jnp.min(jnp.where(valid, ss, buckets - 1)) // bucket_block
-    hi = jnp.max(jnp.where(valid, ss, 0)) // bucket_block
-    upper = jnp.where(jnp.any(valid), hi + 1, lo)
-
-    def body(blk, carry):
-        base = blk * bucket_block
-        ids = base + jax.lax.broadcasted_iota(
-            jnp.int32, (tile, bucket_block), 1)
-        partial = jnp.sum((ss[:, None] == ids).astype(jnp.int32), axis=0,
-                          keepdims=True)             # (1, bucket_block) int32
-        cur = hist_ref[:, pl.ds(base, bucket_block)]
-        hist_ref[:, pl.ds(base, bucket_block)] = cur + partial
-        return carry
-
-    jax.lax.fori_loop(lo, upper, body, 0)
-
-    @pl.when(i == num_tiles - 1)
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _moments():
-        def kahan(blk, carry):
-            s1, c1, s2, c2 = carry
-            q = hist_ref[:, pl.ds(blk * bucket_block, bucket_block)] \
-                .astype(jnp.float32)                 # padding buckets are 0
-            y1 = jnp.sum(q) - c1
-            t1 = s1 + y1
-            y2 = jnp.sum(q * q) - c2
-            t2 = s2 + y2
-            return t1, (t1 - s1) - y1, t2, (t2 - s2) - y2
-
-        s1, c1, s2, c2 = jax.lax.fori_loop(
-            0, buckets // bucket_block, kahan,
-            (mcar_ref[0, 0], mcar_ref[0, 1], mcar_ref[0, 2], mcar_ref[0, 3]))
-        mom_ref[0, 0] = s1
-        mom_ref[0, 1] = c1
-        mom_ref[0, 2] = s2
-        mom_ref[0, 3] = c2
+        mcar = mcar_ref[...]                         # (1, LANE) f32
+        init = tuple(mcar[:, j:j + 1] for j in range(4))
+        _lanes(mom_ref, _kahan_fold(hist_ref, init, buckets=buckets,
+                                    bucket_block=bucket_block))
 
 
 @functools.partial(jax.jit,
@@ -221,25 +214,28 @@ def stream_metrics_carry_pallas(ss: jnp.ndarray, mcar: jnp.ndarray,
     rows = n // LANE
     ss3 = ss.reshape(S, rows, LANE)
     grid = (S, rows // sublane)
+    mcar3 = jnp.zeros((S, 1, LANE), jnp.float32).at[:, 0, :4].set(
+        mcar.astype(jnp.float32))
+    row_spec = pl.BlockSpec((None, 1, LANE), lambda s, i: (s, 0, 0))
     hist, mom = pl.pallas_call(
-        functools.partial(_kernel_carry, buckets=buckets, sublane=sublane,
+        functools.partial(_kernel_carry, buckets=buckets,
                           bucket_block=cfg.bucket_block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, 4), lambda s, i: (s, 0)),
+            pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0)),
+            row_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, buckets), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, 4), lambda s, i: (s, 0)),
+            pl.BlockSpec((None, 1, buckets), lambda s, i: (s, 0, 0)),
+            row_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((S, buckets), jnp.int32),
-            jax.ShapeDtypeStruct((S, 4), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, buckets), jnp.int32),
+            jax.ShapeDtypeStruct((S, 1, LANE), jnp.float32),
         ],
         interpret=interpret,
-    )(ss3, mcar.astype(jnp.float32))
-    return hist, mom
+    )(ss3, mcar3)
+    return hist[:, 0], mom[:, 0, :4]
 
 
 @functools.partial(jax.jit,
@@ -267,18 +263,19 @@ def stream_metrics_pallas(ss: jnp.ndarray, buckets: int, *,
     ss3 = ss.reshape(S, rows, LANE)
     grid = (S, rows // sublane)
     hist, mom = pl.pallas_call(
-        functools.partial(_kernel, buckets=buckets, sublane=sublane,
+        functools.partial(_kernel, buckets=buckets,
                           bucket_block=cfg.bucket_block),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0))],
+        in_specs=[pl.BlockSpec((None, sublane, LANE),
+                               lambda s, i: (s, i, 0))],
         out_specs=[
-            pl.BlockSpec((1, buckets), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, 2), lambda s, i: (s, 0)),
+            pl.BlockSpec((None, 1, buckets), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((None, 1, LANE), lambda s, i: (s, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((S, buckets), jnp.int32),
-            jax.ShapeDtypeStruct((S, 2), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, buckets), jnp.int32),
+            jax.ShapeDtypeStruct((S, 1, LANE), jnp.float32),
         ],
         interpret=interpret,
     )(ss3)
-    return hist, mom
+    return hist[:, 0], mom[:, 0, :2]

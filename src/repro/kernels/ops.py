@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -38,10 +39,10 @@ def on_tpu() -> bool:
 def on_gpu() -> bool:
     """True on any CUDA/ROCm device — the Pallas GPU lowering path.
 
-    The scan/accumulate kernels rely on TPU's sequential grid and are
-    rerouted to the row-parallel lowerings in
-    :mod:`repro.kernels.gpu_lowering`; ``stream_sample`` (whose grid
-    steps are independent) compiles unchanged.
+    Every kernel is rerouted to its lowering in
+    :mod:`repro.kernels.gpu_lowering`: the scan/accumulate kernels rely
+    on TPU's sequential grid, and ``stream_sample`` reads its tables from
+    TPU scalar memory.
     """
     return jax.default_backend() in ("gpu", "cuda", "rocm")
 
@@ -76,6 +77,20 @@ class KeepRuleOverflow(PallasDomainError):
     """
 
 
+class HostFallbackWarning(RuntimeWarning):
+    """A device-path call ran on the host because an input lies outside
+    the kernels' exactness domain (see :class:`PallasDomainError`)."""
+
+
+def warn_host_fallback(stage: str, err: PallasDomainError) -> None:
+    """Announce a host fallback: ``stage`` names what fell back, ``err``
+    the out-of-domain input that forced it. Every caller that catches a
+    :class:`PallasDomainError` and reruns on the host warns through here,
+    so a device run never turns into a host run unnoticed."""
+    warnings.warn(f"{stage} falls back to the host path: {err}",
+                  HostFallbackWarning, stacklevel=3)
+
+
 def _pad_to(x: jnp.ndarray, mult: int, value) -> Tuple[jnp.ndarray, int]:
     n = x.shape[0]
     pad = (-n) % mult
@@ -103,7 +118,7 @@ def _nsa_tables(t64: np.ndarray, max_range: int, multiple: float,
     keep budget, so they can never claim a record or keep anything — the
     row's compute is fully determined by its ``n_buckets`` scalar.
     """
-    from repro.kernels.stream_sample import MAX_RANGE_LIMIT
+    from repro.kernels.stream_sample import MAX_RANGE_LIMIT, MAX_TABLE_WIDTH
     if max_range > MAX_RANGE_LIMIT:
         raise PallasDomainError(
             f"max_range {max_range} exceeds {MAX_RANGE_LIMIT}: the +-1 "
@@ -111,6 +126,10 @@ def _nsa_tables(t64: np.ndarray, max_range: int, multiple: float,
             "numpy NSA path")
     width = max_range if width is None else width
     assert width >= max_range
+    if on_tpu() and width > MAX_TABLE_WIDTH:
+        raise PallasDomainError(
+            f"bucket tables of {width} entries exceed the TPU kernel's "
+            f"{MAX_TABLE_WIDTH}-entry SMEM budget; use the numpy NSA path")
     n = len(t64)
     t_min, t_max = float(t64[0]), float(t64[-1])
     span = t_max - t_min
@@ -161,12 +180,23 @@ def stream_sample(t: jnp.ndarray, max_range: int,
     t32, starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
     cfg = tuning.config_for("stream_sample", s=1, n=n, r=max_range)
     tp, n0 = _pad_to(jnp.asarray(t32), cfg.record_tile, t32[-1])
-    ss, keep = stream_sample_pallas(
+    ss, keep = stream_sample_launch(
         tp[None, :], jnp.asarray(starts)[None, :],
         jnp.asarray(counts)[None, :], jnp.asarray(ktab)[None, :],
-        jnp.asarray(scalars, jnp.float32)[None, :], max_range,
-        interpret=not on_accelerator(), config=cfg)
+        jnp.asarray(scalars, jnp.float32)[None, :], max_range, cfg)
     return ss[0, :n0], keep[0, :n0].astype(bool)
+
+
+def stream_sample_launch(t, starts, counts, ktab, scalars, width, cfg):
+    """One launch of the stream-sample kernel on padded ``(S, N)`` inputs
+    (the :func:`repro.kernels.stream_sample.stream_sample_pallas`
+    contract): the GPU lowering on CUDA/ROCm, else the TPU kernel —
+    compiled on the TPU, interpreted elsewhere."""
+    if on_gpu():
+        return _gpu.stream_sample_gpu(t, starts, counts, ktab, scalars,
+                                      width, sublane=cfg.sublane)
+    return stream_sample_pallas(t, starts, counts, ktab, scalars, width,
+                                interpret=not on_tpu(), config=cfg)
 
 
 def stream_sample_ref(t: jnp.ndarray, max_range: int, multiple: float):
@@ -237,10 +267,10 @@ def stream_sample_batched(ts, max_range, multiples, *, device=None):
             else jnp.asarray(x)
 
     def _launch(lo, hi):
-        return stream_sample_pallas(
+        return stream_sample_launch(
             _dev(t_b[lo:hi]), _dev(starts_b[lo:hi]), _dev(counts_b[lo:hi]),
             _dev(k_b[lo:hi]), _dev(scal_b[lo:hi].astype(np.float32)), width,
-            interpret=not on_accelerator(), config=cfg)
+            cfg)
 
     g = max(1, min(int(cfg.grid_split), S))
     if g == 1:
@@ -338,11 +368,39 @@ def compact_mask_batched_device(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
     else:
         pos, totals = compact_positions_batched_pallas(
             mi, interpret=not _on_tpu(), config=cfg)
-    tgt = jnp.where(mask.astype(bool), pos[:, :n], n)
+    del mi                   # an (R, N) plane: free it before the scatter
+    return _scatter_kept(mask.astype(bool), pos), totals.reshape(-1)
+
+
+@jax.jit
+def _scatter_kept(mask: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+    """``idx[r, pos[r, i]] = i`` for every kept record ``i``; the sentinel
+    ``n`` elsewhere. One program, so the target and source index planes
+    are XLA temporaries instead of live eager arrays."""
+    R, n = mask.shape
+    tgt = jnp.where(mask, pos[:, :n], n)
     rows = jnp.arange(R, dtype=jnp.int32)[:, None]
     cols = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (R, n))
-    idx = jnp.full((R, n), n, jnp.int32).at[rows, tgt].set(cols, mode="drop")
-    return idx, totals.reshape(-1)
+    return jnp.full((R, n), n, jnp.int32).at[rows, tgt].set(cols,
+                                                            mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def slice_records(t: jnp.ndarray, a: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``t[r, a[r] : a[r] + width]`` per row, clamped to the last column —
+    one program, so the index plane is never a live array."""
+    j = jnp.arange(width, dtype=jnp.int32)[None, :]
+    gidx = jnp.clip(a[:, None] + j, 0, t.shape[1] - 1)
+    return jnp.take_along_axis(t, gidx, axis=1)
+
+
+@jax.jit
+def gather_kept(ss: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Scale stamps of the kept records, row by row: ``ss[r, idx[r, j]]``
+    (sentinel entries clip to the last column — garbage the caller masks
+    by the row's kept total). ``idx`` may be narrower than ``ss``."""
+    N = ss.shape[1]
+    return jnp.take_along_axis(ss, jnp.clip(idx, 0, max(N - 1, 0)), axis=1)
 
 
 # -------------------------------------------------------- metrics engine
@@ -1227,15 +1285,17 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 __all__ = [
-    "ChunkCarry", "KeepRuleOverflow", "PallasDomainError", "bucket_hist",
+    "ChunkCarry", "HostFallbackWarning", "KeepRuleOverflow",
+    "PallasDomainError", "bucket_hist",
     "chunk_carry_finalize", "chunk_carry_init", "compact_mask",
     "compact_mask_batched", "compact_mask_batched_device", "flash_decode",
     "on_accelerator", "on_gpu", "on_tpu",
     "stream_metrics", "stream_metrics_chunk", "trend_scan_chunk",
     "stream_metrics_batched", "stream_metrics_batched_device",
-    "stream_sample", "stream_sample_batched", "stream_sample_ref",
+    "stream_sample", "stream_sample_batched", "stream_sample_launch",
+    "stream_sample_ref",
     "trend_corr_pairwise", "trend_correlation_batched",
     "trend_correlation_batched_device", "trend_pair_stats", "trend_scan",
     "trend_scan_batched", "trend_scan_batched_device", "volatility_moments",
-    "volatility_stats",
+    "volatility_stats", "warn_host_fallback",
 ]

@@ -5,7 +5,7 @@ One ``pallas_call`` with a 2-D grid ``(stream, record-tile)`` replaces S
 sequential dispatches: grid step ``(s, i)`` normalizes an (8, 128)-record
 tile of stream ``s`` while that stream's per-bucket tables (starts, counts,
 per-bucket keep budget ``k``; ``max_range`` <= 3600 entries, <= 14 KiB each)
-and scalars (t_min, 1/span, n_buckets) ride along in VMEM. The
+and scalars (t_min, 1/span, n_buckets) ride along in SMEM. The
 single-stream path is just S == 1.
 
 Range-padded batching: each row carries its OWN bucket count ``n_buckets``
@@ -28,7 +28,9 @@ float64 formula, and the kernel corrects its f32 bucket guess by +-1 so that
 sorted, the tables fully determine the true bucket, and the f32 guess is
 provably within one bucket of it for ``max_range < 2**20``. Result: the
 kernel's scale stamps and keep mask are bit-identical to the numpy NSA, not
-just allclose.
+just allclose. The table reads are a data-adaptive loop over the few
+buckets a sorted tile spans (:func:`_lookup`): Mosaic has no vector gather
+from a table, but it reads SMEM scalars at any index.
 
 The per-bucket keep budget ``k = clip(round(count / multiple), 1)`` is also
 precomputed host-side in float64 (an O(max_range) table), removing both the
@@ -40,7 +42,9 @@ wrapper raises :class:`repro.kernels.ops.KeepRuleOverflow` outside that
 domain and ``nsa(backend="pallas")`` falls back to numpy.
 
 Layout: the wrapper pads the record axis to a multiple of the tile and
-reshapes to (S, rows, 128) so the lane dimension is hardware-native.
+reshapes to (S, rows, 128) so the lane dimension is hardware-native; record
+blocks drop the row dim. Tables and scalars go in as (S, 1, width) arrays
+whose (1, width) row blocks sit in SMEM.
 """
 
 from __future__ import annotations
@@ -51,6 +55,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
 
@@ -62,20 +67,38 @@ TILE = LANE * SUBLANE  # records per grid step with the default TileConfig
 # stays under one bucket: ~4 * max_range * 2^-24 < 1
 MAX_RANGE_LIMIT = 1 << 20
 
+#: widest bucket table the kernel takes: three int32 tables per row,
+#: double-buffered in SMEM (24 B per entry), must fit a v5e core's 1 MiB
+#: of SMEM — 43 200 entries compile for v5e, 65 536 do not
+MAX_TABLE_WIDTH = 40_960
+
+
+def _lookup(tab_refs, bucket, lo, hi):
+    """Per-record table lookup ``[tab[bucket] for tab in tab_refs]``.
+
+    The tables sit in SMEM, so each entry is a scalar load; a sorted tile
+    spans only the few buckets in ``[lo, hi]``, so the loop walks that span
+    and selects each bucket's entries into the records that fall in it —
+    a data-adaptive gather (the same trick as the metrics engine's
+    bucket-block walk). Records outside ``[lo, hi]`` read 0."""
+    def body(b, acc):
+        hit = bucket == b
+        return tuple(jnp.where(hit, ref[0, b], a)
+                     for ref, a in zip(tab_refs, acc))
+
+    init = tuple(jnp.zeros_like(bucket) for _ in tab_refs)
+    return jax.lax.fori_loop(lo, hi + 1, body, init)
+
 
 def _kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
-            keep_ref, *, max_range: int, sublane: int):
-    del max_range  # table width only; each row carries its own bucket count
+            keep_ref, *, sublane: int):
     tile = sublane * LANE
     i = pl.program_id(1)
-    t = t_ref[0].astype(jnp.float32)             # (sublane, LANE)
+    t = t_ref[...]                               # (sublane, LANE) f32
     t_min = scalar_ref[0, 0]
     inv_span = scalar_ref[0, 1]                  # 1/span, precomputed
     nb_f = scalar_ref[0, 2]                      # this row's bucket count
-    nb = nb_f.astype(jnp.int32)                  # f32-exact below 2**24
-    starts = starts_ref[0]                       # (max_range,) int32
-    counts = counts_ref[0]
-    ktab = k_ref[0]
+    nb = jnp.full(t.shape, nb_f).astype(jnp.int32)   # f32-exact below 2**24
 
     # --- normalize: paper formula (1), floored to the simulated second ---
     g = jnp.floor((t - t_min) * inv_span * nb_f).astype(jnp.int32)
@@ -87,21 +110,19 @@ def _kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
     gidx = base + row * LANE + col               # per-stream record index
 
     # --- snap the f32 guess to the bucket that actually contains gidx ---
-    s_g = jnp.take(starts, g, axis=0)
-    c_g = jnp.take(counts, g, axis=0)
+    s_g, c_g = _lookup((starts_ref, counts_ref), g, jnp.min(g), jnp.max(g))
     g = g + (gidx >= s_g + c_g).astype(jnp.int32) \
           - (gidx < s_g).astype(jnp.int32)
     ss = jnp.clip(g, 0, nb - 1)
 
     # --- systematic keep: k of c survive, Bresenham-even ---
-    start = jnp.take(starts, ss, axis=0)
-    c = jnp.take(counts, ss, axis=0)
-    k = jnp.take(ktab, ss, axis=0)
+    start, c, k = _lookup((starts_ref, counts_ref, k_ref), ss,
+                          jnp.min(ss), jnp.max(ss))
     rank = gidx - start
     keep = (rank * k) % jnp.maximum(c, 1) < k
 
-    ss_ref[0] = ss
-    keep_ref[0] = keep.astype(jnp.int32)
+    ss_ref[...] = ss
+    keep_ref[...] = keep.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -143,24 +164,25 @@ def stream_sample_pallas(t: jnp.ndarray, starts: jnp.ndarray,
     rows = n // LANE
     t3 = t.reshape(S, rows, LANE)
     grid = (S, rows // sublane)
+    # per-row tables and scalars ride in SMEM as (1, width) blocks of an
+    # (S, 1, width) layout; record tiles drop the row dim
+    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0))
+
+    def smem_row(width):
+        return pl.BlockSpec((None, 1, width), lambda s, i: (s, 0, 0),
+                            memory_space=pltpu.SMEM)
+
     ss, keep = pl.pallas_call(
-        functools.partial(_kernel, max_range=max_range, sublane=sublane),
+        functools.partial(_kernel, sublane=sublane),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, max_range), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, max_range), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, max_range), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, 3), lambda s, i: (s, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-        ],
+        in_specs=[tile_spec, smem_row(max_range), smem_row(max_range),
+                  smem_row(max_range), smem_row(3)],
+        out_specs=[tile_spec, tile_spec],
         out_shape=[
             jax.ShapeDtypeStruct((S, rows, LANE), jnp.int32),
             jax.ShapeDtypeStruct((S, rows, LANE), jnp.int32),
         ],
         interpret=interpret,
-    )(t3, starts, counts, ktab, scalars)
+    )(t3, starts[:, None, :], counts[:, None, :], ktab[:, None, :],
+      scalars[:, None, :])
     return ss.reshape(S, n), keep.reshape(S, n)

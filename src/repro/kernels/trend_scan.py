@@ -12,9 +12,10 @@ sufficient statistics — device-resident:
     Batched inclusive prefix sum over the time axis of ``(S, N)`` stacked
     count series — the same single-pass scan-with-carry pattern as
     :mod:`repro.kernels.compact`, lifted to a 2-D ``(stream, time-tile)``
-    grid: each grid step computes the tile-local cumsum (lane-wise
-    ``cumsum`` + row offsets) and adds the running carry held in SMEM
-    scratch, resetting the carry at each stream's first tile. Counts
+    grid: each grid step computes the tile-local prefix sum (the exact
+    log-step :func:`repro.kernels.compact.tile_prefix_sum`) and adds the
+    running carry held in SMEM scratch, resetting the carry at each
+    stream's first tile. Counts
     accumulate in int32, so prefix sums are *exact* while a stream's total
     record count stays below 2³¹ (enforced by the ops wrapper). The caller
     turns prefix sums into the windowed sliding mean with two clamped
@@ -56,6 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.compact import tile_prefix_sum
 from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
 
 LANE = 128
@@ -64,25 +66,21 @@ TILE = LANE * SUBLANE   # time steps per grid step (default TileConfig)
 PAIR_TILE = 4 * LANE    # time steps per pair-stats step (default config)
 
 
-def _scan_kernel(q_ref, psum_ref, carry_ref):
-    s = pl.program_id(0)
+def _scan_kernel(init_ref, q_ref, psum_ref, tail_ref, carry_ref):
     i = pl.program_id(1)
-    del s  # the carry reset below only needs the tile index
 
     @pl.when(i == 0)
-    def _reset():
-        carry_ref[0] = 0
+    def _seed():                                     # carry-IN, not a reset
+        carry_ref[0] = init_ref[0, 0]
 
-    q = q_ref[0].astype(jnp.int32)                   # (SUBLANE, LANE)
-    # tile-local inclusive cumsum in row-major time order: lane-wise
-    # inclusive scan, then per-row offsets from the row totals
-    row_incl = jnp.cumsum(q, axis=1)
-    row_tot = row_incl[:, -1:]
-    row_off = jnp.cumsum(row_tot, axis=0) - row_tot  # exclusive over rows
-
+    q = q_ref[...].astype(jnp.int32)                 # (sublane, LANE)
     carry = carry_ref[0]
-    psum_ref[0] = carry + row_off + row_incl
+    psum_ref[...] = carry + tile_prefix_sum(q)
     carry_ref[0] = carry + jnp.sum(q)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _tail():
+        tail_ref[...] = jnp.full(tail_ref.shape, carry_ref[0], jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "config"))
@@ -94,47 +92,11 @@ def trend_scan_pallas(q: jnp.ndarray, *, interpret: bool = False,
 
     Returns ``psum int32 (S, N)`` with
     ``psum[s, i] = Σ_{j <= i} q[s, j]`` — exact while each stream's total
-    stays below 2³¹ (the ops wrapper guards this).
+    stays below 2³¹ (the ops wrapper guards this). The zero-carry case of
+    :func:`trend_scan_carry_pallas`.
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
-    S, n = q.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad time steps to a multiple of {cfg.record_tile}"
-    rows = n // LANE
-    q3 = q.reshape(S, rows, LANE)
-    grid = (S, rows // sublane)
-    psum = pl.pallas_call(
-        _scan_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0))],
-        out_specs=pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, rows, LANE), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(q3)
-    return psum.reshape(S, n)
-
-
-def _scan_kernel_carry(init_ref, q_ref, psum_ref, tail_ref, carry_ref):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _seed():                                     # carry-IN, not a reset
-        carry_ref[0] = init_ref[0, 0]
-
-    q = q_ref[0].astype(jnp.int32)                   # (SUBLANE, LANE)
-    row_incl = jnp.cumsum(q, axis=1)
-    row_tot = row_incl[:, -1:]
-    row_off = jnp.cumsum(row_tot, axis=0) - row_tot  # exclusive over rows
-
-    carry = carry_ref[0]
-    psum_ref[0] = carry + row_off + row_incl
-    carry_ref[0] = carry + jnp.sum(q)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _tail():
-        tail_ref[0, 0] = carry_ref[0]
+    return trend_scan_carry_pallas(q, jnp.zeros(q.shape[0], jnp.int32),
+                                   interpret=interpret, config=config)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "config"))
@@ -164,25 +126,27 @@ def trend_scan_carry_pallas(q: jnp.ndarray, init: jnp.ndarray, *,
     rows = n // LANE
     q3 = q.reshape(S, rows, LANE)
     grid = (S, rows // sublane)
+    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0))
     psum, tail = pl.pallas_call(
-        _scan_kernel_carry,
+        _scan_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda s, i: (s, 0)),
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((None, 1, 1), lambda s, i: (s, 0, 0),
+                         memory_space=pltpu.SMEM),
+            tile_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, sublane, LANE), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, 1), lambda s, i: (s, 0)),
+            tile_spec,
+            pl.BlockSpec((None, 1, LANE), lambda s, i: (s, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((S, rows, LANE), jnp.int32),
-            jax.ShapeDtypeStruct((S, 1), jnp.int32),
+            jax.ShapeDtypeStruct((S, 1, LANE), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
-    )(init.reshape(S, 1).astype(jnp.int32), q3)
-    return psum.reshape(S, n), tail.reshape(S)
+    )(init.reshape(S, 1, 1).astype(jnp.int32), q3)
+    return psum.reshape(S, n), tail[:, 0, 0]
 
 
 def _pair_kernel(x_ref, sums_ref, gram_ref):

@@ -286,8 +286,7 @@ def _pad_rows(x: np.ndarray, mult: int, value) -> np.ndarray:
 def _run_stream_sample(key: TuneKey, cfg: TileConfig):
     import jax.numpy as jnp
 
-    from repro.kernels.ops import _nsa_tables
-    from repro.kernels.stream_sample import stream_sample_pallas
+    from repro.kernels.ops import _nsa_tables, stream_sample_launch
 
     s, n, r = _spec_shapes(key)
     r = max(r, 2)
@@ -305,8 +304,7 @@ def _run_stream_sample(key: TuneKey, cfg: TileConfig):
     args = tuple(map(jnp.asarray, (tp, *tables, scal)))
 
     def run():
-        ss, keep = stream_sample_pallas(*args, r, interpret=_interpret(),
-                                        config=cfg)
+        ss, keep = stream_sample_launch(*args, r, cfg)
         return ss[:, :n], keep[:, :n]
 
     def reference():

@@ -55,16 +55,6 @@ _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "u16": 2, "c64": 8}
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returned a one-element list of dicts
-    before jax 0.6 and a bare dict after; normalize to the dict (the single
-    compat shim — tests import it too)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _shape_bytes(type_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(type_str):
@@ -203,7 +193,7 @@ def analyze(arch: str, shape_name: str, mesh_kind: str, cfg, mesh, lowered,
             compiled, t_lower, t_compile) -> dict:
     n_dev = int(np.prod(list(mesh.shape.values())))
     mem = compiled.memory_analysis()
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     # loop-aware HLO analysis (cost_analysis undercounts while bodies);
     # the compiled module is the per-device SPMD program, so flops/bytes

@@ -20,25 +20,20 @@ ICI_BW = 50e9                 # bytes/s per link
 HBM_PER_CHIP = 16 * 1024**3   # 16 GiB
 
 
-def _axis_types_kwargs(n: int) -> dict:
-    """``axis_types`` only exists from jax 0.5 (explicit-sharding API); on
-    older versions every mesh axis is implicitly Auto, so omitting the
-    kwarg is semantically identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def auto_axes(n: int) -> tuple:
+    """``axis_types`` for an ``n``-axis mesh whose shardings the compiler
+    propagates (the model code only constrains activations)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/examples."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), auto_axes(2))

@@ -51,6 +51,10 @@ class Controller:
         #: the per-sweep S×S fidelity matrices from the latest
         #: :meth:`run_many` call (also persisted under ``fidelity_dir``)
         self.last_fidelity: List[FidelityReport] = []
+        #: where the latest monolithic :meth:`run_many` ran each scenario
+        #: it computed in device mode (see :meth:`repro.streamsim.engine.
+        #: DeviceSweepResult.placement`)
+        self.last_placement: Dict = {}
 
     # ----------------------------------------------------- (1) simulate/run
     def prepare(self, dataset: str, *, scale: float = 1.0, seed: int = 0,
@@ -422,6 +426,7 @@ class Controller:
                     n_hosts=n_hosts, chunk_s=chunk_s,
                     duration_s=duration_s)
         new_reports: List[SimulationReport] = []
+        self.last_placement = {}
         if plan is not None:
             if chunk_s:
                 runner = engine.ChunkedSweepRunner(
@@ -438,6 +443,7 @@ class Controller:
                                               backend=backend,
                                               checkpoint=ckpt,
                                               autotune=autotune)
+                self.last_placement = result.placement()
                 new_reports, fidelity = engine.run_sweep(
                     result, consumer, queue_size=queue_size,
                     fidelity_window_s=fidelity_window_s, t_pre=t_pre,

@@ -34,9 +34,12 @@ are bit-equal to the sequential path. ``backend="pallas"`` (and
 bit-identical, counts are bit-exact, and moments / trend correlations
 agree within the documented 1e-3 tolerance (f32 device statistics). Any
 :class:`~repro.kernels.ops.PallasDomainError` during the device chain
-falls back to host mode wholesale — never silently wrong output. The
-fallback keeps the caller's *metrics* backend (an NSA-only domain error
-does not demote in-domain pallas metrics — the pre-plan behaviour); only
+falls back to host mode wholesale — never silently wrong output, and
+never silently: it warns with :class:`~repro.kernels.ops.
+HostFallbackWarning` naming the out-of-domain input, and every
+:class:`SimulationReport` carries the sweep's ``mode``. The fallback keeps
+the caller's *metrics* backend (an NSA-only domain error does not demote
+in-domain pallas metrics — the pre-plan behaviour); only
 ``backend="numpy"`` guarantees f64 host statistics throughout.
 """
 
@@ -94,6 +97,10 @@ class SimulationReport:
     status: str = "ok"
     failure: Optional[str] = None   #: repr of the terminal consumer error
     attempts: int = 1               #: replay attempts consumed (1 = clean)
+    #: how the sweep ran: "device" (the Pallas chain) or "host" (numpy,
+    #: by request, off the chip, or after a domain fallback); None for a
+    #: quarantine stub no sweep produced
+    mode: Optional[str] = None
 
     def to_json(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -256,6 +263,20 @@ class DeviceSweepResult:
         return tuple(s.scenario for s in self.plan.scenarios
                      if s.scenario in local)
 
+    def placement(self) -> Dict[Tuple[str, int], Tuple[int, List[str]]]:
+        """scenario -> (plan device slot of its shard, the devices its
+        shard's device-resident arrays actually sit on) — every scenario
+        this process computed in device mode; empty in host mode."""
+        out = {}
+        for sr in self.shard_results:
+            on = set()
+            for arr in (sr.ss_kept, sr.idx, sr.hist):
+                if arr is not None:
+                    on |= arr.devices()
+            for sc in sr.pairs:
+                out[sc] = (sr.shard.device_index, sorted(map(str, on)))
+        return out
+
     def _scenario_sources(self):
         """scenario -> ("shard", shard_result, row) | ("host", None, None)"""
         src = {sc: ("host", None, None) for sc in self.host_sims}
@@ -394,7 +415,8 @@ class DeviceSweepResult:
             return ops.trend_corr_pairwise(om_mat, om_trs, qb, lb,
                                            REPORT_TREND_WINDOW_S,
                                            totals=totals, a_index=rows)
-        except ops.PallasDomainError:
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback("report trend correlations", err)
             return np.array([trend_correlation_from_counts(
                 self.om[sc[0]].counts, self._counts_host(sc, src),
                 REPORT_TREND_WINDOW_S)
@@ -484,7 +506,8 @@ class DeviceSweepResult:
                                               store=self.store or None):
                         matrix = ops.trend_correlation_batched_device(
                             qmat, lengths, window_s, totals=totals)
-                except ops.PallasDomainError:
+                except ops.PallasDomainError as err:
+                    ops.warn_host_fallback(f"fidelity matrix at {mr}", err)
                     matrix = trend_correlation_matrix(
                         [self.om[d].counts for d in row_ds] +
                         [self._counts_host((d, mr), src)
@@ -619,7 +642,8 @@ def _execute_device(plan, originals, store, backend, multiple_mode,
                     shard=shard, pairs=pairs, ss_kept=ss_kept, idx=idx,
                     totals=np.asarray(totals, np.int64), hist=hist,
                     mom=mom_host, nsa_s=dt))
-    except ops.PallasDomainError:
+    except ops.PallasDomainError as err:
+        ops.warn_host_fallback("sweep", err)
         return None   # out-of-domain scenario: host mode, wholesale
 
     for spec in plan.cached:
@@ -989,6 +1013,7 @@ def build_report(result: DeviceSweepResult, scenario: Tuple[str, int],
         failure=consumer_metrics.get("failed") if degraded else None,
         attempts=int(consumer_metrics.get(
             "attempts", consumer_metrics.get("retries", 0) + 1)),
+        mode=result.mode,
     )
 
 
@@ -1108,7 +1133,8 @@ class ChunkedSweepRunner:
             try:
                 self._prep_device()
                 self.mode = "device"
-            except ops.PallasDomainError:
+            except ops.PallasDomainError as err:
+                ops.warn_host_fallback("chunked sweep", err)
                 self._shard_states = []   # wholesale host fallback
 
     @property

@@ -243,7 +243,8 @@ def metrics_batched(streams: Sequence[Stream],
         with tuning.tuner_context(autotune):
             hist, mom, _ = ops.stream_metrics_batched(
                 [b for b, _ in series], max_tr)
-    except ops.PallasDomainError:
+    except ops.PallasDomainError as err:
+        ops.warn_host_fallback("metrics_batched", err)
         return [_numpy_metrics(b, tr) for b, tr in series]
     hist = np.asarray(hist, np.int64)
     mom = np.asarray(mom, np.float64)
@@ -319,8 +320,8 @@ def trend(stream: Stream, window_s: int = 600,
             hist, _ = ops.stream_metrics(buckets, tr)
             return np.asarray(ops.trend_scan(np.asarray(hist),
                                              max(window_s, 1)), np.float64)
-        except ops.PallasDomainError:
-            pass  # counts outside the int32 scan domain -> host path
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback("trend", err)
     q = np.bincount(buckets, minlength=tr)
     return sliding_mean(q.astype(np.float64), window_s)
 
@@ -377,8 +378,8 @@ def trend_correlation(a: Stream, b: Stream, window_s: int = 60,
         try:
             return float(ops.trend_correlation_batched(
                 [qa, qb], max(window_s, 1))[0, 1])
-        except ops.PallasDomainError:
-            pass  # totals outside the int32 scan domain -> host path
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback("trend_correlation", err)
     return trend_correlation_from_counts(qa, qb, window_s)
 
 
@@ -458,6 +459,6 @@ def trend_correlation_matrix(counts: Sequence[np.ndarray],
             with tuning.tuner_context(autotune):
                 return ops.trend_correlation_batched(counts, window_s,
                                                      n_points)
-        except ops.PallasDomainError:
-            pass  # totals outside the int32 scan domain -> host path
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback("trend_correlation_matrix", err)
     return _corr_matrix_numpy(counts, window_s, n_points)

@@ -189,24 +189,26 @@ def nsa(stream: Stream, max_range: int, *, keep: str = "systematic",
 
     Notes
     -----
-    Streams outside the device kernels' exactness domain (int32 keep-rule
-    overflow, ``max_range`` past the ±1-snap guarantee) raise
+    Streams outside the device kernels' domain (int32 keep-rule overflow,
+    ``max_range`` past the ±1-snap guarantee, on the TPU bucket tables
+    wider than the kernel's SMEM budget) raise
     :class:`repro.kernels.ops.PallasDomainError` inside the ops layer;
-    this function catches it and silently falls back to the numpy path, so
-    the bit-identity contract survives out-of-domain inputs.
+    this function catches it, warns (:class:`repro.kernels.ops.
+    HostFallbackWarning`) and falls back to the numpy path, so the
+    bit-identity contract survives out-of-domain inputs.
     """
     if max_range <= 0:
         raise ValueError("max_range must be positive")
     m = _multiple(len(stream), stream.time_range, max_range, multiple_mode)
     if (_resolve_backend(backend) == "pallas" and keep == "systematic"
             and len(stream) > 0):
-        from repro.kernels import tuning
-        from repro.kernels.ops import PallasDomainError
+        from repro.kernels import ops, tuning
         try:
             with tuning.tuner_context(autotune):
                 return _nsa_pallas(stream, max_range, m)
-        except PallasDomainError:
-            pass  # stream outside the kernel's exactness domain
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback(f"nsa({stream.name!r}, {max_range})",
+                                   err)
     ss = scale_stamps(stream.t, max_range)
     mask = systematic_keep_mask(ss, max_range, m, keep=keep)
     return Stream(
@@ -308,8 +310,8 @@ def nsa_batched(streams: Dict[str, Stream], max_range: int, *,
             return {name: _compact_gather(streams[name], ss_b[s],
                                           keep_b[s, :lengths[s]])
                     for s, name in enumerate(names)}
-    except ops.PallasDomainError:
-        # some stream falls outside the kernel's exactness domain
+    except ops.PallasDomainError as err:
+        ops.warn_host_fallback("nsa_batched", err)
         return {name: nsa(s, max_range, multiple_mode=multiple_mode,
                           backend="numpy")
                 for name, s in streams.items()}
@@ -389,8 +391,8 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
     try:
         ss_kept, idx_b, totals, _ = nsa_sweep_device(
             streams, pairs, multiple_mode=multiple_mode, autotune=autotune)
-    except ops.PallasDomainError:
-        # some scenario falls outside the kernel's exactness domain
+    except ops.PallasDomainError as err:
+        ops.warn_host_fallback("nsa_sweep", err)
         return _host()
     return materialize_sweep(streams, pairs, ss_kept, idx_b, totals)
 
@@ -432,7 +434,6 @@ def nsa_sweep_device(streams: Dict[str, Stream],
         When any scenario falls outside the kernels' exactness domain —
         callers fall back to the numpy path wholesale.
     """
-    import jax.numpy as jnp
     from repro.kernels import ops, tuning
 
     ts = [streams[name].t for name, _ in pairs]
@@ -442,10 +443,7 @@ def nsa_sweep_device(streams: Dict[str, Stream],
         ss_b, keep_b, lengths = ops.stream_sample_batched(
             ts, [mr for _, mr in pairs], mults, device=device)
         idx_b, totals = ops.compact_mask_batched(keep_b)
-    N = idx_b.shape[1]
-    ss_kept = jnp.take_along_axis(ss_b, jnp.clip(idx_b, 0, max(N - 1, 0)),
-                                  axis=1)
-    return ss_kept, idx_b, totals, lengths
+    return ops.gather_kept(ss_b, idx_b), idx_b, totals, lengths
 
 
 def materialize_sweep(streams: Dict[str, Stream],
@@ -485,8 +483,8 @@ class ChunkHandles:
     slice; add ``rec_off[r]`` (host int64) to recover absolute record
     indices into the source stream.
     """
-    ss_kept: object          # (R, Nc) int32 device — kept scale stamps
-    idx: object              # (R, Nc) int32 device — local kept indices
+    ss_kept: object          # (R, K) int32 device — kept scale stamps
+    idx: object              # (R, K) int32 device — local kept indices
     totals: object           # (R,)    int32 device — kept counts
     rec_off: np.ndarray      # (R,)    int64 host   — record slice offsets
     lo: int                  # chunk bucket range [lo, hi)
@@ -577,6 +575,12 @@ class ChunkedNSA:
         # lo (tail buckets carry starts = n, so rows whose range ends
         # before the sweep's maximum contribute empty slices for free)
         self._starts_np = starts_b.astype(np.int64)
+        # kept records through each bucket: a non-empty bucket keeps
+        # exactly min(k, c) = k records, an empty one none — so a chunk's
+        # kept width is known here, with no device sync
+        self._kept_cum = np.zeros((R, self.width + 1), np.int64)
+        np.cumsum(np.minimum(k_b, counts_b), axis=1,
+                  out=self._kept_cum[:, 1:])
 
         def _dev(x):
             return jax.device_put(x, device) if device is not None \
@@ -614,22 +618,21 @@ class ChunkedNSA:
             tile = cfg.record_tile
             Nc = max(int(-(-max(int(m.max()), 1) // tile) * tile), tile)
             a_dev = self._dev(a.astype(np.int32))
-            j = jnp.arange(Nc, dtype=jnp.int32)[None, :]
-            gidx = jnp.clip(a_dev[:, None] + j, 0, self.N - 1)
-            t_slice = jnp.take_along_axis(self._t, gidx, axis=1)
             # rebase the bucket tables by the slice offset: local rank ==
             # global rank, so the keep bits match the monolithic launch
             starts_reb = self._starts - a_dev[:, None]
-            ss, keep = ops.stream_sample_pallas(
-                t_slice, starts_reb, self._counts, self._ktab, self._scal,
-                self.width, interpret=not ops.on_accelerator(), config=cfg)
+            ss, keep = ops.stream_sample_launch(
+                ops.slice_records(self._t, a_dev, Nc), starts_reb,
+                self._counts, self._ktab, self._scal, self.width, cfg)
+            j = jnp.arange(Nc, dtype=jnp.int32)[None, :]
             keep = keep.astype(bool) & \
                 (j < self._dev(m.astype(np.int32))[:, None])
             idx, totals = ops.compact_mask_batched_device(keep)
-        ss_kept = jnp.take_along_axis(ss, jnp.clip(idx, 0, max(Nc - 1, 0)),
-                                      axis=1)
-        return ChunkHandles(ss_kept=ss_kept, idx=idx, totals=totals,
-                            rec_off=a, lo=lo, hi=hi)
+        # only the first ``kept`` columns of each row hold kept records
+        kept = int((self._kept_cum[:, hi] - self._kept_cum[:, lo]).max())
+        idx = idx[:, :max(int(-(-kept // tile) * tile), tile)]
+        return ChunkHandles(ss_kept=ops.gather_kept(ss, idx), idx=idx,
+                            totals=totals, rec_off=a, lo=lo, hi=hi)
 
 
 def materialize_sweep_chunk(streams: Dict[str, Stream],

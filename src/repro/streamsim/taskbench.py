@@ -92,8 +92,8 @@ def _hist_rows(arrays: List[np.ndarray], n_bins: int,
         try:
             hist, _, _ = ops.stream_metrics_batched(arrays, n_bins)
             return np.asarray(hist, np.int64)
-        except ops.PallasDomainError:
-            pass
+        except ops.PallasDomainError as err:
+            ops.warn_host_fallback("histogram rows", err)
     return np.stack([np.bincount(a, minlength=n_bins).astype(np.int64)
                      for a in arrays])
 

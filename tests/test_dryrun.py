@@ -16,9 +16,6 @@ from repro.launch.hlo_analysis import analyze_hlo
 
 REPO = pathlib.Path(__file__).parent.parent
 
-# the single cost_analysis() list-vs-dict compat shim lives in dryrun
-from repro.launch.dryrun import xla_cost_analysis as _xla_cost  # noqa: E402
-
 
 class TestHloAnalyzer:
     def test_loop_free_matches_xla(self):
@@ -28,7 +25,7 @@ class TestHloAnalyzer:
         args = [jax.ShapeDtypeStruct(s, jnp.float32)
                 for s in [(64, 128), (128, 256), (256, 512)]]
         c = jax.jit(f).lower(*args).compile()
-        xla = _xla_cost(c)
+        xla = c.cost_analysis()
         mine = analyze_hlo(c.as_text())
         exact = 2 * 64 * 128 * 256 + 2 * 64 * 256 * 512
         assert abs(mine["flops"] - exact) / exact < 0.01
@@ -53,7 +50,7 @@ class TestHloAnalyzer:
         assert abs(mine["flops"] - exact) / exact < 0.01, \
             "while bodies must be multiplied by trip count"
         # XLA's own count misses the loop: stays far below exact
-        assert _xla_cost(c)["flops"] < exact / 4
+        assert c.cost_analysis()["flops"] < exact / 4
 
     def test_scan_bytes_not_inflated_by_stacked_params(self):
         # a scan reading one (128,128) slice per step must not count the
@@ -96,7 +93,7 @@ import repro.launch.mesh as M
 M.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
     (2, 2, 4) if multi_pod else (4, 4),
     ("pod", "data", "model") if multi_pod else ("data", "model"),
-    **M._axis_types_kwargs(3 if multi_pod else 2))
+    M.auto_axes(3 if multi_pod else 2))
 D.make_production_mesh = M.make_production_mesh
 import repro.configs as C
 # reduced shapes so a smoke config lowers in seconds

@@ -130,6 +130,57 @@ def test_pair_stats_within_tolerance(rng):
                                rtol=1e-3, atol=1e-3)
 
 
+def _sample_args(rng, widths=(600, 240), n=2048):
+    """Range-padded (S, N) stream-sample inputs, one row per width."""
+    width = max(widths)
+    rows = [ops._nsa_tables(np.sort(rng.uniform(0, 900.0, n)), w, 3.0,
+                            width) for w in widths]
+    return tuple(jnp.asarray(np.stack([r[i] for r in rows]))
+                 for i in range(4)) + (
+        jnp.asarray(np.stack([r[4] for r in rows]), jnp.float32),), width
+
+
+def test_stream_sample_matches_tpu_kernel_and_ref(rng):
+    from repro.kernels.stream_sample import stream_sample_pallas
+    args, width = _sample_args(rng)
+    ss_g, keep_g = gpu.stream_sample_gpu(*args, width, interpret=True)
+    ss_t, keep_t = stream_sample_pallas(*args, width, interpret=True)
+    ss_r, keep_r = ref.stream_sample_ref(*args, width)
+    for got, want in ((ss_g, ss_t), (keep_g, keep_t), (ss_g, ss_r),
+                      (keep_g, keep_r)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_ops_routes_stream_sample_to_gpu_lowering(rng, monkeypatch):
+    ts = [np.sort(rng.uniform(0, 900.0, n)) for n in (1500, 700)]
+    want = ops.stream_sample_batched(ts, [600, 240], 3.0)
+    calls = []
+    lowering = gpu.stream_sample_gpu
+
+    def spy(*args, **kw):
+        calls.append(args[5])
+        return lowering(*args, **kw)
+
+    monkeypatch.setattr(ops, "on_gpu", lambda: True)
+    monkeypatch.setattr(gpu, "stream_sample_gpu", spy)
+    got = ops.stream_sample_batched(ts, [600, 240], 3.0)
+    assert calls == [600]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_smem_table_budget_binds_only_on_tpu(monkeypatch):
+    from repro.kernels.stream_sample import MAX_TABLE_WIDTH
+    t = np.arange(100, dtype=np.float64)
+    wide = MAX_TABLE_WIDTH + 1
+    assert len(ops._nsa_tables(t, wide, 2.0)[1]) == wide
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ops.PallasDomainError, match="SMEM"):
+        ops._nsa_tables(t, wide, 2.0)
+    assert len(ops._nsa_tables(t, MAX_TABLE_WIDTH, 2.0)[1]) == \
+        MAX_TABLE_WIDTH
+
+
 def test_ops_backend_auto_prefers_any_accelerator():
     # "auto" resolves to the pallas path whenever a real accelerator is
     # present (TPU *or* GPU) — the GPU lowering makes that safe
@@ -174,15 +225,9 @@ def test_compiled_trend_and_pair_on_gpu(rng):
 
 @needs_gpu
 def test_compiled_stream_sample_on_gpu(rng):
-    # the TPU stream_sample kernel is grid-parallel-safe and must compile
-    # unchanged on GPU (ops dispatches it with interpret=False there)
-    from repro.kernels.stream_sample import stream_sample_pallas
-    t = np.sort(rng.uniform(0, 900.0, 2048))
-    t32, starts, counts, ktab, scal = ops._nsa_tables(t, 600, 1.0)
-    args = (jnp.asarray(t32)[None], jnp.asarray(starts)[None],
-            jnp.asarray(counts)[None], jnp.asarray(ktab)[None],
-            jnp.asarray(scal)[None])
-    ss_c, keep_c = stream_sample_pallas(*args, 600, interpret=False)
-    ss_i, keep_i = stream_sample_pallas(*args, 600, interpret=True)
+    # ops dispatches the GPU lowering with interpret=False there
+    args, width = _sample_args(rng)
+    ss_c, keep_c = gpu.stream_sample_gpu(*args, width, interpret=False)
+    ss_i, keep_i = gpu.stream_sample_gpu(*args, width, interpret=True)
     np.testing.assert_array_equal(np.asarray(ss_c), np.asarray(ss_i))
     np.testing.assert_array_equal(np.asarray(keep_c), np.asarray(keep_i))

@@ -199,8 +199,8 @@ from repro.training.optimizer import AdamW, adamw_init, adamw_update
 cfg = consumer_lm().replace(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                             head_dim=16, d_ff=128, vocab_size=512,
                             loss_chunk=16)
-from repro.launch.mesh import _axis_types_kwargs
-mesh = jax.make_mesh((2,), ("data",), **_axis_types_kwargs(1))
+from repro.launch.mesh import auto_axes
+mesh = jax.make_mesh((2,), ("data",), auto_axes(1))
 params = T.init_params(cfg, jax.random.PRNGKey(0))
 ef = ef_init(params)
 opt = AdamW(lr=3e-3, warmup_steps=2, total_steps=40)
