@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import gpu_lowering as _gpu
 from repro.kernels import ref, tuning
 from repro.kernels.compact import compact_positions_pallas
@@ -254,13 +255,14 @@ def stream_sample_batched(ts, max_range, multiples, *, device=None):
     counts_b = np.empty((S, width), np.int32)
     k_b = np.empty((S, width), np.int32)
     scal_b = np.empty((S, 3), np.float32)
-    for s, t64 in enumerate(ts):
-        t32, starts, counts, ktab, scalars = _nsa_tables(
-            t64, int(ranges[s]), float(mults[s]), width)
-        t_b[s, :len(t32)] = t32
-        t_b[s, len(t32):] = t32[-1]          # pad into the last bucket
-        starts_b[s], counts_b[s], k_b[s] = starts, counts, ktab
-        scal_b[s] = scalars
+    with obs.span("nsa.tables"):
+        for s, t64 in enumerate(ts):
+            t32, starts, counts, ktab, scalars = _nsa_tables(
+                t64, int(ranges[s]), float(mults[s]), width)
+            t_b[s, :len(t32)] = t32
+            t_b[s, len(t32):] = t32[-1]      # pad into the last bucket
+            starts_b[s], counts_b[s], k_b[s] = starts, counts, ktab
+            scal_b[s] = scalars
 
     def _dev(x):
         return jax.device_put(x, device) if device is not None \

@@ -27,6 +27,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.streamsim import engine
 from repro.streamsim.datasets import make_stream
 # Report dataclasses live in the engine's report layer now; re-exported
@@ -63,8 +64,8 @@ class Controller:
         key = f"{dataset}__orig"
         if self.store.exists(key) and not force:
             return self.store.get(key)
-        raw = make_stream(dataset, scale=scale, seed=seed)
-        stream = preprocess(raw)
+        with obs.span("controller.posd"):
+            stream = preprocess(make_stream(dataset, scale=scale, seed=seed))
         self.store.put(key, stream, {"scale": scale, "seed": seed})
         return stream
 
@@ -90,7 +91,8 @@ class Controller:
 
     def _prepare_all(self, datasets: Sequence[str], scale: float,
                      seed: int, duration_s: int = 0) -> tuple:
-        """POSD every dataset, timing each (matching ``run``'s reports).
+        """POSD every dataset, timing each (matching ``run``'s reports) with
+        its ``controller.prepare`` span.
 
         ``duration_s > 0`` prepares the MULTI-DAY original instead: one
         preprocessed day per 86 400 s of duration (day ``d`` generated
@@ -101,13 +103,13 @@ class Controller:
         """
         originals, t_pre = {}, {}
         for d in datasets:
-            t0 = time.perf_counter()
-            if duration_s > 0:
-                originals[d] = self._prepare_multiday(d, scale, seed,
-                                                      duration_s)
-            else:
-                originals[d] = self.prepare(d, scale=scale, seed=seed)
-            t_pre[d] = time.perf_counter() - t0
+            with obs.span("controller.prepare") as sp:
+                if duration_s > 0:
+                    originals[d] = self._prepare_multiday(d, scale, seed,
+                                                          duration_s)
+                else:
+                    originals[d] = self.prepare(d, scale=scale, seed=seed)
+            t_pre[d] = sp.seconds
         return originals, t_pre
 
     def _prepare_multiday(self, dataset: str, scale: float, seed: int,
@@ -115,25 +117,26 @@ class Controller:
         key = f"{dataset}__orig__d{duration_s}"
         if self.store.exists(key):
             return self.store.get(key)
-        n_days = -(-int(duration_s) // DAY_S)
-        ts, payloads = [], []
-        for day in range(n_days):
-            raw = make_stream(dataset, scale=scale, seed=seed + day)
-            st = preprocess(raw)
-            # rebase the day onto its slot; clip a (pathological) day
-            # running past 86 400 s to the slot boundary so the
-            # concatenation stays chronological
-            t_day = np.minimum(st.t - st.t[0], float(DAY_S))
-            ts.append(t_day + day * float(DAY_S))
-            payloads.append(st.payload)
-        t = np.concatenate(ts)
-        cols = payloads[0].keys()
-        payload = {c: np.concatenate([p[c] for p in payloads])
-                   for c in cols}
-        keep = t < float(duration_s)     # trim the partial last day
-        stream = Stream(name=dataset, t=t[keep],
-                        payload={c: v[keep] for c, v in payload.items()},
-                        scale_stamp=None)
+        with obs.span("controller.posd"):
+            n_days = -(-int(duration_s) // DAY_S)
+            ts, payloads = [], []
+            for day in range(n_days):
+                raw = make_stream(dataset, scale=scale, seed=seed + day)
+                st = preprocess(raw)
+                # rebase the day onto its slot; clip a (pathological) day
+                # running past 86 400 s to the slot boundary so the
+                # concatenation stays chronological
+                t_day = np.minimum(st.t - st.t[0], float(DAY_S))
+                ts.append(t_day + day * float(DAY_S))
+                payloads.append(st.payload)
+            t = np.concatenate(ts)
+            cols = payloads[0].keys()
+            payload = {c: np.concatenate([p[c] for p in payloads])
+                       for c in cols}
+            keep = t < float(duration_s)     # trim the partial last day
+            stream = Stream(name=dataset, t=t[keep],
+                            payload={c: v[keep] for c, v in payload.items()},
+                            scale_stamp=None)
         self.store.put(key, stream, {"scale": scale, "seed": seed,
                                      "duration_s": int(duration_s)})
         return stream
@@ -348,7 +351,8 @@ class Controller:
             :meth:`run` report (``nsa_s`` holds the sweep's shared NSA wall
             time for scenarios simulated together and ``produce_s`` the
             shared replay-loop wall time; ``nsa_s`` is 0.0 for store cache
-            hits).
+            hits). Every report also carries the call's span totals and
+            counters (``spans``, ``counts``; see :mod:`repro.obs`).
 
         Notes
         -----
@@ -374,105 +378,114 @@ class Controller:
                 "service mode is incompatible with chunk_s/checkpoint — "
                 "the service's durable work queue is its own checkpoint "
                 "and leases are scenario-granular")
-        originals, t_pre = self._prepare_all(datasets, scale, seed,
-                                             duration_s)
-        if _resolve_backend(backend) == "numpy":
-            # host mode ignores the partition; don't let the topology
-            # defaults force a jax runtime initialization on the pure
-            # numpy path
-            n_devices = 1 if n_devices is None else n_devices
-            host_index = 0 if host_index is None else host_index
-            n_hosts = 1 if n_hosts is None else n_hosts
-        row_counts = {d: len(originals[d]) for d in datasets}
-        if service:
-            return self._run_service(
-                datasets, max_ranges, originals, t_pre, consumer,
-                scale=scale, seed=seed, queue_size=queue_size,
-                backend=backend, fidelity_window_s=fidelity_window_s,
-                n_devices=n_devices, host_index=host_index,
-                n_hosts=n_hosts, fault_plan=fault_plan,
-                retry_policy=retry_policy,
-                breaker_threshold=breaker_threshold,
-                consumer_deadline_s=consumer_deadline_s,
-                on_failure=on_failure, max_bytes=max_bytes,
-                retention_policy=retention_policy,
-                lease_ttl_s=lease_ttl_s, service_poll_s=service_poll_s,
-                lease_batch=lease_batch, worker_id=worker_id,
-                service_deadline_s=service_deadline_s)
-        plan = plan_sweep(self.store, datasets, max_ranges, row_counts,
-                          scale=scale, seed=seed, n_devices=n_devices,
-                          host_index=host_index, n_hosts=n_hosts,
-                          chunk_s=chunk_s, duration_s=duration_s)
-        ckpt: Optional[SweepCheckpoint] = None
-        prior: Dict = {}
-        grid = [s.scenario for s in plan.scenarios]
-        if plan.n_hosts > 1:
-            local = {s.scenario for s in plan.local_missing} | \
-                {s.scenario for s in plan.cached}
-            grid = [sc for sc in grid if sc in local]
-        if checkpoint:
-            ckpt = SweepCheckpoint(self.store, plan.sweep_id)
-            done = set(ckpt.done_scenarios()) & set(grid)
-            if done:
-                # resume: completed scenarios' reports come straight from
-                # their markers; only the remainder is planned and run
-                prior = {sc: r for sc, r in ckpt.load_reports().items()
-                         if sc in done}
-                remaining = [sc for sc in grid if sc not in done]
-                plan = None if not remaining else plan_sweep(
-                    self.store, datasets, max_ranges, row_counts,
-                    scale=scale, seed=seed, pairs=remaining,
+        with obs.recording() as rec:
+            originals, t_pre = self._prepare_all(datasets, scale, seed,
+                                                 duration_s)
+            if _resolve_backend(backend) == "numpy":
+                # host mode ignores the partition; don't let the topology
+                # defaults force a jax runtime initialization on the pure
+                # numpy path
+                n_devices = 1 if n_devices is None else n_devices
+                host_index = 0 if host_index is None else host_index
+                n_hosts = 1 if n_hosts is None else n_hosts
+            row_counts = {d: len(originals[d]) for d in datasets}
+            if service:
+                return self._run_service(
+                    datasets, max_ranges, originals, t_pre, consumer,
+                    scale=scale, seed=seed, queue_size=queue_size,
+                    backend=backend, fidelity_window_s=fidelity_window_s,
                     n_devices=n_devices, host_index=host_index,
-                    n_hosts=n_hosts, chunk_s=chunk_s,
-                    duration_s=duration_s)
-        new_reports: List[SimulationReport] = []
-        self.last_placement = {}
-        if plan is not None:
-            if chunk_s:
-                runner = engine.ChunkedSweepRunner(
-                    plan, originals, self.store, backend=backend,
-                    checkpoint=ckpt, autotune=autotune)
-                new_reports, fidelity = engine.run_sweep_chunked(
-                    runner, consumer, queue_size=queue_size,
-                    fidelity_window_s=fidelity_window_s, t_pre=t_pre,
-                    fault_plan=fault_plan, on_failure=on_failure,
-                    max_bytes=max_bytes,
-                    retention_policy=retention_policy, checkpoint=ckpt)
-            else:
-                result = engine.execute_sweep(plan, originals, self.store,
-                                              backend=backend,
-                                              checkpoint=ckpt,
-                                              autotune=autotune)
-                self.last_placement = result.placement()
-                new_reports, fidelity = engine.run_sweep(
-                    result, consumer, queue_size=queue_size,
-                    fidelity_window_s=fidelity_window_s, t_pre=t_pre,
-                    fault_plan=fault_plan, retry_policy=retry_policy,
+                    n_hosts=n_hosts, fault_plan=fault_plan,
+                    retry_policy=retry_policy,
                     breaker_threshold=breaker_threshold,
                     consumer_deadline_s=consumer_deadline_s,
                     on_failure=on_failure, max_bytes=max_bytes,
-                    retention_policy=retention_policy, checkpoint=ckpt)
-            if not chunk_s and plan.n_hosts > 1:
-                # PR 5 gap closed: publish this host's exact count rows
-                # into the shared store and, once every host's rows are
-                # there, replace the partial per-host matrices with the
-                # merged FULL S×S matrix (the last host to finish — and
-                # any later re-run — sees the complete artifact)
-                merged = self._publish_and_merge_fidelity(
-                    result, plan, fidelity_window_s)
-                if merged is not None:
-                    fidelity = merged
-            self.last_fidelity = fidelity
-            for fr in fidelity:
-                self.save_fidelity(fr)
-        by_sc = dict(prior)
-        by_sc.update({(r.dataset, r.max_range): r for r in new_reports})
-        reports = [by_sc[sc] for sc in grid]
-        for report in reports:
-            self.save_metrics(report)
-        if ckpt is not None:
-            ckpt.clear()     # sweep complete: the next run starts fresh
-        return reports
+                    retention_policy=retention_policy,
+                    lease_ttl_s=lease_ttl_s, service_poll_s=service_poll_s,
+                    lease_batch=lease_batch, worker_id=worker_id,
+                    service_deadline_s=service_deadline_s, recorder=rec)
+            with obs.span("plan.sweep"):
+                plan = plan_sweep(self.store, datasets, max_ranges,
+                                  row_counts, scale=scale, seed=seed,
+                                  n_devices=n_devices, host_index=host_index,
+                                  n_hosts=n_hosts, chunk_s=chunk_s,
+                                  duration_s=duration_s)
+            ckpt: Optional[SweepCheckpoint] = None
+            prior: Dict = {}
+            grid = [s.scenario for s in plan.scenarios]
+            if plan.n_hosts > 1:
+                local = {s.scenario for s in plan.local_missing} | \
+                    {s.scenario for s in plan.cached}
+                grid = [sc for sc in grid if sc in local]
+            if checkpoint:
+                ckpt = SweepCheckpoint(self.store, plan.sweep_id)
+                done = set(ckpt.done_scenarios()) & set(grid)
+                if done:
+                    # resume: completed scenarios' reports come straight
+                    # from their markers; only the remainder is planned
+                    # and run
+                    prior = {sc: r for sc, r in ckpt.load_reports().items()
+                             if sc in done}
+                    remaining = [sc for sc in grid if sc not in done]
+                    plan = None
+                    if remaining:
+                        with obs.span("plan.sweep"):
+                            plan = plan_sweep(
+                                self.store, datasets, max_ranges,
+                                row_counts, scale=scale, seed=seed,
+                                pairs=remaining, n_devices=n_devices,
+                                host_index=host_index, n_hosts=n_hosts,
+                                chunk_s=chunk_s, duration_s=duration_s)
+            new_reports: List[SimulationReport] = []
+            self.last_placement = {}
+            if plan is not None:
+                if chunk_s:
+                    runner = engine.ChunkedSweepRunner(
+                        plan, originals, self.store, backend=backend,
+                        checkpoint=ckpt, autotune=autotune)
+                    new_reports, fidelity = engine.run_sweep_chunked(
+                        runner, consumer, queue_size=queue_size,
+                        fidelity_window_s=fidelity_window_s, t_pre=t_pre,
+                        fault_plan=fault_plan, on_failure=on_failure,
+                        max_bytes=max_bytes,
+                        retention_policy=retention_policy, checkpoint=ckpt)
+                else:
+                    result = engine.execute_sweep(
+                        plan, originals, self.store, backend=backend,
+                        checkpoint=ckpt, autotune=autotune)
+                    self.last_placement = result.placement()
+                    new_reports, fidelity = engine.run_sweep(
+                        result, consumer, queue_size=queue_size,
+                        fidelity_window_s=fidelity_window_s, t_pre=t_pre,
+                        fault_plan=fault_plan, retry_policy=retry_policy,
+                        breaker_threshold=breaker_threshold,
+                        consumer_deadline_s=consumer_deadline_s,
+                        on_failure=on_failure, max_bytes=max_bytes,
+                        retention_policy=retention_policy, checkpoint=ckpt)
+                if not chunk_s and plan.n_hosts > 1:
+                    # PR 5 gap closed: publish this host's exact count
+                    # rows into the shared store and, once every host's
+                    # rows are there, replace the partial per-host
+                    # matrices with the merged FULL S×S matrix (the last
+                    # host to finish — and any later re-run — sees the
+                    # complete artifact)
+                    merged = self._publish_and_merge_fidelity(
+                        result, plan, fidelity_window_s)
+                    if merged is not None:
+                        fidelity = merged
+                self.last_fidelity = fidelity
+                with obs.span("engine.report"):
+                    for fr in fidelity:
+                        self.save_fidelity(fr)
+            by_sc = dict(prior)
+            by_sc.update({(r.dataset, r.max_range): r for r in new_reports})
+            reports = [by_sc[sc] for sc in grid]
+            _carry_totals(reports, rec)
+            for report in reports:
+                self.save_metrics(report)
+            if ckpt is not None:
+                ckpt.clear()     # sweep complete: the next run starts fresh
+            return reports
 
     def _run_service(self, datasets, max_ranges, originals, t_pre,
                      consumer, *, scale, seed, queue_size, backend,
@@ -480,8 +493,8 @@ class Controller:
                      fault_plan, retry_policy, breaker_threshold,
                      consumer_deadline_s, on_failure, max_bytes,
                      retention_policy, lease_ttl_s, service_poll_s,
-                     lease_batch, worker_id,
-                     service_deadline_s) -> List[SimulationReport]:
+                     lease_batch, worker_id, service_deadline_s,
+                     recorder: obs.Recorder) -> List[SimulationReport]:
         """The ``run_many(service=True)`` leg: one participant of the
         lease-based sweep service. Every participant gets the full
         grid's merged reports back; only the reports THIS worker
@@ -511,9 +524,11 @@ class Controller:
             on_failure=on_failure, max_bytes=max_bytes,
             retention_policy=retention_policy)
         self.last_fidelity = fidelity
-        for fr in fidelity:
-            self.save_fidelity(fr)
+        with obs.span("engine.report"):
+            for fr in fidelity:
+                self.save_fidelity(fr)
         from repro.streamsim.service import scenario_marker
+        _carry_totals(reports, recorder)
         own = set(mine)
         for report in reports:
             if scenario_marker(report.dataset, report.max_range) in own:
@@ -609,6 +624,16 @@ class Controller:
             with open(p) as f:
                 out.append(json.load(f))
         return out
+
+
+def _carry_totals(reports: List[SimulationReport],
+                  rec: obs.Recorder) -> None:
+    """Give every report of a ``run_many`` call the call's span totals
+    and counters, as every report of a sweep carries the shared
+    ``nsa_s``."""
+    spans, counts = rec.spans(), rec.counts()
+    for r in reports:
+        r.spans, r.counts = dict(spans), dict(counts)
 
 
 def _np_default(o):

@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.streamsim.faults import FaultPlan
 from repro.streamsim.metrics import (StreamMetrics, Volatility,
                                      _volatility_from_moments,
@@ -101,6 +102,11 @@ class SimulationReport:
     #: by request, off the chip, or after a domain fallback); None for a
     #: quarantine stub no sweep produced
     mode: Optional[str] = None
+    #: seconds per span name (:mod:`repro.obs`) over the whole
+    #: ``run_many`` call, and its counters — like ``nsa_s``, every report
+    #: of the call carries the call's totals
+    spans: Dict[str, float] = dataclasses.field(default_factory=dict)
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def to_json(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -176,7 +182,6 @@ class ShardResult:
     totals: np.ndarray       # (R,) int64 host
     hist: object             # (R, max_range) int32 device
     mom: np.ndarray          # (R, 2) float64 host
-    nsa_s: float
 
 
 class DeviceSweepResult:
@@ -531,19 +536,21 @@ class DeviceSweepResult:
         store = self.store if store is None else store
         if self._sims is None:
             sims: Dict[Tuple[str, int], Stream] = dict(self.host_sims)
-            for sr in self.shard_results:
-                if sr.ss_kept is None:
-                    # chunked run: the per-record handles were consumed
-                    # chunk by chunk and the streams are already durable —
-                    # reassemble from the store's chunk files (this loads
-                    # everything to host; bounded-memory callers use
-                    # ``sim_row_counts`` instead of calling materialize)
-                    for sc in sr.pairs:
-                        sims[sc] = self.store.get(self._store_keys[sc])
-                else:
-                    sims.update(materialize_sweep(
-                        self.originals, list(sr.pairs), sr.ss_kept, sr.idx,
-                        sr.totals))
+            with obs.span("engine.materialize"):
+                for sr in self.shard_results:
+                    if sr.ss_kept is None:
+                        # chunked run: the per-record handles were
+                        # consumed chunk by chunk and the streams are
+                        # already durable — reassemble from the store's
+                        # chunk files (this loads everything to host;
+                        # bounded-memory callers use ``sim_row_counts``
+                        # instead of calling materialize)
+                        for sc in sr.pairs:
+                            sims[sc] = self.store.get(self._store_keys[sc])
+                    else:
+                        sims.update(materialize_sweep(
+                            self.originals, list(sr.pairs), sr.ss_kept,
+                            sr.idx, sr.totals))
             self._sims = {sc: sims[sc] for sc in self.scenarios}
         if store and not self._persisted:
             shard_scs = [sc for sr in self.shard_results
@@ -622,26 +629,27 @@ def _execute_device(plan, originals, store, backend, multiple_mode,
             for shard in plan.shards:
                 pairs = tuple(s.scenario for s in shard.specs)
                 dev = devices[shard.device_index % len(devices)]
-                t0 = time.perf_counter()
-                ss_kept, idx, totals, _ = nsa_sweep_device(
-                    originals, pairs, multiple_mode=multiple_mode,
-                    device=dev)
-                # compaction packed every row's kept stamps to the front,
-                # so the metrics dispatch only needs the kept-width column
-                # slice (device slice — kept counts are far below the
-                # padded source width after compression)
-                n_kept = int(-(-max(int(totals.max(initial=1)), 1)
-                               // ops.TILE) * ops.TILE)
-                hist, mom = ops.stream_metrics_batched_device(
-                    ss_kept[:, :min(n_kept, ss_kept.shape[1])], totals,
-                    shard.max_range)
-                mom_host = np.asarray(mom, np.float64)  # O(rows) scalars
-                dt = time.perf_counter() - t0
-                total_nsa += dt
+                with obs.span("nsa.leg") as leg:
+                    ss_kept, idx, totals, _ = nsa_sweep_device(
+                        originals, pairs, multiple_mode=multiple_mode,
+                        device=dev)
+                    # compaction packed every row's kept stamps to the
+                    # front, so the metrics dispatch only needs the
+                    # kept-width column slice (device slice — kept counts
+                    # are far below the padded source width after
+                    # compression)
+                    n_kept = int(-(-max(int(totals.max(initial=1)), 1)
+                                   // ops.TILE) * ops.TILE)
+                    hist, mom = ops.stream_metrics_batched_device(
+                        ss_kept[:, :min(n_kept, ss_kept.shape[1])], totals,
+                        shard.max_range)
+                    with obs.span("nsa.device_wait"):
+                        mom_host = np.asarray(mom, np.float64)  # O(rows)
+                total_nsa += leg.seconds
                 result.shard_results.append(ShardResult(
                     shard=shard, pairs=pairs, ss_kept=ss_kept, idx=idx,
                     totals=np.asarray(totals, np.int64), hist=hist,
-                    mom=mom_host, nsa_s=dt))
+                    mom=mom_host))
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("sweep", err)
         return None   # out-of-domain scenario: host mode, wholesale
@@ -665,12 +673,11 @@ def _execute_host(plan, originals, store, backend, multiple_mode,
     """The host path — the exact pre-plan ``run_many`` composition."""
     result = DeviceSweepResult(plan, originals, store, backend, "host",
                                autotune=autotune)
-    t0 = time.perf_counter()
-    for spec in plan.local_missing:
-        result.host_sims[spec.scenario] = nsa(
-            originals[spec.dataset], spec.max_range,
-            multiple_mode=multiple_mode, backend="numpy")
-    t_sweep = time.perf_counter() - t0
+    with obs.span("nsa.leg") as leg:
+        for spec in plan.local_missing:
+            result.host_sims[spec.scenario] = nsa(
+                originals[spec.dataset], spec.max_range,
+                multiple_mode=multiple_mode, backend="numpy")
     if store:
         for spec in plan.local_missing:
             store.put(spec.store_key, result.host_sims[spec.scenario],
@@ -679,15 +686,16 @@ def _execute_host(plan, originals, store, backend, multiple_mode,
         result.host_sims[spec.scenario] = store.get(spec.store_key)
     for spec in plan.scenarios:
         result.nsa_s[spec.scenario] = \
-            0.0 if spec.cached else t_sweep
+            0.0 if spec.cached else leg.seconds
     scenarios = [sc for sc in (s.scenario for s in plan.scenarios)
                  if sc in result.host_sims]
     datasets = list(plan.datasets)
-    ms = metrics_batched(
-        [originals[d] for d in datasets] +
-        [result.host_sims[sc] for sc in scenarios],
-        [None] * len(datasets) + [mr for _, mr in scenarios],
-        backend=backend)
+    with obs.span("engine.stats"):
+        ms = metrics_batched(
+            [originals[d] for d in datasets] +
+            [result.host_sims[sc] for sc in scenarios],
+            [None] * len(datasets) + [mr for _, mr in scenarios],
+            backend=backend)
     result._om = dict(zip(datasets, ms[:len(datasets)]))
     result.sm = dict(zip(scenarios, ms[len(datasets):]))
     result._host_group_done = True   # one dispatch covered everything
@@ -704,21 +712,20 @@ def replay_one(sim: Stream, consumer, queue_size: int, faults=None):
     FaultInjector` schedule to the producer."""
     queue = StreamQueue(maxsize=queue_size)
     producer = Producer(sim, queue, clock=VirtualClock(), faults=faults)
-    t0 = time.perf_counter()
     status = [None]
 
     def _produce():
         status[0] = producer.run()
 
-    th = threading.Thread(target=_produce, daemon=True)
-    th.start()
-    consumer_metrics = consumer(queue)
-    th.join()
-    t_prod = time.perf_counter() - t0
+    with obs.span("replay.loop") as loop:
+        th = threading.Thread(target=_produce, daemon=True)
+        th.start()
+        consumer_metrics = consumer(queue)
+        th.join()
     if status[0] != 0:
         raise RuntimeError("producer reported fault status")
     return ({**consumer_metrics, **queue.stats(), **producer.stats()},
-            t_prod)
+            loop.seconds)
 
 
 def consumer_label(consumer) -> Optional[str]:
@@ -862,41 +869,41 @@ def replay_many(sims: Dict, consumer, queue_size: int, *,
             for _ in group[key]:
                 pass
 
-    t0 = time.perf_counter()
-    prod_th = threading.Thread(target=_produce, daemon=True)
-    cons = {key: threading.Thread(target=_consume, args=(key,),
-                                  daemon=True) for key in sims}
-    prod_th.start()
-    for th in cons.values():
-        th.start()
-    deadline = Deadline(consumer_deadline_s)
-    for th in cons.values():
-        th.join(deadline.remaining())    # None remaining == join forever
-    for key, th in cons.items():
-        if not th.is_alive():
-            continue
-        q = group[key]
-        if q.qsize() > 0 or q.closed:
-            # wedged: buckets available (or stream over) yet not
-            # finishing — shed it so the walk and its siblings complete
-            errors[key] = _deadline_error(consumer_deadline_s, key,
-                                          wrapped[key])
-            q.close()
-    prod_th.join()
-    # post-shed grace: starved consumers (empty queue behind the wedged
-    # sibling's backpressure) finish quickly once the producer resumed;
-    # already-errored (wedged) threads are abandoned, not re-joined
-    grace = Deadline(5.0 if consumer_deadline_s is not None else None)
-    for key, th in cons.items():
-        if key in errors:
-            continue
-        if th.is_alive():
-            th.join(grace.remaining())
-        if th.is_alive():
-            errors[key] = _deadline_error(consumer_deadline_s, key,
-                                          wrapped[key])
-            group[key].close()
-    t_prod = time.perf_counter() - t0
+    with obs.span("replay.loop") as loop:
+        prod_th = threading.Thread(target=_produce, daemon=True)
+        cons = {key: threading.Thread(target=_consume, args=(key,),
+                                      daemon=True) for key in sims}
+        prod_th.start()
+        for th in cons.values():
+            th.start()
+        deadline = Deadline(consumer_deadline_s)
+        for th in cons.values():
+            th.join(deadline.remaining())    # None remaining == join forever
+        for key, th in cons.items():
+            if not th.is_alive():
+                continue
+            q = group[key]
+            if q.qsize() > 0 or q.closed:
+                # wedged: buckets available (or stream over) yet not
+                # finishing — shed it so the walk and its siblings complete
+                errors[key] = _deadline_error(consumer_deadline_s, key,
+                                              wrapped[key])
+                q.close()
+        prod_th.join()
+        # post-shed grace: starved consumers (empty queue behind the wedged
+        # sibling's backpressure) finish quickly once the producer resumed;
+        # already-errored (wedged) threads are abandoned, not re-joined
+        grace = Deadline(5.0 if consumer_deadline_s is not None else None)
+        for key, th in cons.items():
+            if key in errors:
+                continue
+            if th.is_alive():
+                th.join(grace.remaining())
+            if th.is_alive():
+                errors[key] = _deadline_error(consumer_deadline_s, key,
+                                              wrapped[key])
+                group[key].close()
+    t_prod = loop.seconds
 
     # ---- phase 2: solo retries with backoff, behind the breaker
     attempts = {key: 1 for key in errors}
@@ -1050,8 +1057,9 @@ def run_sweep(result: DeviceSweepResult, consumer, *,
     publish raw count rows instead and the merger owns the matrix).
     """
     t_pre = t_pre or {}
-    fid = result.fidelity(fidelity_window_s) if fidelity else []
-    result._ensure_stats()        # device stats before the host pass
+    with obs.span("engine.stats"):
+        fid = result.fidelity(fidelity_window_s) if fidelity else []
+        result._ensure_stats()    # device stats before the host pass
     sims = result.materialize()
     all_metrics, t_prod = replay_many(
         sims, consumer, queue_size, fault_plan=fault_plan,
@@ -1059,14 +1067,15 @@ def run_sweep(result: DeviceSweepResult, consumer, *,
         consumer_deadline_s=consumer_deadline_s, on_failure=on_failure,
         max_bytes=max_bytes, retention_policy=retention_policy)
     reports = []
-    for sc in result.scenarios:
-        r = build_report(result, sc, t_pre.get(sc[0], 0.0), t_prod,
-                         all_metrics[sc])
-        if checkpoint is not None:
-            checkpoint.mark_report(r)     # marker lands per report, so a
-        if on_report is not None:
-            on_report(r)
-        reports.append(r)                 # kill leaves a clean prefix
+    with obs.span("engine.report"):
+        for sc in result.scenarios:
+            r = build_report(result, sc, t_pre.get(sc[0], 0.0), t_prod,
+                             all_metrics[sc])
+            if checkpoint is not None:
+                checkpoint.mark_report(r)     # marker lands per report,
+            if on_report is not None:
+                on_report(r)
+            reports.append(r)                 # so a kill leaves a prefix
     return reports, fid
 
 
@@ -1155,22 +1164,23 @@ class ChunkedSweepRunner:
 
         from repro.kernels import ops
 
-        devices = jax.local_devices()
-        for shard in self.plan.shards:
-            dev = devices[shard.device_index % len(devices)]
-            cn = ChunkedNSA(
-                self.originals,
-                [(s.dataset, s.span_s) for s in shard.specs],
-                multiple_mode=self.multiple_mode, device=dev,
-                autotune=self.autotune)
-            self._shard_states.append({
-                "shard": shard,
-                "nsa": cn,
-                "carry": ops.chunk_carry_init(
-                    len(shard.specs), cn.width,
-                    window=REPORT_TREND_WINDOW_S),
-                "totals": np.zeros(len(shard.specs), np.int64),
-            })
+        with obs.span("chunk.prep"):
+            devices = jax.local_devices()
+            for shard in self.plan.shards:
+                dev = devices[shard.device_index % len(devices)]
+                cn = ChunkedNSA(
+                    self.originals,
+                    [(s.dataset, s.span_s) for s in shard.specs],
+                    multiple_mode=self.multiple_mode, device=dev,
+                    autotune=self.autotune)
+                self._shard_states.append({
+                    "shard": shard,
+                    "nsa": cn,
+                    "carry": ops.chunk_carry_init(
+                        len(shard.specs), cn.width,
+                        window=REPORT_TREND_WINDOW_S),
+                    "totals": np.zeros(len(shard.specs), np.int64),
+                })
 
     # ------------------------------------------------------------- pipeline
     def run(self, feeds: Optional[Dict[Tuple[str, int], ChunkFeed]] = None
@@ -1221,7 +1231,8 @@ class ChunkedSweepRunner:
     def _feed_chunk(self, feeds, spec, k: int, chunk: Stream) -> None:
         if feeds is None or spec.scenario not in feeds:
             return
-        feeds[spec.scenario].put(chunk)
+        with obs.span("chunk.feed_wait"):
+            feeds[spec.scenario].put(chunk)
         if k == spec.n_chunks - 1:
             feeds[spec.scenario].close()
 
@@ -1259,82 +1270,83 @@ class ChunkedSweepRunner:
         result = DeviceSweepResult(plan, self.originals, self.store,
                                    self.backend, "device")
         result.checkpoint = self.checkpoint
-        t0 = time.perf_counter()
-        for spec in plan.cached:
-            result.host_sims[spec.scenario] = \
-                self.store.get(spec.store_key)
-        cached = [s for s in plan.scenarios
-                  if s.scenario in result.host_sims]
+        with obs.span("chunk.pipeline") as pipeline:
+            for spec in plan.cached:
+                result.host_sims[spec.scenario] = \
+                    self.store.get(spec.store_key)
+            cached = [s for s in plan.scenarios
+                      if s.scenario in result.host_sims]
 
-        def _dispatch(k: int) -> List[Tuple[Dict, object]]:
-            out = []
-            for st in self._shard_states:
-                lo = k * self.chunk_s
-                hi = min(lo + self.chunk_s, st["nsa"].width)
-                if lo >= hi:
-                    continue          # this shard's timeline is over
-                h = st["nsa"].chunk(lo, hi)
-                st["carry"] = ops.stream_metrics_chunk(
-                    st["carry"], h.ss_kept, h.totals, lo, hi)
-                out.append((st, h))
-            return out
+            def _dispatch(k: int) -> List[Tuple[Dict, object]]:
+                out = []
+                for st in self._shard_states:
+                    lo = k * self.chunk_s
+                    hi = min(lo + self.chunk_s, st["nsa"].width)
+                    if lo >= hi:
+                        continue          # this shard's timeline is over
+                    h = st["nsa"].chunk(lo, hi)
+                    st["carry"] = ops.stream_metrics_chunk(
+                        st["carry"], h.ss_kept, h.totals, lo, hi)
+                    out.append((st, h))
+                return out
 
-        def _host_leg(handles, k: int) -> None:
-            for st, h in handles:
-                # the ONE sync per (shard, chunk) — chunk k+1's dispatch
-                # is already in flight when this blocks
-                totals = np.asarray(h.totals, np.int64)
-                chunks = materialize_sweep_chunk(
-                    self.originals, st["nsa"].pairs, h, totals)
-                for r, spec in enumerate(st["shard"].specs):
-                    if k >= spec.n_chunks:
-                        continue
-                    st["totals"][r] += int(totals[r])
-                    if self.store:
-                        self.store.append_chunk(spec.store_key, k,
-                                                chunks[r])
-                        self._note_chunk(spec.store_key, chunks[r])
-                    self._feed_chunk(feeds, spec, k, chunks[r])
-            self._host_round(result, feeds, k, cached)
+            def _host_leg(handles, k: int) -> None:
+                for st, h in handles:
+                    # the ONE sync per (shard, chunk) — chunk k+1's
+                    # dispatch is already in flight when this blocks
+                    with obs.span("chunk.device_wait"):
+                        totals = np.asarray(h.totals, np.int64)
+                    with obs.span("engine.materialize"):
+                        chunks = materialize_sweep_chunk(
+                            self.originals, st["nsa"].pairs, h, totals)
+                    for r, spec in enumerate(st["shard"].specs):
+                        if k >= spec.n_chunks:
+                            continue
+                        st["totals"][r] += int(totals[r])
+                        if self.store:
+                            self.store.append_chunk(spec.store_key, k,
+                                                    chunks[r])
+                            self._note_chunk(spec.store_key, chunks[r])
+                        self._feed_chunk(feeds, spec, k, chunks[r])
+                self._host_round(result, feeds, k, cached)
 
-        # the double-buffered loop: dispatch k, THEN drain k-1's host leg
-        prev: Optional[Tuple[List, int]] = None
-        for k in range(plan.n_chunks):
-            cur = _dispatch(k)
+            # the double-buffered loop: dispatch k, THEN drain k-1's host leg
+            prev: Optional[Tuple[List, int]] = None
+            for k in range(plan.n_chunks):
+                cur = _dispatch(k)
+                if prev is not None:
+                    _host_leg(*prev)
+                prev = (cur, k)
             if prev is not None:
                 _host_leg(*prev)
-            prev = (cur, k)
-        if prev is not None:
-            _host_leg(*prev)
 
-        # compose: fold each shard's carry into monolithic-shaped stats
-        for st in self._shard_states:
-            hist, mom2 = ops.chunk_carry_finalize(st["carry"])
-            result.shard_results.append(ShardResult(
-                shard=st["shard"],
-                pairs=tuple(s.scenario for s in st["shard"].specs),
-                ss_kept=None, idx=None, totals=st["totals"].copy(),
-                hist=hist, mom=np.asarray(mom2, np.float64), nsa_s=0.0))
-        if self.store:
+            # compose: fold each shard's carry into monolithic-shaped stats
             for st in self._shard_states:
-                for spec in st["shard"].specs:
-                    self.store.finalize_chunks(
-                        spec.store_key,
-                        name=self.originals[spec.dataset].name,
-                        n_chunks=spec.n_chunks,
-                        extra_meta={"max_range": spec.max_range},
-                        stats=self._manifest_stats(spec.store_key))
-            result._persisted = True
-            if self.checkpoint is not None:
-                self.checkpoint.mark_materialized(
-                    [s.scenario for s in plan.local_missing])
-        total_s = time.perf_counter() - t0
+                hist, mom2 = ops.chunk_carry_finalize(st["carry"])
+                result.shard_results.append(ShardResult(
+                    shard=st["shard"],
+                    pairs=tuple(s.scenario for s in st["shard"].specs),
+                    ss_kept=None, idx=None, totals=st["totals"].copy(),
+                    hist=hist, mom=np.asarray(mom2, np.float64)))
+            if self.store:
+                for st in self._shard_states:
+                    for spec in st["shard"].specs:
+                        self.store.finalize_chunks(
+                            spec.store_key,
+                            name=self.originals[spec.dataset].name,
+                            n_chunks=spec.n_chunks,
+                            extra_meta={"max_range": spec.max_range},
+                            stats=self._manifest_stats(spec.store_key))
+                result._persisted = True
+                if self.checkpoint is not None:
+                    self.checkpoint.mark_materialized(
+                        [s.scenario for s in plan.local_missing])
         for sc in (s.scenario for s in plan.scenarios):
             result.nsa_s[sc] = 0.0
         result.sim_row_counts = {}
         for sr in result.shard_results:
             for r, sc in enumerate(sr.pairs):
-                result.nsa_s[sc] = total_s
+                result.nsa_s[sc] = pipeline.seconds
                 result.sim_row_counts[sc] = int(sr.totals[r])
         for spec in plan.cached:
             result.sim_row_counts[spec.scenario] = \
@@ -1346,42 +1358,44 @@ class ChunkedSweepRunner:
         result = DeviceSweepResult(plan, self.originals, self.store,
                                    self.backend, "host")
         result.checkpoint = self.checkpoint
-        t0 = time.perf_counter()
-        for spec in plan.local_missing:
-            result.host_sims[spec.scenario] = nsa(
-                self.originals[spec.dataset], spec.span_s,
-                multiple_mode=self.multiple_mode, backend="numpy")
-        t_sweep = time.perf_counter() - t0
-        for spec in plan.cached:
-            result.host_sims[spec.scenario] = \
-                self.store.get(spec.store_key)
-        local = [s for s in plan.scenarios
-                 if s.scenario in result.host_sims]
-        for k in range(plan.n_chunks):
-            self._host_round(result, feeds, k, local)
-        if self.store:
-            for spec in plan.local_missing:
-                self.store.finalize_chunks(
-                    spec.store_key,
-                    name=result.host_sims[spec.scenario].name,
-                    n_chunks=spec.n_chunks,
-                    extra_meta={"max_range": spec.max_range},
-                    stats=self._manifest_stats(spec.store_key))
-            result._persisted = True
-            if self.checkpoint is not None:
-                self.checkpoint.mark_materialized(
-                    [s.scenario for s in plan.local_missing])
+        with obs.span("chunk.pipeline") as pipeline:
+            with obs.span("nsa.leg"):
+                for spec in plan.local_missing:
+                    result.host_sims[spec.scenario] = nsa(
+                        self.originals[spec.dataset], spec.span_s,
+                        multiple_mode=self.multiple_mode, backend="numpy")
+            for spec in plan.cached:
+                result.host_sims[spec.scenario] = \
+                    self.store.get(spec.store_key)
+            local = [s for s in plan.scenarios
+                     if s.scenario in result.host_sims]
+            for k in range(plan.n_chunks):
+                self._host_round(result, feeds, k, local)
+            if self.store:
+                for spec in plan.local_missing:
+                    self.store.finalize_chunks(
+                        spec.store_key,
+                        name=result.host_sims[spec.scenario].name,
+                        n_chunks=spec.n_chunks,
+                        extra_meta={"max_range": spec.max_range},
+                        stats=self._manifest_stats(spec.store_key))
+                result._persisted = True
+                if self.checkpoint is not None:
+                    self.checkpoint.mark_materialized(
+                        [s.scenario for s in plan.local_missing])
         for spec in plan.scenarios:
-            result.nsa_s[spec.scenario] = 0.0 if spec.cached else t_sweep
+            result.nsa_s[spec.scenario] = \
+                0.0 if spec.cached else pipeline.seconds
         scenarios = [sc for sc in (s.scenario for s in plan.scenarios)
                      if sc in result.host_sims]
         datasets = list(plan.datasets)
-        ms = metrics_batched(
-            [self.originals[d] for d in datasets] +
-            [result.host_sims[sc] for sc in scenarios],
-            [None] * len(datasets) +
-            [self._specs[sc].span_s for sc in scenarios],
-            backend=self.backend)
+        with obs.span("engine.stats"):
+            ms = metrics_batched(
+                [self.originals[d] for d in datasets] +
+                [result.host_sims[sc] for sc in scenarios],
+                [None] * len(datasets) +
+                [self._specs[sc].span_s for sc in scenarios],
+                backend=self.backend)
         result._om = dict(zip(datasets, ms[:len(datasets)]))
         result.sm = dict(zip(scenarios, ms[len(datasets):]))
         result._host_group_done = True
@@ -1450,18 +1464,19 @@ def run_sweep_chunked(runner: ChunkedSweepRunner, consumer, *,
             for _ in group[sc]:
                 pass
 
-    t0 = time.perf_counter()
-    prod_th = threading.Thread(target=_produce, daemon=True)
-    cons = {sc: threading.Thread(target=_consume, args=(sc,), daemon=True)
-            for sc in scenarios}
-    prod_th.start()
-    for th in cons.values():
-        th.start()
-    result = runner.run(feeds)       # the chunk pipeline, on THIS thread
-    prod_th.join()
-    for th in cons.values():
-        th.join()
-    t_prod = time.perf_counter() - t0
+    with obs.span("replay.loop") as loop:
+        prod_th = threading.Thread(target=_produce, daemon=True)
+        cons = {sc: threading.Thread(target=_consume, args=(sc,),
+                                     daemon=True)
+                for sc in scenarios}
+        prod_th.start()
+        for th in cons.values():
+            th.start()
+        result = runner.run(feeds)   # the chunk pipeline, on THIS thread
+        prod_th.join()
+        for th in cons.values():
+            th.join()
+    t_prod = loop.seconds
     if errors and on_failure == "raise":
         ordered = [(sc, errors[sc]) for sc in scenarios if sc in errors]
         detail = "; ".join(f"{sc!r}: {exc!r}" for sc, exc in ordered)
@@ -1480,13 +1495,15 @@ def run_sweep_chunked(runner: ChunkedSweepRunner, consumer, *,
         else:
             all_metrics[sc] = {**results[sc], **group[sc].stats(),
                                **producer.stats(sc)}
-    fidelity = result.fidelity(fidelity_window_s)
-    result._ensure_stats()
+    with obs.span("engine.stats"):
+        fidelity = result.fidelity(fidelity_window_s)
+        result._ensure_stats()
     reports = []
-    for sc in result.scenarios:
-        r = build_report(result, sc, t_pre.get(sc[0], 0.0), t_prod,
-                         all_metrics[sc])
-        if checkpoint is not None:
-            checkpoint.mark_report(r)
-        reports.append(r)
+    with obs.span("engine.report"):
+        for sc in result.scenarios:
+            r = build_report(result, sc, t_pre.get(sc[0], 0.0), t_prod,
+                             all_metrics[sc])
+            if checkpoint is not None:
+                checkpoint.mark_report(r)
+            reports.append(r)
     return reports, fidelity

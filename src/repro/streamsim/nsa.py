@@ -72,6 +72,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.streamsim.preprocess import Stream
 
 BACKENDS = ("auto", "numpy", "pallas")
@@ -564,13 +565,14 @@ class ChunkedNSA:
         counts_b = np.empty((R, self.width), np.int32)
         k_b = np.empty((R, self.width), np.int32)
         scal_b = np.empty((R, 3), np.float32)
-        for r, t64 in enumerate(ts):
-            t32, starts, counts, ktab, scalars = ops._nsa_tables(
-                t64, self.pairs[r][1], float(mults[r]), self.width)
-            t_b[r, :len(t32)] = t32
-            t_b[r, len(t32):] = t32[-1]      # pad into the last bucket
-            starts_b[r], counts_b[r], k_b[r] = starts, counts, ktab
-            scal_b[r] = scalars
+        with obs.span("nsa.tables"):
+            for r, t64 in enumerate(ts):
+                t32, starts, counts, ktab, scalars = ops._nsa_tables(
+                    t64, self.pairs[r][1], float(mults[r]), self.width)
+                t_b[r, :len(t32)] = t32
+                t_b[r, len(t32):] = t32[-1]  # pad into the last bucket
+                starts_b[r], counts_b[r], k_b[r] = starts, counts, ktab
+                scal_b[r] = scalars
         # host copy for slicing: col lo gives the first record of bucket
         # lo (tail buckets carry starts = n, so rows whose range ends
         # before the sweep's maximum contribute empty slices for free)

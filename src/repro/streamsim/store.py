@@ -50,6 +50,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.streamsim.preprocess import Stream
 
 _MANIFEST = "manifest.json"
@@ -77,39 +78,41 @@ class StreamStore:
     # ------------------------------------------------------------------- put
     def put(self, key: str, stream: Stream,
             extra_meta: Optional[Dict] = None) -> None:
-        d = self._dir(key)
-        d.mkdir(parents=True, exist_ok=True)
-        arrays: Dict[str, np.ndarray] = {"__t__": stream.t}
-        if stream.scale_stamp is not None:
-            arrays["__scale_stamp__"] = stream.scale_stamp
-        for k, v in stream.payload.items():
-            arrays[f"c:{k}"] = v
-        # atomic write: tmp file in the same dir, then rename
-        fd, tmp = tempfile.mkstemp(dir=d, suffix=".npz.tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                np.savez(f, **arrays)
-            os.replace(tmp, d / _COLUMNS)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-        manifest = {
-            "name": stream.name,
-            "rows": len(stream),
-            "has_scale_stamp": stream.scale_stamp is not None,
-            "time_range_s": stream.time_range,
-            "nbytes": stream.nbytes(),
-            "written_at": time.time(),
-            "extra": extra_meta or {},
-        }
-        fd, tmp = tempfile.mkstemp(dir=d, suffix=".json.tmp")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(manifest, f, indent=2)
-            os.replace(tmp, d / _MANIFEST)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        with obs.span("store.write"):
+            d = self._dir(key)
+            d.mkdir(parents=True, exist_ok=True)
+            arrays: Dict[str, np.ndarray] = {"__t__": stream.t}
+            if stream.scale_stamp is not None:
+                arrays["__scale_stamp__"] = stream.scale_stamp
+            for k, v in stream.payload.items():
+                arrays[f"c:{k}"] = v
+            # atomic write: tmp file in the same dir, then rename
+            fd, tmp = tempfile.mkstemp(dir=d, suffix=".npz.tmp")
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    np.savez(f, **arrays)
+                os.replace(tmp, d / _COLUMNS)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+            manifest = {
+                "name": stream.name,
+                "rows": len(stream),
+                "has_scale_stamp": stream.scale_stamp is not None,
+                "time_range_s": stream.time_range,
+                "nbytes": stream.nbytes(),
+                "written_at": time.time(),
+                "extra": extra_meta or {},
+            }
+            fd, tmp = tempfile.mkstemp(dir=d, suffix=".json.tmp")
+            try:
+                with os.fdopen(fd, "w") as f:
+                    json.dump(manifest, f, indent=2)
+                os.replace(tmp, d / _MANIFEST)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+        obs.count("store.bytes_written", stream.nbytes())
 
     def put_many(self, items: Dict[str, Stream],
                  extra_meta: Optional[Dict[str, Dict]] = None) -> None:
@@ -151,20 +154,22 @@ class StreamStore:
         target = self._chunk_file(d, chunk_idx)
         if target.exists() and not overwrite:
             return False
-        d.mkdir(parents=True, exist_ok=True)
-        arrays: Dict[str, np.ndarray] = {"__t__": stream.t}
-        if stream.scale_stamp is not None:
-            arrays["__scale_stamp__"] = stream.scale_stamp
-        for k, v in stream.payload.items():
-            arrays[f"c:{k}"] = v
-        fd, tmp = tempfile.mkstemp(dir=d, suffix=".npz.tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                np.savez(f, **arrays)
-            os.replace(tmp, target)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        with obs.span("store.write"):
+            d.mkdir(parents=True, exist_ok=True)
+            arrays: Dict[str, np.ndarray] = {"__t__": stream.t}
+            if stream.scale_stamp is not None:
+                arrays["__scale_stamp__"] = stream.scale_stamp
+            for k, v in stream.payload.items():
+                arrays[f"c:{k}"] = v
+            fd, tmp = tempfile.mkstemp(dir=d, suffix=".npz.tmp")
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    np.savez(f, **arrays)
+                os.replace(tmp, target)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+        obs.count("store.bytes_written", stream.nbytes())
         return True
 
     def has_chunk(self, key: str, chunk_idx: int) -> bool:
@@ -197,53 +202,60 @@ class StreamStore:
         sweep runner's hot path. Without it, the chunk files are read
         back (the standalone / recovery path).
         """
-        d = self._dir(key)
-        have = set(self.list_chunks(key))
-        missing = [i for i in range(n_chunks) if i not in have]
-        if missing:
-            raise ValueError(
-                f"cannot finalize {key!r}: missing chunk(s) {missing[:8]}")
-        if stats is not None:
-            rows = int(stats["rows"])
-            nbytes = int(stats["nbytes"])
-            time_range_s = float(stats["time_range_s"])
-        else:
-            rows = 0
-            nbytes = 0
-            t_first = t_last = None
-            for i in range(n_chunks):
-                with np.load(self._chunk_file(d, i),
-                             allow_pickle=False) as z:
-                    t = z["__t__"]
-                    rows += len(t)
-                    nbytes += sum(int(z[k].nbytes) for k in z.files)
-                    if len(t):
-                        if t_first is None:
-                            t_first = float(t[0])
-                        t_last = float(t[-1])
-            time_range_s = ((t_last - t_first)
-                            if t_first is not None else 0.0)
-        manifest = {
-            "name": name,
-            "rows": rows,
-            "has_scale_stamp": True,
-            "time_range_s": time_range_s,
-            "nbytes": nbytes,
-            "written_at": time.time(),
-            "chunks": n_chunks,
-            "extra": extra_meta or {},
-        }
-        fd, tmp = tempfile.mkstemp(dir=d, suffix=".json.tmp")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(manifest, f, indent=2)
-            os.replace(tmp, d / _MANIFEST)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+        with obs.span("store.write"):
+            d = self._dir(key)
+            have = set(self.list_chunks(key))
+            missing = [i for i in range(n_chunks) if i not in have]
+            if missing:
+                raise ValueError(f"cannot finalize {key!r}: missing "
+                                 f"chunk(s) {missing[:8]}")
+            if stats is not None:
+                rows = int(stats["rows"])
+                nbytes = int(stats["nbytes"])
+                time_range_s = float(stats["time_range_s"])
+            else:
+                rows = 0
+                nbytes = 0
+                t_first = t_last = None
+                for i in range(n_chunks):
+                    with np.load(self._chunk_file(d, i),
+                                 allow_pickle=False) as z:
+                        t = z["__t__"]
+                        rows += len(t)
+                        nbytes += sum(int(z[k].nbytes) for k in z.files)
+                        if len(t):
+                            if t_first is None:
+                                t_first = float(t[0])
+                            t_last = float(t[-1])
+                time_range_s = ((t_last - t_first)
+                                if t_first is not None else 0.0)
+            manifest = {
+                "name": name,
+                "rows": rows,
+                "has_scale_stamp": True,
+                "time_range_s": time_range_s,
+                "nbytes": nbytes,
+                "written_at": time.time(),
+                "chunks": n_chunks,
+                "extra": extra_meta or {},
+            }
+            fd, tmp = tempfile.mkstemp(dir=d, suffix=".json.tmp")
+            try:
+                with os.fdopen(fd, "w") as f:
+                    json.dump(manifest, f, indent=2)
+                os.replace(tmp, d / _MANIFEST)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
 
     # ------------------------------------------------------------------- get
     def get(self, key: str) -> Stream:
+        with obs.span("store.read"):
+            stream = self._read(key)
+        obs.count("store.bytes_read", stream.nbytes())
+        return stream
+
+    def _read(self, key: str) -> Stream:
         d = self._dir(key)
         man = self.manifest(key)
         n_chunks = int(man.get("chunks", 0))
