@@ -389,11 +389,18 @@ def _scatter_kept(mask: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("width",))
 def slice_records(t: jnp.ndarray, a: jnp.ndarray, width: int) -> jnp.ndarray:
-    """``t[r, a[r] : a[r] + width]`` per row, clamped to the last column —
-    one program, so the index plane is never a live array."""
-    j = jnp.arange(width, dtype=jnp.int32)[None, :]
-    gidx = jnp.clip(a[:, None] + j, 0, t.shape[1] - 1)
-    return jnp.take_along_axis(t, gidx, axis=1)
+    """``t[r, min(a[r] + j, N - 1)]`` for ``j < width``: each row's window
+    of ``width`` columns from record offset ``a[r] >= 0``, the columns past
+    the row's end repeating its last value.
+
+    One contiguous copy per row (``R`` is static, so the loop unrolls):
+    the plane is edge-padded by ``width`` columns so that no window is
+    clamped back from the end, and XLA fuses the pad into the slices
+    instead of materializing it. A per-element gather would move the same
+    bytes one index at a time."""
+    tp = jnp.pad(t, ((0, 0), (0, width)), mode="edge")
+    return jnp.stack([jax.lax.dynamic_slice_in_dim(tp[r], a[r], width)
+                      for r in range(t.shape[0])])
 
 
 @jax.jit

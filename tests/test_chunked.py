@@ -444,6 +444,48 @@ class TestChunkedFaults:
             assert group[k].stats()["records_in"] == len(sim)
 
 
+# ------------------------------------------------------ chunk record slice
+def _clipped_gather(t, a, width):
+    """The oracle: ``t[r, clip(a[r] + j, 0, N - 1)]``, one index a column."""
+    j = np.arange(width)[None, :]
+    return np.take_along_axis(t, np.clip(a[:, None] + j, 0, t.shape[1] - 1),
+                              axis=1)
+
+
+class TestSliceRecords:
+    N = 40
+
+    @pytest.mark.parametrize("starts,width", [
+        ([0], 8),                        # R = 1, first window
+        ([N - 1], 8),                    # the last record, then padding
+        ([N], 8),                        # timeline over: all padding
+        ([0, N - 1, N], 16),
+        ([N - 5, 3, 17], 16),            # a + width > N, columns left
+        ([0, 7, N], N),                  # width = N
+        ([2, 0], N + 24),                # wider than the plane
+    ])
+    def test_matches_clipped_gather(self, starts, width):
+        from repro.kernels import ops
+        rng = np.random.default_rng(len(starts) * 100 + width)
+        t = np.sort(rng.random((len(starts), self.N)), axis=1) \
+            .astype(np.float32)
+        a = np.array(starts, np.int32)
+        got = np.asarray(ops.slice_records(t, a, width))
+        np.testing.assert_array_equal(got, _clipped_gather(t, a, width))
+
+    @pytest.mark.parametrize("rows", [1, 6])
+    def test_lowers_to_copies_not_a_gather(self, rows):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.kernels import ops
+        text = ops.slice_records.lower(
+            jax.ShapeDtypeStruct((rows, 4096), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32), 2048).as_text()
+        assert "dynamic_slice" in text
+        assert "gather" not in text
+
+
 # -------------------------------------------------------- regression gate
 def _load_check_regression():
     path = (Path(__file__).resolve().parent.parent / "benchmarks"

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.compact import (compact_positions_batched_pallas,
                                    compact_positions_pallas)
 from repro.kernels.metrics_fused import (stream_metrics_carry_pallas,
@@ -118,3 +119,19 @@ def test_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes +
             mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{kernel} needs {used} bytes of HBM"
+
+
+def test_slice_records_compiles_to_copies_for_v5e(one_chip,
+                                                  no_persistent_cache):
+    """The chunk pipeline's record slice at the multi-day stream's shape
+    (6 rows of ~21.2 M records, the widest chunk ~10.9 M): contiguous
+    copies, no per-element gather, and the edge-padded plane is never
+    materialized (its scratch stays under the output's size)."""
+    rows, n, width = 6, 21_218_304, 10_863_616
+    compiled = ops.slice_records.lower(
+        jax.ShapeDtypeStruct((rows, n), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+        width).compile()
+    assert "gather" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= mem.output_size_in_bytes
