@@ -141,7 +141,9 @@ def run(csv: List[str]) -> None:
     r_streams = _hetero_streams(8, base * 2, seed=11)
     r_names = list(r_streams)
     r_pairs = [(n, mr) for n in r_streams for mr in r_ranges]
-    ss_kept, _, totals, _ = nsa_sweep_device(r_streams, r_pairs)
+    ss_b, idx_b, totals, _ = nsa_sweep_device(r_streams, r_pairs)
+    totals = np.asarray(totals, np.int64)
+    ss_kept = ops.gather_kept(ss_b, idx_b)
     # compaction packs kept stamps to the front: the metrics dispatch (one
     # per path variant, identical shape — run in setup) reads only the
     # kept-width column slice, exactly as the engine does

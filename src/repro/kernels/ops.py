@@ -319,8 +319,8 @@ def compact_mask(mask: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
     return idx, int(total[0])
 
 
-def compact_mask_batched(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
-                                                     np.ndarray]:
+def compact_mask_batched_device(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
+                                                            jnp.ndarray]:
     """Kept-record indices for R stacked keep masks, ONE device dispatch.
 
     mask : (R, N) boolean/0-1 keep masks; rows may describe streams of
@@ -332,25 +332,14 @@ def compact_mask_batched(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
     whole (R, N) grid — replacing R sequential :func:`compact_mask`
     dispatches.
 
-    Returns ``(idx int32 (R, N), totals int64 (R,))``: ``idx[r, :totals[r]]``
-    are row ``r``'s set-entry indices in ascending order; the tail is the
-    sentinel ``N`` (the input width — TILE padding is internal and never
-    shows up in the output). Per row this matches :func:`compact_mask` on
-    that row exactly: same kept indices, same sentinel convention.
-    """
-    idx, totals = compact_mask_batched_device(mask)
-    return idx, np.asarray(totals, np.int64).reshape(-1)
-
-
-def compact_mask_batched_device(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
-                                                            jnp.ndarray]:
-    """:func:`compact_mask_batched` with the totals left ON DEVICE.
-
-    Same scan + scatter chain and the same ``idx`` contract, but the
-    per-row totals come back as an int32 device array instead of a host
-    int64 one — reading them would force a device sync, which the chunked
-    pipeline must NOT do at dispatch time (the host reads chunk ``k``'s
-    totals only after chunk ``k+1``'s dispatch is in flight).
+    Returns ``(idx int32 (R, N), totals int32 (R,))``, both on device:
+    ``idx[r, :totals[r]]`` are row ``r``'s set-entry indices in ascending
+    order; the tail is the sentinel ``N`` (the input width — TILE padding
+    is internal and never shows up in the output). Per row this matches
+    :func:`compact_mask` on that row exactly: same kept indices, same
+    sentinel convention. Nothing here waits for the device: reading the
+    totals does, which the sweep engine and the chunked pipeline defer
+    until every launch they can dispatch is in flight.
     """
     from repro.kernels.compact import compact_positions_batched_pallas
     mask = jnp.asarray(mask)
@@ -1297,7 +1286,7 @@ __all__ = [
     "ChunkCarry", "HostFallbackWarning", "KeepRuleOverflow",
     "PallasDomainError", "bucket_hist",
     "chunk_carry_finalize", "chunk_carry_init", "compact_mask",
-    "compact_mask_batched", "compact_mask_batched_device", "flash_decode",
+    "compact_mask_batched_device", "flash_decode",
     "on_accelerator", "on_gpu", "on_tpu",
     "stream_metrics", "stream_metrics_chunk", "trend_scan_chunk",
     "stream_metrics_batched", "stream_metrics_batched_device",

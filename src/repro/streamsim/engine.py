@@ -5,7 +5,8 @@ architecture:
 
 - **Execute** (:func:`execute_sweep`): runs every plan shard's NSA →
   metrics chain as ONE dispatch per kernel stage on that shard's device,
-  producing a :class:`DeviceSweepResult` whose kept-index sets and
+  every shard dispatched before the host reads any back, producing a
+  :class:`DeviceSweepResult` whose kept-index sets and
   per-second counts stay **device-resident** — the handle chains
   ``nsa_sweep_device`` straight into the fused metrics engine
   (``ops.stream_metrics_batched_device``) with no host round-trip, and
@@ -168,11 +169,11 @@ class FidelityReport:
 class ShardResult:
     """One shard's device-resident NSA + metrics output.
 
-    ``ss_kept``/``idx`` are the :func:`~repro.streamsim.nsa.
-    nsa_sweep_device` handles (still on the shard's device); ``hist`` is
-    the fused metrics engine's per-second count matrix, also
-    device-resident. Only ``totals`` and ``mom`` — O(rows) report
-    scalars — live on host.
+    ``idx`` is the :func:`~repro.streamsim.nsa.nsa_sweep_device` kept
+    indices and ``ss_kept`` the kept stamps gathered by them (both still
+    on the shard's device); ``hist`` is the fused metrics engine's
+    per-second count matrix, also device-resident. Only ``totals`` and
+    ``mom`` — O(rows) report scalars — live on host.
     """
 
     shard: Shard
@@ -616,40 +617,16 @@ def _execute_device(plan, originals, store, backend, multiple_mode,
                     autotune=None) -> Optional[DeviceSweepResult]:
     """The pallas path; returns None when a domain error demands the
     wholesale host fallback."""
-    import jax
-
     from repro.kernels import ops, tuning
 
     result = DeviceSweepResult(plan, originals, store, backend, "device",
                                autotune=autotune)
-    devices = jax.local_devices()
-    total_nsa = 0.0
     try:
         with tuning.tuner_context(autotune, store=store or None):
-            for shard in plan.shards:
-                pairs = tuple(s.scenario for s in shard.specs)
-                dev = devices[shard.device_index % len(devices)]
+            if plan.shards:
                 with obs.span("nsa.leg") as leg:
-                    ss_kept, idx, totals, _ = nsa_sweep_device(
-                        originals, pairs, multiple_mode=multiple_mode,
-                        device=dev)
-                    # compaction packed every row's kept stamps to the
-                    # front, so the metrics dispatch only needs the
-                    # kept-width column slice (device slice — kept counts
-                    # are far below the padded source width after
-                    # compression)
-                    n_kept = int(-(-max(int(totals.max(initial=1)), 1)
-                                   // ops.TILE) * ops.TILE)
-                    hist, mom = ops.stream_metrics_batched_device(
-                        ss_kept[:, :min(n_kept, ss_kept.shape[1])], totals,
-                        shard.max_range)
-                    with obs.span("nsa.device_wait"):
-                        mom_host = np.asarray(mom, np.float64)  # O(rows)
-                total_nsa += leg.seconds
-                result.shard_results.append(ShardResult(
-                    shard=shard, pairs=pairs, ss_kept=ss_kept, idx=idx,
-                    totals=np.asarray(totals, np.int64), hist=hist,
-                    mom=mom_host))
+                    result.shard_results = _run_shards(plan, originals,
+                                                       multiple_mode)
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("sweep", err)
         return None   # out-of-domain scenario: host mode, wholesale
@@ -664,8 +641,60 @@ def _execute_device(plan, originals, store, backend, multiple_mode,
         result.nsa_s[sc] = 0.0
     for sr in result.shard_results:
         for sc in sr.pairs:
-            result.nsa_s[sc] = total_nsa
+            result.nsa_s[sc] = leg.seconds
     return result
+
+
+def _run_shards(plan, originals, multiple_mode) -> List[ShardResult]:
+    """Every shard's NSA → compaction → metrics chain on its device, all
+    of them dispatched before the host reads any back.
+
+    1. per shard: host tables, uploads, sampling and compaction (its
+       totals stay on the device), so shard ``k+1``'s host tables run
+       while shard ``k``'s device works;
+    2. every shard's kept totals in one read, then each shard's kept-stamp
+       gather and its metrics on the kept-width column slice;
+    3. every shard's moments in one read.
+
+    With one shard this is the single chain's own order.
+    """
+    import jax
+
+    from repro.kernels import ops
+
+    devices = jax.local_devices()
+    out, stamps = [], []
+    for shard in plan.shards:
+        pairs = tuple(s.scenario for s in shard.specs)
+        with obs.span("nsa.shard"):
+            ss, idx, totals, _ = nsa_sweep_device(
+                originals, pairs, multiple_mode=multiple_mode,
+                device=devices[shard.device_index % len(devices)])
+        obs.count("nsa.shard_rows", len(pairs))
+        obs.count("nsa.padded_cells", len(pairs) * ss.shape[1])
+        stamps.append(ss)
+        # ss_kept, totals, hist and mom are filled in below
+        out.append(ShardResult(shard=shard, pairs=pairs, ss_kept=None,
+                               idx=idx, totals=totals, hist=None, mom=None))
+    with obs.span("nsa.totals_wait"):
+        totals = jax.device_get([sr.totals for sr in out])
+    for sr, ss, t in zip(out, stamps, totals):
+        sr.totals = np.asarray(t, np.int64).reshape(-1)
+        sr.ss_kept = ops.gather_kept(ss, sr.idx)
+        # compaction packed every row's kept stamps to the front, so the
+        # metrics dispatch only needs the kept-width column slice (device
+        # slice — kept counts are far below the padded source width after
+        # compression)
+        n_kept = int(-(-max(int(sr.totals.max(initial=1)), 1)
+                       // ops.TILE) * ops.TILE)
+        sr.hist, sr.mom = ops.stream_metrics_batched_device(
+            sr.ss_kept[:, :min(n_kept, sr.ss_kept.shape[1])], sr.totals,
+            sr.shard.max_range)
+    with obs.span("nsa.device_wait"):
+        moms = jax.device_get([sr.mom for sr in out])
+    for sr, m in zip(out, moms):
+        sr.mom = np.asarray(m, np.float64)      # O(rows)
+    return out
 
 
 def _execute_host(plan, originals, store, backend, multiple_mode,

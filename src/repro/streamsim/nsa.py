@@ -333,7 +333,7 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
     as a kernel scalar — so mixing ``max_range = 1`` with ``max_range =
     3600`` in one launch is exact. All rows' keep masks then compact
     through ONE batched prefix-sum dispatch plus one XLA scatter
-    (:func:`repro.kernels.ops.compact_mask_batched`).
+    (:func:`repro.kernels.ops.compact_mask_batched_device`).
 
     Parameters
     ----------
@@ -390,12 +390,13 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
         return _host()
     from repro.kernels import ops
     try:
-        ss_kept, idx_b, totals, _ = nsa_sweep_device(
+        ss_b, idx_b, totals, _ = nsa_sweep_device(
             streams, pairs, multiple_mode=multiple_mode, autotune=autotune)
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("nsa_sweep", err)
         return _host()
-    return materialize_sweep(streams, pairs, ss_kept, idx_b, totals)
+    return materialize_sweep(streams, pairs, ops.gather_kept(ss_b, idx_b),
+                             idx_b, np.asarray(totals, np.int64))
 
 
 def nsa_sweep_device(streams: Dict[str, Stream],
@@ -404,11 +405,14 @@ def nsa_sweep_device(streams: Dict[str, Stream],
                      autotune: Optional[str] = None):
     """The device leg of the range-padded sweep — NO host gather.
 
-    Runs ONE ``stream_sample`` dispatch plus ONE batched compaction for
-    the given scenario rows and returns device-resident handles, so a
-    caller (the sweep engine) can chain the kept scale stamps straight
-    into the fused metrics engine without a host round-trip; the payload
-    gather is deferred to :func:`materialize_sweep`.
+    Dispatches ONE ``stream_sample`` launch plus ONE batched compaction
+    for the given scenario rows and returns device-resident handles
+    without waiting for the device, so a caller (the sweep engine) can
+    dispatch every shard's chain before reading any of them back. The
+    caller then takes the kept scale stamps with
+    :func:`repro.kernels.ops.gather_kept` and chains them straight into
+    the fused metrics engine; the payload gather is deferred to
+    :func:`materialize_sweep`.
 
     Parameters
     ----------
@@ -421,13 +425,14 @@ def nsa_sweep_device(streams: Dict[str, Stream],
 
     Returns
     -------
-    (ss_kept, idx, totals, lengths)
-        ``ss_kept`` int32 ``(R, N)`` device — row ``r``'s first
-        ``totals[r]`` entries are the kept scale stamps (tail entries are
-        clipped-gather garbage; mask by ``totals``). ``idx`` int32
-        ``(R, N)`` device — kept-record indices, sentinel ``N`` past each
-        row's total. ``totals`` int64 ``(R,)`` host (the O(R) scalars);
-        ``lengths`` int64 ``(R,)`` host source lengths.
+    (ss, idx, totals, lengths)
+        ``ss`` int32 ``(R, N)`` device — every record's scale stamp
+        (``gather_kept(ss, idx)`` puts row ``r``'s kept stamps in its
+        first ``totals[r]`` entries). ``idx`` int32 ``(R, N)`` device —
+        kept-record indices, sentinel ``N`` past each row's total.
+        ``totals`` int32 ``(R,)`` device (the O(R) kept counts; reading
+        them waits for the compaction); ``lengths`` int64 ``(R,)`` host
+        source lengths.
 
     Raises
     ------
@@ -443,8 +448,8 @@ def nsa_sweep_device(streams: Dict[str, Stream],
     with tuning.tuner_context(autotune):
         ss_b, keep_b, lengths = ops.stream_sample_batched(
             ts, [mr for _, mr in pairs], mults, device=device)
-        idx_b, totals = ops.compact_mask_batched(keep_b)
-    return ops.gather_kept(ss_b, idx_b), idx_b, totals, lengths
+        idx_b, totals = ops.compact_mask_batched_device(keep_b)
+    return ss_b, idx_b, totals, lengths
 
 
 def materialize_sweep(streams: Dict[str, Stream],

@@ -164,7 +164,8 @@ class TestCompactBatched:
         R, n = shape
         rng = np.random.default_rng(R * n)
         mask = np.stack([rng.random(n) < d for d in densities])
-        idx_b, totals = ops.compact_mask_batched(mask)
+        idx_b, totals = ops.compact_mask_batched_device(mask)
+        totals = np.asarray(totals)
         assert idx_b.shape == (R, n) and totals.shape == (R,)
         for r in range(R):
             idx_1, total_1 = ops.compact_mask(mask[r])
@@ -181,16 +182,16 @@ class TestCompactBatched:
         # identical all-kept rows: a leaking carry would shift row 1's
         # positions by row 0's total
         mask = np.ones((2, 2 * TILE), bool)
-        idx_b, totals = ops.compact_mask_batched(mask)
+        idx_b, totals = ops.compact_mask_batched_device(mask)
         np.testing.assert_array_equal(totals, [2 * TILE, 2 * TILE])
         np.testing.assert_array_equal(np.asarray(idx_b[0]),
                                       np.asarray(idx_b[1]))
 
     def test_empty_and_bad_shapes(self):
-        idx, totals = ops.compact_mask_batched(np.zeros((2, 0), bool))
-        assert idx.shape == (2, 0) and list(totals) == [0, 0]
+        idx, totals = ops.compact_mask_batched_device(np.zeros((2, 0), bool))
+        assert idx.shape == (2, 0) and list(np.asarray(totals)) == [0, 0]
         with pytest.raises(ValueError):
-            ops.compact_mask_batched(np.zeros(5, bool))
+            ops.compact_mask_batched_device(np.zeros(5, bool))
 
 
 class TestStreamMetrics:

@@ -315,6 +315,48 @@ class TestShardedEngine:
         result.materialize()
         assert store.exists("s__sim30"), "later default call must persist"
 
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_leg_spans_and_shard_counters(self, tmp_path, n_devices):
+        # one nsa.leg over every shard; per shard an nsa.shard holding its
+        # nsa.tables; one totals read and one moments read for the sweep.
+        # A 1-shard sweep thus opens nsa.leg, nsa.tables and
+        # nsa.device_wait once each, as before the shards overlapped
+        from repro import obs
+        from repro.streamsim import engine
+        from repro.streamsim.store import StreamStore
+
+        originals = {d: preprocess(make_stream(d, scale=0.002, seed=3))
+                     for d in ("sogouq", "traffic", "userbehavior")}
+        store = StreamStore(str(tmp_path / "store"))
+        plan = plan_sweep(store, list(originals), [30, 60],
+                          {k: len(v) for k, v in originals.items()},
+                          n_devices=n_devices, host_index=0, n_hosts=1)
+        n = len(plan.shards)
+        assert n == n_devices
+        with obs.recording() as rec:
+            result = engine.execute_sweep(plan, originals, store,
+                                          backend="pallas")
+        assert result.mode == "device"
+        calls = rec.calls()
+        assert {k: calls.get(k) for k in (
+            "nsa.leg", "nsa.shard", "nsa.tables", "nsa.totals_wait",
+            "nsa.device_wait")} == {
+            "nsa.leg": 1, "nsa.shard": n, "nsa.tables": n,
+            "nsa.totals_wait": 1, "nsa.device_wait": 1}
+        parents = {name: parent for name, parent in rec.totals}
+        assert parents["nsa.leg"] is None
+        for inner in ("nsa.shard", "nsa.totals_wait", "nsa.device_wait"):
+            assert parents[inner] == "nsa.leg"
+        assert parents["nsa.tables"] == "nsa.shard"
+        counts = rec.counts()
+        assert counts["nsa.shard_rows"] == 6
+        assert counts["nsa.padded_cells"] == sum(
+            len(sr.pairs) * sr.ss_kept.shape[1]
+            for sr in result.shard_results)
+        # every co-simulated scenario reports the one leg's seconds
+        leg_s = rec.spans()["nsa.leg"]
+        assert all(result.nsa_s[sc] == leg_s for sc in result.nsa_s)
+
 
 # ----------------------------------------------------------- replay errors
 def test_replay_many_chains_through_existing_causes():
@@ -499,3 +541,120 @@ def test_sharded_sweep_on_four_forced_devices(tmp_path):
     assert proc.returncode == 0, \
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "OK devices=" in proc.stdout
+
+
+# --------------------------- four forced devices: overlap and bit identity
+_OVERLAP_SCRIPT = textwrap.dedent("""
+    import json
+
+    import numpy as np
+    import jax
+    assert jax.local_device_count() == 4, jax.local_device_count()
+
+    import repro.kernels.ops as ops_mod
+    from repro.streamsim import Controller
+
+    # from the first compaction on: each compaction's device, each
+    # metrics dispatch, and each batched host read of device results
+    events = []
+    real_compact = ops_mod.compact_mask_batched_device
+    real_metrics = ops_mod.stream_metrics_batched_device
+    real_get = jax.device_get
+
+    def compact(mask):
+        events.append(["compact", tuple(mask.devices())[0].id])
+        return real_compact(mask)
+
+    def metrics(*args, **kwargs):
+        events.append(["metrics", None])
+        return real_metrics(*args, **kwargs)
+
+    def device_get(x):
+        if events:
+            events.append(["read", None])
+        return real_get(x)
+
+    ops_mod.compact_mask_batched_device = compact
+    ops_mod.stream_metrics_batched_device = metrics
+    jax.device_get = device_get
+
+    def consumer(queue):
+        return {"records_seen": sum(len(b) for b in queue)}
+
+    datasets = ["sogouq", "traffic", "userbehavior"]
+    ranges = [10, 20, 30, 40, 50, 60]
+    kw = dict(scale=0.002, seed=9)
+    runs = {"four": Controller("@STORE@/four"),
+            "one": Controller("@STORE@/one"),
+            "numpy": Controller("@STORE@/numpy")}
+    runs["four"].run_many(datasets, ranges, consumer, backend="pallas",
+                          **kw)
+    sweep_events = list(events)
+    shards = sorted({(slot, tuple(devs)) for slot, devs
+                     in runs["four"].last_placement.values()})
+    runs["one"].run_many(datasets, ranges, consumer, backend="pallas",
+                         n_devices=1, **kw)
+    runs["numpy"].run_many(datasets, ranges, consumer, backend="numpy",
+                           **kw)
+
+    def columns(s):
+        cols = {"t": s.t, "scale_stamp": s.scale_stamp}
+        cols.update({"payload." + k: v for k, v in s.payload.items()})
+        return {k: (str(v.dtype), v.shape,
+                    v.tolist() if v.dtype == object else v.tobytes().hex())
+                for k, v in cols.items()}
+
+    differ = []
+    for d in datasets:
+        for mr in ranges:
+            key = f"{d}__sim{mr}"
+            cols = {name: columns(c.store.get(key))
+                    for name, c in runs.items()}
+            for name in ("one", "numpy"):
+                if cols["four"] != cols[name]:
+                    differ.append([key, name])
+    print(json.dumps({"events": sweep_events,
+                      "shards": shards,
+                      "differ": differ}))
+""")
+
+
+@pytest.fixture(scope="module")
+def four_device_sweep(tmp_path_factory):
+    """One child process with four forced host-platform devices: a
+    4-shard pallas sweep (its compaction, metrics and read order
+    recorded), a 1-shard pallas sweep and the numpy path of the same
+    grid; returns the child's summary."""
+    tmp = tmp_path_factory.mktemp("four_devices")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        " --xla_force_host_platform_device_count=4").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
+    script = _OVERLAP_SCRIPT.replace("@STORE@", str(tmp))
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, \
+        f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
+    import json
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_four_shard_streams_are_byte_identical_to_one_shard_and_numpy(
+        four_device_sweep):
+    shards = four_device_sweep["shards"]
+    assert len(shards) == 4
+    assert len({tuple(devs) for _, devs in shards}) == 4
+    assert four_device_sweep["differ"] == []
+
+
+def test_every_shard_is_dispatched_before_any_totals_are_read(
+        four_device_sweep):
+    events = [tuple(e) for e in four_device_sweep["events"]]
+    compacts = [dev for kind, dev in events[:4] if kind == "compact"]
+    assert len(compacts) == 4 and len(set(compacts)) == 4, events
+    # then one read of every shard's totals, every shard's kept-stamp
+    # gather and metrics, and one read of every shard's moments
+    assert events[4:10] == [("read", None)] + [("metrics", None)] * 4 + \
+        [("read", None)], events
