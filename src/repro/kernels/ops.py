@@ -103,15 +103,16 @@ def _pad_to(x: jnp.ndarray, mult: int, value) -> Tuple[jnp.ndarray, int]:
 # --------------------------------------------------------------------- NSA
 def _nsa_tables(t64: np.ndarray, max_range: int, multiple: float,
                 width: Optional[int] = None):
-    """Exact per-bucket tables + kernel inputs for one sorted stream.
+    """Exact per-bucket tables for one sorted stream at one range.
 
-    Computes (rebased f32 timestamps, starts, counts, ktab,
-    (t_min, 1/span, n_buckets)) where the tables come from the *float64
-    host formula* — the identical expression ``(t - t_min) / span *
-    max_range`` that :func:`repro.streamsim.nsa.scale_stamps` floors — so
+    Computes (starts, counts, ktab, (t_min, 1/span, n_buckets)) from the
+    *float64 host formula* — the identical expression ``(t - t_min) / span
+    * max_range`` that :func:`repro.streamsim.nsa.scale_stamps` floors — so
     the kernel's +-1-snapped scale stamps are bit-identical to the numpy
-    path. O(n) vectorized host work for ``v`` plus O(max_range log n)
-    searchsorted; everything per-record then runs on device.
+    path. ``starts`` comes from :func:`_bucket_starts`, which evaluates
+    that expression at O(log n) records per bucket edge; no per-record
+    pass runs here. The kernel's timestamps come from :func:`_rebase`,
+    once per stream.
 
     ``width`` (default ``max_range``) pads the table axis for range-padded
     sweeps mixing rows at different ``max_range``: tail buckets in
@@ -134,16 +135,14 @@ def _nsa_tables(t64: np.ndarray, max_range: int, multiple: float,
     n = len(t64)
     t_min, t_max = float(t64[0]), float(t64[-1])
     span = t_max - t_min
+    starts = np.full(width, n, np.int32)
     if span <= 0.0:
         # degenerate stream (all timestamps equal): everything is bucket 0,
         # so bucket 0 spans [0, n) and every later bucket starts at n
-        starts = np.full(width, n, np.int32)
         starts[0] = 0
         inv_span = 0.0
     else:
-        v = (t64 - t_min) / span * max_range
-        starts = np.full(width, n, np.int32)
-        starts[:max_range] = np.searchsorted(v, np.arange(max_range))
+        starts[:max_range] = _bucket_starts(t64, t_min, span, max_range)
         inv_span = 1.0 / span
     counts = np.zeros(width, np.int32)
     counts[:max_range] = np.diff(np.append(starts[:max_range], n))
@@ -156,8 +155,93 @@ def _nsa_tables(t64: np.ndarray, max_range: int, multiple: float,
             f"bucket with count={counts[prod.argmax()]} and "
             f"k={ktab[prod.argmax()]} overflows the int32 keep rule; "
             "use the numpy NSA path for this stream")
-    t32 = (t64 - t_min).astype(np.float32)
-    return t32, starts, counts, ktab, (0.0, inv_span, float(max_range))
+    return starts, counts, ktab, (0.0, inv_span, float(max_range))
+
+
+def _bucket_starts(t64: np.ndarray, t_min: float, span: float,
+                   max_range: int) -> np.ndarray:
+    """``np.searchsorted(v, arange(max_range))`` for ``v = (t64 - t_min) /
+    span * max_range``, without building ``v``.
+
+    A binary search for every bucket edge ``j`` at once: each step
+    evaluates the same float64 expression, in the same order, on the
+    gathered ``t64[i]``. Each rounding step is monotone in ``t``, so ``v``
+    is non-decreasing and the count of its entries below ``j`` comes out
+    bit for bit — ties and entries landing exactly on ``j`` included."""
+    n = len(t64)
+    j = np.arange(max_range)
+    below = np.zeros(max_range, np.int64)    # entries known to lie below j
+    step = 1 << (n.bit_length() - 1)
+    while step:
+        cand = np.minimum(below + step, n)
+        grow = (t64[cand - 1] - t_min) / span * max_range < j
+        below = np.where(grow, cand, below)
+        step >>= 1
+    return below
+
+
+def _rebase(t64: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """The kernel's timestamps: ``(t64 - t64[0])`` in float64, rounded to
+    float32. Epoch seconds (~1.5e9) quantize to ~128 s in float32, so the
+    rebase comes before the cast. Written into ``out`` (float32, at least
+    ``len(t64)`` long) in one buffered pass; its tail repeats the last
+    stamp, which puts padded records into the last bucket."""
+    n = len(t64)
+    if out is None:
+        out = np.empty(n, np.float32)
+    np.subtract(t64, t64[0], out=out[:n], dtype=np.float64,
+                casting="same_kind")
+    out[n:] = out[n - 1]
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _expand_rows(planes: Tuple[jnp.ndarray, ...],
+                 rows: Tuple[int, ...]) -> jnp.ndarray:
+    """Row ``r`` of the result is ``planes[rows[r]]``: the kernel's ``(R,
+    N)`` timestamp plane from the rows' ``D`` dataset rows, one contiguous
+    row copy each. Separate 1-D inputs, not one ``(D, N)`` plane: XLA
+    then copies straight into the output, with no sliced scratch rows."""
+    return jnp.stack([planes[d] for d in rows])
+
+
+def _nsa_row_inputs(ts, ranges, mults, width: int, N: int, put):
+    """Kernel inputs of R scenario rows, per-dataset work done per dataset.
+
+    ``ts`` are the rows' sorted float64 timestamp arrays; rows that hold
+    the SAME array object share a dataset. Each row gets its own bucket
+    tables (:func:`_nsa_tables` at its range, padded to ``width``); each
+    dataset is rebased to float32 once into its row of a ``(D, N)``
+    plane, ``put`` uploads each such row once and :func:`_expand_rows`
+    copies them out to the kernel's ``(R, N)`` plane on the device. With
+    every row distinct (``D == R``) the host plane is the kernel's plane,
+    uploaded whole.
+
+    Returns ``(t, starts, counts, ktab, scalars)``: ``t`` the device
+    ``(R, N)`` float32 plane, the rest host arrays, ``(R, width)`` int32
+    and ``(R, 3)`` float32.
+    """
+    R = len(ts)
+    starts_b = np.empty((R, width), np.int32)
+    counts_b = np.empty((R, width), np.int32)
+    k_b = np.empty((R, width), np.int32)
+    scal_b = np.empty((R, 3), np.float32)
+    datasets = {}               # id(array) -> (its row of the plane, array)
+    rows = tuple(datasets.setdefault(id(t), (len(datasets), t))[0]
+                 for t in ts)
+    with obs.span("nsa.tables"):
+        for r, t64 in enumerate(ts):
+            starts_b[r], counts_b[r], k_b[r], scal_b[r] = _nsa_tables(
+                t64, int(ranges[r]), float(mults[r]), width)
+        plane = np.empty((len(datasets), N), np.float32)
+        for d, t64 in datasets.values():
+            _rebase(t64, plane[d])
+        obs.count("nsa.tables_rows", R)
+        obs.count("nsa.tables_datasets", len(datasets))
+    if len(datasets) == R:
+        return put(plane), starts_b, counts_b, k_b, scal_b
+    t = _expand_rows(tuple(put(row) for row in plane), rows)
+    return t, starts_b, counts_b, k_b, scal_b
 
 
 def stream_sample(t: jnp.ndarray, max_range: int,
@@ -178,14 +262,15 @@ def stream_sample(t: jnp.ndarray, max_range: int,
     n = len(t64)
     if n == 0:
         return jnp.zeros(0, jnp.int32), jnp.zeros(0, bool)
-    t32, starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
+    starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
     cfg = tuning.config_for("stream_sample", s=1, n=n, r=max_range)
-    tp, n0 = _pad_to(jnp.asarray(t32), cfg.record_tile, t32[-1])
+    tp = _rebase(t64, np.empty(-(-n // cfg.record_tile) * cfg.record_tile,
+                               np.float32))
     ss, keep = stream_sample_launch(
-        tp[None, :], jnp.asarray(starts)[None, :],
+        jnp.asarray(tp)[None, :], jnp.asarray(starts)[None, :],
         jnp.asarray(counts)[None, :], jnp.asarray(ktab)[None, :],
         jnp.asarray(scalars, jnp.float32)[None, :], max_range, cfg)
-    return ss[0, :n0], keep[0, :n0].astype(bool)
+    return ss[0, :n], keep[0, :n].astype(bool)
 
 
 def stream_sample_launch(t, starts, counts, ktab, scalars, width, cfg):
@@ -205,9 +290,9 @@ def stream_sample_ref(t: jnp.ndarray, max_range: int, multiple: float):
     t64 = np.asarray(t, np.float64)
     if len(t64) == 0:
         return jnp.zeros(0, jnp.int32), jnp.zeros(0, bool)
-    t32, starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
+    starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
     ss, keep = ref.stream_sample_ref(
-        jnp.asarray(t32)[None, :], jnp.asarray(starts)[None, :],
+        jnp.asarray(_rebase(t64))[None, :], jnp.asarray(starts)[None, :],
         jnp.asarray(counts)[None, :], jnp.asarray(ktab)[None, :],
         jnp.asarray(scalars, jnp.float32)[None, :], max_range)
     return ss[0], keep[0].astype(bool)
@@ -250,29 +335,19 @@ def stream_sample_batched(ts, max_range, multiples, *, device=None):
     cfg = tuning.config_for("stream_sample", s=S, n=int(lengths.max()),
                             r=width)
     N = int(-(-lengths.max() // cfg.record_tile) * cfg.record_tile)
-    t_b = np.empty((S, N), np.float32)
-    starts_b = np.empty((S, width), np.int32)
-    counts_b = np.empty((S, width), np.int32)
-    k_b = np.empty((S, width), np.int32)
-    scal_b = np.empty((S, 3), np.float32)
-    with obs.span("nsa.tables"):
-        for s, t64 in enumerate(ts):
-            t32, starts, counts, ktab, scalars = _nsa_tables(
-                t64, int(ranges[s]), float(mults[s]), width)
-            t_b[s, :len(t32)] = t32
-            t_b[s, len(t32):] = t32[-1]      # pad into the last bucket
-            starts_b[s], counts_b[s], k_b[s] = starts, counts, ktab
-            scal_b[s] = scalars
 
     def _dev(x):
         return jax.device_put(x, device) if device is not None \
             else jnp.asarray(x)
 
+    t_b, starts_b, counts_b, k_b, scal_b = _nsa_row_inputs(
+        ts, ranges, mults, width, N, _dev)
+
     def _launch(lo, hi):
         return stream_sample_launch(
-            _dev(t_b[lo:hi]), _dev(starts_b[lo:hi]), _dev(counts_b[lo:hi]),
-            _dev(k_b[lo:hi]), _dev(scal_b[lo:hi].astype(np.float32)), width,
-            cfg)
+            t_b if hi - lo == S else t_b[lo:hi], _dev(starts_b[lo:hi]),
+            _dev(counts_b[lo:hi]), _dev(k_b[lo:hi]), _dev(scal_b[lo:hi]),
+            width, cfg)
 
     g = max(1, min(int(cfg.grid_split), S))
     if g == 1:

@@ -286,7 +286,7 @@ def _pad_rows(x: np.ndarray, mult: int, value) -> np.ndarray:
 def _run_stream_sample(key: TuneKey, cfg: TileConfig):
     import jax.numpy as jnp
 
-    from repro.kernels.ops import _nsa_tables, stream_sample_launch
+    from repro.kernels.ops import _nsa_tables, _rebase, stream_sample_launch
 
     s, n, r = _spec_shapes(key)
     r = max(r, 2)
@@ -296,8 +296,8 @@ def _run_stream_sample(key: TuneKey, cfg: TileConfig):
     tables = [np.empty((s, r), np.int32) for _ in range(3)]
     scal = np.empty((s, 3), np.float32)
     for i, t64 in enumerate(rows):
-        t32, starts, counts, ktab, scalars = _nsa_tables(t64, r, 3.0)
-        t_b[i] = t32
+        starts, counts, ktab, scalars = _nsa_tables(t64, r, 3.0)
+        _rebase(t64, t_b[i])
         tables[0][i], tables[1][i], tables[2][i] = starts, counts, ktab
         scal[i] = scalars
     tp = _pad_rows(t_b, cfg.record_tile, t_b[:, -1:].max())

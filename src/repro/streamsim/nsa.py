@@ -72,7 +72,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro import obs
 from repro.streamsim.preprocess import Stream
 
 BACKENDS = ("auto", "numpy", "pallas")
@@ -500,12 +499,13 @@ class ChunkHandles:
 class ChunkedNSA:
     """Per-chunk device NSA over a scenario grid — the unbounded-stream form.
 
-    Uploads each row's full-width bucket tables and (rebased f32)
-    timestamps to the device ONCE, then serves the timeline chunk by
-    chunk: ``chunk(lo, hi)`` runs the range-padded ``stream_sample``
-    kernel on just the record slice whose scale stamps land in
-    ``[lo, hi)`` and compacts its keep mask — all device-resident, no
-    host sync (totals stay on device; see
+    Uploads each row's full-width bucket tables and each dataset's
+    rebased f32 timestamps to the device ONCE (rows of one stream share
+    its upload, :func:`repro.kernels.ops._nsa_row_inputs`), then serves
+    the timeline chunk by chunk: ``chunk(lo, hi)`` runs the range-padded
+    ``stream_sample`` kernel on just the record slice whose scale stamps
+    land in ``[lo, hi)`` and compacts its keep mask — all
+    device-resident, no host sync (totals stay on device; see
     :func:`repro.kernels.ops.compact_mask_batched_device`).
 
     Bit-exactness with the monolithic sweep: a chunk's records are a
@@ -565,19 +565,15 @@ class ChunkedNSA:
         mults = [_multiple(len(streams[name]), streams[name].time_range,
                            rng, multiple_mode)
                  for name, rng in self.pairs]
-        t_b = np.empty((R, self.N), np.float32)
-        starts_b = np.empty((R, self.width), np.int32)
-        counts_b = np.empty((R, self.width), np.int32)
-        k_b = np.empty((R, self.width), np.int32)
-        scal_b = np.empty((R, 3), np.float32)
-        with obs.span("nsa.tables"):
-            for r, t64 in enumerate(ts):
-                t32, starts, counts, ktab, scalars = ops._nsa_tables(
-                    t64, self.pairs[r][1], float(mults[r]), self.width)
-                t_b[r, :len(t32)] = t32
-                t_b[r, len(t32):] = t32[-1]  # pad into the last bucket
-                starts_b[r], counts_b[r], k_b[r] = starts, counts, ktab
-                scal_b[r] = scalars
+
+        def _dev(x):
+            return jax.device_put(x, device) if device is not None \
+                else jnp.asarray(x)
+
+        self._dev = _dev
+        self._t, starts_b, counts_b, k_b, scal_b = ops._nsa_row_inputs(
+            ts, [rng for _, rng in self.pairs], mults, self.width, self.N,
+            _dev)
         # host copy for slicing: col lo gives the first record of bucket
         # lo (tail buckets carry starts = n, so rows whose range ends
         # before the sweep's maximum contribute empty slices for free)
@@ -589,12 +585,6 @@ class ChunkedNSA:
         np.cumsum(np.minimum(k_b, counts_b), axis=1,
                   out=self._kept_cum[:, 1:])
 
-        def _dev(x):
-            return jax.device_put(x, device) if device is not None \
-                else jnp.asarray(x)
-
-        self._dev = _dev
-        self._t = _dev(t_b)
         self._starts = _dev(starts_b)
         self._counts = _dev(counts_b)
         self._ktab = _dev(k_b)

@@ -133,8 +133,9 @@ def test_pair_stats_within_tolerance(rng):
 def _sample_args(rng, widths=(600, 240), n=2048):
     """Range-padded (S, N) stream-sample inputs, one row per width."""
     width = max(widths)
-    rows = [ops._nsa_tables(np.sort(rng.uniform(0, 900.0, n)), w, 3.0,
-                            width) for w in widths]
+    ts = [np.sort(rng.uniform(0, 900.0, n)) for _ in widths]
+    rows = [(ops._rebase(t),) + ops._nsa_tables(t, w, 3.0, width)
+            for t, w in zip(ts, widths)]
     return tuple(jnp.asarray(np.stack([r[i] for r in rows]))
                  for i in range(4)) + (
         jnp.asarray(np.stack([r[4] for r in rows]), jnp.float32),), width
@@ -173,11 +174,11 @@ def test_smem_table_budget_binds_only_on_tpu(monkeypatch):
     from repro.kernels.stream_sample import MAX_TABLE_WIDTH
     t = np.arange(100, dtype=np.float64)
     wide = MAX_TABLE_WIDTH + 1
-    assert len(ops._nsa_tables(t, wide, 2.0)[1]) == wide
+    assert len(ops._nsa_tables(t, wide, 2.0)[0]) == wide
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     with pytest.raises(ops.PallasDomainError, match="SMEM"):
         ops._nsa_tables(t, wide, 2.0)
-    assert len(ops._nsa_tables(t, MAX_TABLE_WIDTH, 2.0)[1]) == \
+    assert len(ops._nsa_tables(t, MAX_TABLE_WIDTH, 2.0)[0]) == \
         MAX_TABLE_WIDTH
 
 

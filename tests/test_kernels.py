@@ -84,6 +84,66 @@ class TestStreamSample:
                                                    600, 144.0))
 
 
+def _starts_by_searchsorted(t64, max_range, width):
+    """Bucket starts from the full float64 ``v`` plane: the formula the
+    bisection in ``ops._nsa_tables`` must reproduce bit for bit."""
+    n = len(t64)
+    starts = np.full(width, n, np.int64)
+    span = float(t64[-1]) - float(t64[0])
+    if span <= 0.0:
+        starts[0] = 0
+    else:
+        v = (t64 - float(t64[0])) / span * max_range
+        starts[:max_range] = np.searchsorted(v, np.arange(max_range))
+    return starts
+
+
+def _edge_ties():
+    # runs of equal stamps on every bucket edge of range 600 over a day,
+    # with neighbours one ulp either side: ties straddle the edges
+    edges = np.arange(601) * 144.0
+    return np.sort(np.concatenate([
+        np.repeat(edges, 3), np.nextafter(edges, -np.inf)[1:],
+        np.nextafter(edges, np.inf)[:-1]])), 600, 600
+
+
+def _on_integers():
+    # span 1024 over 512 buckets: v = t / 2 is exact, so every even
+    # stamp lands exactly on a bucket edge
+    return np.repeat(np.arange(1025.0), 2), 512, 512
+
+
+def _day(max_range, days=1, epoch=0.0, width=None):
+    def make():
+        rng = np.random.default_rng(max_range * days)
+        t = epoch + np.sort(rng.uniform(0, days * 86_400.0, 40_000))
+        return t, max_range * days, width or max_range * days
+    return make
+
+
+class TestBucketStarts:
+    @pytest.mark.parametrize("case", [
+        _edge_ties, _on_integers,
+        lambda: (np.array([7.5]), 600, 600),               # n = 1
+        lambda: (np.full(9, 1.5e9), 600, 600),             # span == 0
+        lambda: (np.array([0.0, 86_400.0]), 600, 600),     # n = 2
+        _day(600, width=3600),                             # width > range
+        _day(3600, epoch=1.5e9),                           # epoch seconds
+        *[_day(r) for r in (600, 1200, 1800, 2400, 3000, 3600)],
+        *[_day(r, days=2, epoch=1.5e9)
+          for r in (600, 1200, 1800, 2400, 3000, 3600)],
+    ], ids=["edge_ties", "on_integers", "n1", "span0", "n2",
+            "width_gt_range", "epoch"]
+        + [f"grid{r}" for r in (600, 1200, 1800, 2400, 3000, 3600)]
+        + [f"stream{2 * r}" for r in (600, 1200, 1800, 2400, 3000, 3600)])
+    def test_bisection_matches_searchsorted(self, case):
+        t, max_range, width = case()
+        starts, counts, _, _ = ops._nsa_tables(t, max_range, 3.0, width)
+        want = _starts_by_searchsorted(t, max_range, width)
+        np.testing.assert_array_equal(starts, want)
+        assert counts.sum() == len(t)
+
+
 class TestStreamSampleBatched:
     @pytest.mark.parametrize("lengths", [
         (256, 256, 256),          # uniform
@@ -114,6 +174,50 @@ class TestStreamSampleBatched:
     def test_empty_stream_rejected(self):
         with pytest.raises(ValueError):
             ops.stream_sample_batched([np.zeros(0)], 10, 1.0)
+
+    @pytest.mark.parametrize("path", ["batched", "chunked"])
+    def test_rows_sharing_an_array_match_copies(self, path):
+        # rows holding one array object share its rebase and upload; rows
+        # holding copies take one each — every output must agree
+        from repro import obs
+        from repro.streamsim.nsa import ChunkedNSA
+        from repro.streamsim.preprocess import Stream
+        a = _sorted_times(3000, 86_400.0, seed=11) + 1.5e9
+        b = _sorted_times(700, 86_400.0, seed=12)
+        rows = [(a, 60), (a, 120), (b, 60), (a, 240), (b, 240)]
+
+        def run(arrays):
+            with obs.recording() as rec:
+                if path == "batched":
+                    out = ops.stream_sample_batched(
+                        arrays, [r for _, r in rows], 3.0)
+                    out = [np.asarray(x) for x in out]
+                else:
+                    streams = {str(i): Stream(str(i), t, {})
+                               for i, t in enumerate(arrays)}
+                    sweep = ChunkedNSA(streams, [
+                        (str(i), r) for i, (_, r) in enumerate(rows)])
+                    out = []
+                    for lo in range(0, sweep.width, 50):
+                        h = sweep.chunk(lo, min(lo + 50, sweep.width))
+                        out += [np.asarray(h.ss_kept), np.asarray(h.idx),
+                                np.asarray(h.totals), h.rec_off]
+            counts = rec.counts()
+            return out, (counts["nsa.tables_rows"],
+                         counts["nsa.tables_datasets"])
+
+        shared, n_shared = run([t for t, _ in rows])
+        copied, n_copied = run([np.copy(t) for t, _ in rows])
+        assert n_shared == (5, 2)
+        assert n_copied == (5, 5)
+        assert len(shared) == len(copied)
+        for got, want in zip(shared, copied):
+            np.testing.assert_array_equal(got, want)
+        if path == "batched":
+            for s, (t, r) in enumerate(rows):
+                ss_1, keep_1 = ops.stream_sample(t, r, 3.0)
+                np.testing.assert_array_equal(shared[0][s, :len(t)], ss_1)
+                np.testing.assert_array_equal(shared[1][s, :len(t)], keep_1)
 
 
 class TestCompact:

@@ -135,3 +135,19 @@ def test_slice_records_compiles_to_copies_for_v5e(one_chip,
     assert "gather" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= mem.output_size_in_bytes
+
+
+@pytest.mark.parametrize("datasets,per,n", [
+    (1, 6, 21_218_304),          # the multi-day stream: 6 rows, 1 dataset
+    (3, 6, 10_580_992),          # the paper grid: 18 rows, 3 datasets
+])
+def test_expand_rows_compiles_to_copies_for_v5e(datasets, per, n, one_chip,
+                                               no_persistent_cache):
+    """The row -> dataset expansion of the NSA timestamp plane at the
+    benchmark's shapes: row copies, no gather, no scratch plane."""
+    rows = tuple(d for d in range(datasets) for _ in range(per))
+    planes = tuple(jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+                   for _ in range(datasets))
+    compiled = ops._expand_rows.lower(planes, rows).compile()
+    assert "gather" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
