@@ -31,14 +31,13 @@ def main() -> None:
     compile_cache.enable()
     from benchmarks import (bench_efficiency, bench_kernels, bench_network,
                             bench_PR4, bench_PR5, bench_PR6, bench_PR7,
-                            bench_PR8, bench_PR9, bench_PR10,
-                            bench_volatility)
+                            bench_PR8, bench_PR9, bench_volatility)
     out_dir = os.environ.get("BENCH_OUT_DIR", ".")
     os.makedirs(out_dir, exist_ok=True)
     csv = ["name,us_per_call,derived"]
     for mod in (bench_volatility, bench_network, bench_efficiency,
                 bench_kernels, bench_PR4, bench_PR5, bench_PR6, bench_PR7,
-                bench_PR8, bench_PR9, bench_PR10):
+                bench_PR8, bench_PR9):
         print(f"# running {mod.__name__} ...", file=sys.stderr, flush=True)
         start = len(csv)
         mod.run(csv)

@@ -28,18 +28,15 @@ every (dataset × max_range) scenario is a row.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
-
 LANE = 128
 SUBLANE = 8
-TILE = LANE * SUBLANE  # records per grid step with the default TileConfig
+TILE = LANE * SUBLANE  # records per grid step
 
 
 def tile_prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
@@ -73,7 +70,7 @@ def _kernel(mask_ref, pos_ref, total_ref, carry_ref):
     def _reset():                                    # new row: fresh carry
         carry_ref[0] = 0
 
-    m = mask_ref[...].astype(jnp.int32)              # (sublane, LANE) 0/1
+    m = mask_ref[...].astype(jnp.int32)              # (SUBLANE, LANE) 0/1
     carry = carry_ref[0]
     pos_ref[...] = carry + tile_prefix_sum(m) - m
     total = carry + jnp.sum(m)
@@ -82,9 +79,8 @@ def _kernel(mask_ref, pos_ref, total_ref, carry_ref):
     total_ref[...] = jnp.full(total_ref.shape, total, jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "config"))
-def compact_positions_pallas(mask: jnp.ndarray, *, interpret: bool = False,
-                             config: Optional[TileConfig] = None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def compact_positions_pallas(mask: jnp.ndarray, *, interpret: bool = False):
     """mask: (n,) int32 0/1, n % TILE == 0 (pad with 0).
 
     Returns ``(pos int32 (n,), total int32 (1,))`` where ``pos[i]`` is the
@@ -92,15 +88,14 @@ def compact_positions_pallas(mask: jnp.ndarray, *, interpret: bool = False,
     is kept) and ``total`` the number of set mask entries — the one-row case
     of :func:`compact_positions_batched_pallas`.
     """
-    pos, totals = compact_positions_batched_pallas(
-        mask[None, :], interpret=interpret, config=config)
+    pos, totals = compact_positions_batched_pallas(mask[None, :],
+                                                   interpret=interpret)
     return pos[0], totals[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def compact_positions_batched_pallas(mask: jnp.ndarray, *,
-                                     interpret: bool = False,
-                                     config: Optional[TileConfig] = None):
+                                     interpret: bool = False):
     """Batched mask compaction: R rows' scans in ONE 2-D-grid dispatch.
 
     mask: (R, N) int32 0/1, N % TILE == 0 (pad record tails with 0).
@@ -111,15 +106,12 @@ def compact_positions_batched_pallas(mask: jnp.ndarray, *,
     count. The SMEM carry resets at each row's first record tile, so rows
     are independent (bit-identical to R sequential single-row dispatches).
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
     R, n = mask.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad records to a multiple of {cfg.record_tile}"
+    assert n % TILE == 0, f"pad records to a multiple of {TILE}"
     rows = n // LANE
     m3 = mask.reshape(R, rows, LANE)
-    grid = (R, rows // sublane)
-    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda r, i: (r, i, 0))
+    grid = (R, rows // SUBLANE)
+    tile_spec = pl.BlockSpec((None, SUBLANE, LANE), lambda r, i: (r, i, 0))
     pos, totals = pl.pallas_call(
         _kernel,
         grid=grid,

@@ -65,26 +65,23 @@ one-hot column and never contribute to any count or moment.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
-
 LANE = 128
 SUBLANE = 8
-TILE = LANE * SUBLANE      # records per grid step (default TileConfig)
-BUCKET_BLOCK = 4 * LANE    # bucket columns per inner step (default config)
+TILE = LANE * SUBLANE      # records per grid step
+BUCKET_BLOCK = 4 * LANE    # bucket columns per inner step
 
 
-def _accumulate_hist(ss_ref, hist_ref, *, buckets: int, bucket_block: int):
+def _accumulate_hist(ss_ref, hist_ref, *, buckets: int):
     """Fold one record tile's stamps into the resident ``(1, buckets)``
     histogram block.
 
     The tile is transposed once so records run down the sublanes; each of
-    its columns then compares against a lane-dense ``(LANE, bucket_block)``
+    its columns then compares against a lane-dense ``(LANE, BUCKET_BLOCK)``
     iota of bucket ids, and the one-hot sums straight into the block's
     lane slice. A ``fori_loop`` with traced bounds walks only the bucket
     blocks the tile's stamps touch."""
@@ -94,33 +91,33 @@ def _accumulate_hist(ss_ref, hist_ref, *, buckets: int, bucket_block: int):
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    ss = ss_ref[...]                                 # (sublane, LANE) int32
+    ss = ss_ref[...]                                 # (SUBLANE, LANE) int32
     valid = ss < buckets                             # padding id >= buckets
 
     # data-adaptive bucket-block range: sorted stamps => a tile spans few
     # blocks; an all-padding tile runs zero iterations
-    lo = jnp.min(jnp.where(valid, ss, buckets - 1)) // bucket_block
-    hi = jnp.max(jnp.where(valid, ss, 0)) // bucket_block
+    lo = jnp.min(jnp.where(valid, ss, buckets - 1)) // BUCKET_BLOCK
+    hi = jnp.max(jnp.where(valid, ss, 0)) // BUCKET_BLOCK
     upper = jnp.where(jnp.sum(valid.astype(jnp.int32)) > 0, hi + 1, lo)
-    cols = ss.T                                      # (LANE, sublane)
+    cols = ss.T                                      # (LANE, SUBLANE)
 
     def body(blk, carry):
-        base = pl.multiple_of(blk * bucket_block, LANE)
+        base = pl.multiple_of(blk * BUCKET_BLOCK, LANE)
         ids = base + jax.lax.broadcasted_iota(
-            jnp.int32, (LANE, bucket_block), 1)
-        partial = jnp.zeros((1, bucket_block), jnp.int32)
+            jnp.int32, (LANE, BUCKET_BLOCK), 1)
+        partial = jnp.zeros((1, BUCKET_BLOCK), jnp.int32)
         for c in range(cols.shape[1]):
             partial += jnp.sum((cols[:, c:c + 1] == ids).astype(jnp.int32),
                                axis=0, keepdims=True)
-        hist_ref[:, pl.ds(base, bucket_block)] += partial
+        hist_ref[:, pl.ds(base, BUCKET_BLOCK)] += partial
         return carry
 
     jax.lax.fori_loop(lo, upper, body, 0)
 
 
-def _kahan_fold(hist_ref, init, *, buckets: int, bucket_block: int):
+def _kahan_fold(hist_ref, init, *, buckets: int):
     """Pairwise-block + Kahan summation of ``[Σq, Σq²]`` over the resident
-    histogram: each ``bucket_block`` slice reduces to one f32 partial
+    histogram: each ``BUCKET_BLOCK`` slice reduces to one f32 partial
     (error ~ O(log BLOCK) ulp) and the partials accumulate through
     compensated addition, so the total error is independent of the
     bucket-axis length instead of growing O(B)·eps with a naive running f32
@@ -128,8 +125,8 @@ def _kahan_fold(hist_ref, init, *, buckets: int, bucket_block: int):
     are ``(s1, c1, s2, c2)``, each a ``(1, 1)`` f32 vector."""
     def kahan(blk, carry):
         s1, c1, s2, c2 = carry
-        base = pl.multiple_of(blk * bucket_block, LANE)
-        q = hist_ref[:, pl.ds(base, bucket_block)] \
+        base = pl.multiple_of(blk * BUCKET_BLOCK, LANE)
+        q = hist_ref[:, pl.ds(base, BUCKET_BLOCK)] \
             .astype(jnp.float32)                     # padding buckets are 0
         y1 = jnp.sum(q, axis=1, keepdims=True) - c1
         t1 = s1 + y1
@@ -137,7 +134,7 @@ def _kahan_fold(hist_ref, init, *, buckets: int, bucket_block: int):
         t2 = s2 + y2
         return t1, (t1 - s1) - y1, t2, (t2 - s2) - y2
 
-    return jax.lax.fori_loop(0, buckets // bucket_block, kahan, init)
+    return jax.lax.fori_loop(0, buckets // BUCKET_BLOCK, kahan, init)
 
 
 def _lanes(mom_ref, values):
@@ -150,21 +147,18 @@ def _lanes(mom_ref, values):
     mom_ref[...] = out
 
 
-def _kernel(ss_ref, hist_ref, mom_ref, *, buckets: int, bucket_block: int):
-    _accumulate_hist(ss_ref, hist_ref, buckets=buckets,
-                     bucket_block=bucket_block)
+def _kernel(ss_ref, hist_ref, mom_ref, *, buckets: int):
+    _accumulate_hist(ss_ref, hist_ref, buckets=buckets)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _moments():
         zero = jnp.zeros((1, 1), jnp.float32)
         s1, _, s2, _ = _kahan_fold(hist_ref, (zero, zero, zero, zero),
-                                   buckets=buckets,
-                                   bucket_block=bucket_block)
+                                   buckets=buckets)
         _lanes(mom_ref, (s1, s2))
 
 
-def _kernel_carry(ss_ref, mcar_ref, hist_ref, mom_ref, *, buckets: int,
-                  bucket_block: int):
+def _kernel_carry(ss_ref, mcar_ref, hist_ref, mom_ref, *, buckets: int):
     """Chunked variant of :func:`_kernel`: the final moment reduction seeds
     its pairwise+Kahan fold from a per-row carry-in ``[s1, c1, s2, c2]`` and
     emits the UPDATED 4-state instead of the bare ``[Σq, Σq²]`` pair, so
@@ -173,22 +167,18 @@ def _kernel_carry(ss_ref, mcar_ref, hist_ref, mom_ref, *, buckets: int,
     the compensation terms keeps the error O(1) ulp over any number of
     chunks). With a zero carry the fold is bit-identical to
     :func:`_kernel`'s."""
-    _accumulate_hist(ss_ref, hist_ref, buckets=buckets,
-                     bucket_block=bucket_block)
+    _accumulate_hist(ss_ref, hist_ref, buckets=buckets)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _moments():
         mcar = mcar_ref[...]                         # (1, LANE) f32
         init = tuple(mcar[:, j:j + 1] for j in range(4))
-        _lanes(mom_ref, _kahan_fold(hist_ref, init, buckets=buckets,
-                                    bucket_block=bucket_block))
+        _lanes(mom_ref, _kahan_fold(hist_ref, init, buckets=buckets))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("buckets", "interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("buckets", "interpret"))
 def stream_metrics_carry_pallas(ss: jnp.ndarray, mcar: jnp.ndarray,
-                                buckets: int, *, interpret: bool = False,
-                                config: Optional[TileConfig] = None):
+                                buckets: int, *, interpret: bool = False):
     """Fused histogram + carried Kahan moments over ONE time chunk.
 
     ss      : (S, N) int32 chunk-LOCAL scale stamps (the caller rebases the
@@ -204,25 +194,21 @@ def stream_metrics_carry_pallas(ss: jnp.ndarray, mcar: jnp.ndarray,
     zero carry, ``(hist, mom[:, ::2])`` is bit-identical to
     :func:`stream_metrics_pallas` on the same input.
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
     S, n = ss.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad records to a multiple of {cfg.record_tile}"
-    assert buckets % cfg.bucket_block == 0, \
-        f"pad buckets to a multiple of {cfg.bucket_block}"
+    assert n % TILE == 0, f"pad records to a multiple of {TILE}"
+    assert buckets % BUCKET_BLOCK == 0, \
+        f"pad buckets to a multiple of {BUCKET_BLOCK}"
     rows = n // LANE
     ss3 = ss.reshape(S, rows, LANE)
-    grid = (S, rows // sublane)
+    grid = (S, rows // SUBLANE)
     mcar3 = jnp.zeros((S, 1, LANE), jnp.float32).at[:, 0, :4].set(
         mcar.astype(jnp.float32))
     row_spec = pl.BlockSpec((None, 1, LANE), lambda s, i: (s, 0, 0))
     hist, mom = pl.pallas_call(
-        functools.partial(_kernel_carry, buckets=buckets,
-                          bucket_block=cfg.bucket_block),
+        functools.partial(_kernel_carry, buckets=buckets),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0)),
+            pl.BlockSpec((None, SUBLANE, LANE), lambda s, i: (s, i, 0)),
             row_spec,
         ],
         out_specs=[
@@ -238,11 +224,9 @@ def stream_metrics_carry_pallas(ss: jnp.ndarray, mcar: jnp.ndarray,
     return hist[:, 0], mom[:, 0, :4]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("buckets", "interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("buckets", "interpret"))
 def stream_metrics_pallas(ss: jnp.ndarray, buckets: int, *,
-                          interpret: bool = False,
-                          config: Optional[TileConfig] = None):
+                          interpret: bool = False):
     """Fused batched histogram + moments over stacked scale-stamp streams.
 
     ss      : (S, N) int32, N % TILE == 0; entries in [0, buckets) count,
@@ -252,21 +236,17 @@ def stream_metrics_pallas(ss: jnp.ndarray, buckets: int, *,
     Returns ``(hist int32 (S, buckets), moments f32 (S, 2))`` with
     ``moments[s] = [Σ_b hist[s, b], Σ_b hist[s, b]²]``.
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
     S, n = ss.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad records to a multiple of {cfg.record_tile}"
-    assert buckets % cfg.bucket_block == 0, \
-        f"pad buckets to a multiple of {cfg.bucket_block}"
+    assert n % TILE == 0, f"pad records to a multiple of {TILE}"
+    assert buckets % BUCKET_BLOCK == 0, \
+        f"pad buckets to a multiple of {BUCKET_BLOCK}"
     rows = n // LANE
     ss3 = ss.reshape(S, rows, LANE)
-    grid = (S, rows // sublane)
+    grid = (S, rows // SUBLANE)
     hist, mom = pl.pallas_call(
-        functools.partial(_kernel, buckets=buckets,
-                          bucket_block=cfg.bucket_block),
+        functools.partial(_kernel, buckets=buckets),
         grid=grid,
-        in_specs=[pl.BlockSpec((None, sublane, LANE),
+        in_specs=[pl.BlockSpec((None, SUBLANE, LANE),
                                lambda s, i: (s, i, 0))],
         out_specs=[
             pl.BlockSpec((None, 1, buckets), lambda s, i: (s, 0, 0)),

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.kernels import gpu_lowering as _gpu
-from repro.kernels import ref, tuning
+from repro.kernels import ref
 from repro.kernels.compact import compact_positions_pallas
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.metrics_fused import (BUCKET_BLOCK, TILE,
@@ -33,28 +32,10 @@ from repro.kernels.trend_scan import (PAIR_TILE, pair_stats_pallas,
 
 
 def on_tpu() -> bool:
-    """Single source of truth for the device-selection predicate."""
+    """Single source of truth for the device-selection predicate: the
+    kernels compile on the TPU and run under ``interpret=True`` anywhere
+    else (the CPU test tier)."""
     return jax.default_backend() == "tpu"
-
-
-def on_gpu() -> bool:
-    """True on any CUDA/ROCm device — the Pallas GPU lowering path.
-
-    Every kernel is rerouted to its lowering in
-    :mod:`repro.kernels.gpu_lowering`: the scan/accumulate kernels rely
-    on TPU's sequential grid, and ``stream_sample`` reads its tables from
-    TPU scalar memory.
-    """
-    return jax.default_backend() in ("gpu", "cuda", "rocm")
-
-
-def on_accelerator() -> bool:
-    """TPU or GPU: compiled Pallas. Anywhere else the TPU kernels run
-    under ``interpret=True`` (this container's CPU tier)."""
-    return on_tpu() or on_gpu()
-
-
-_on_tpu = on_tpu
 
 
 class PallasDomainError(ValueError):
@@ -263,26 +244,20 @@ def stream_sample(t: jnp.ndarray, max_range: int,
     if n == 0:
         return jnp.zeros(0, jnp.int32), jnp.zeros(0, bool)
     starts, counts, ktab, scalars = _nsa_tables(t64, max_range, multiple)
-    cfg = tuning.config_for("stream_sample", s=1, n=n, r=max_range)
-    tp = _rebase(t64, np.empty(-(-n // cfg.record_tile) * cfg.record_tile,
-                               np.float32))
+    tp = _rebase(t64, np.empty(-(-n // TILE) * TILE, np.float32))
     ss, keep = stream_sample_launch(
         jnp.asarray(tp)[None, :], jnp.asarray(starts)[None, :],
         jnp.asarray(counts)[None, :], jnp.asarray(ktab)[None, :],
-        jnp.asarray(scalars, jnp.float32)[None, :], max_range, cfg)
+        jnp.asarray(scalars, jnp.float32)[None, :], max_range)
     return ss[0, :n], keep[0, :n].astype(bool)
 
 
-def stream_sample_launch(t, starts, counts, ktab, scalars, width, cfg):
+def stream_sample_launch(t, starts, counts, ktab, scalars, width):
     """One launch of the stream-sample kernel on padded ``(S, N)`` inputs
     (the :func:`repro.kernels.stream_sample.stream_sample_pallas`
-    contract): the GPU lowering on CUDA/ROCm, else the TPU kernel —
-    compiled on the TPU, interpreted elsewhere."""
-    if on_gpu():
-        return _gpu.stream_sample_gpu(t, starts, counts, ktab, scalars,
-                                      width, sublane=cfg.sublane)
+    contract): compiled on the TPU, interpreted elsewhere."""
     return stream_sample_pallas(t, starts, counts, ktab, scalars, width,
-                                interpret=not on_tpu(), config=cfg)
+                                interpret=not on_tpu())
 
 
 def stream_sample_ref(t: jnp.ndarray, max_range: int, multiple: float):
@@ -332,9 +307,7 @@ def stream_sample_batched(ts, max_range, multiples, *, device=None):
         raise ValueError("max_range entries must be positive")
     width = int(ranges.max())
     mults = np.broadcast_to(np.asarray(multiples, np.float64), (S,))
-    cfg = tuning.config_for("stream_sample", s=S, n=int(lengths.max()),
-                            r=width)
-    N = int(-(-lengths.max() // cfg.record_tile) * cfg.record_tile)
+    N = int(-(-lengths.max() // TILE) * TILE)
 
     def _dev(x):
         return jax.device_put(x, device) if device is not None \
@@ -342,25 +315,8 @@ def stream_sample_batched(ts, max_range, multiples, *, device=None):
 
     t_b, starts_b, counts_b, k_b, scal_b = _nsa_row_inputs(
         ts, ranges, mults, width, N, _dev)
-
-    def _launch(lo, hi):
-        return stream_sample_launch(
-            t_b if hi - lo == S else t_b[lo:hi], _dev(starts_b[lo:hi]),
-            _dev(counts_b[lo:hi]), _dev(k_b[lo:hi]), _dev(scal_b[lo:hi]),
-            width, cfg)
-
-    g = max(1, min(int(cfg.grid_split), S))
-    if g == 1:
-        ss, keep = _launch(0, S)
-    else:
-        # split the row axis into g near-equal launches — smaller grids
-        # overlap better with transfers on GPU; per-row outputs are
-        # unchanged (each launch sees the identical range-padded tables)
-        bounds = [round(i * S / g) for i in range(g + 1)]
-        parts = [_launch(a, b)
-                 for a, b in zip(bounds[:-1], bounds[1:]) if b > a]
-        ss = jnp.concatenate([p[0] for p in parts], axis=0)
-        keep = jnp.concatenate([p[1] for p in parts], axis=0)
+    ss, keep = stream_sample_launch(t_b, _dev(starts_b), _dev(counts_b),
+                                    _dev(k_b), _dev(scal_b), width)
     valid = jnp.arange(N)[None, :] < _dev(lengths)[:, None]
     return ss, keep.astype(bool) & valid, lengths
 
@@ -381,13 +337,8 @@ def compact_mask(mask: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
     n = mask.shape[0]
     if n == 0:
         return jnp.zeros(0, jnp.int32), 0
-    cfg = tuning.config_for("compact", s=1, n=n)
-    mp, _ = _pad_to(mask.astype(jnp.int32), cfg.record_tile, 0)
-    if on_gpu():
-        pos, total = _gpu.compact_positions_gpu(mp)
-    else:
-        pos, total = compact_positions_pallas(mp, interpret=not _on_tpu(),
-                                              config=cfg)
+    mp, _ = _pad_to(mask.astype(jnp.int32), TILE, 0)
+    pos, total = compact_positions_pallas(mp, interpret=not on_tpu())
     tgt = jnp.where(mask.astype(bool), pos[:n], n)
     idx = jnp.full((n,), n, jnp.int32).at[tgt].set(
         jnp.arange(n, dtype=jnp.int32), mode="drop")
@@ -423,17 +374,13 @@ def compact_mask_batched_device(mask: jnp.ndarray) -> Tuple[jnp.ndarray,
     R, n = mask.shape
     if n == 0 or R == 0:
         return jnp.zeros((R, n), jnp.int32), jnp.zeros(R, jnp.int32)
-    cfg = tuning.config_for("compact", s=R, n=n)
-    pad = (-n) % cfg.record_tile
+    pad = (-n) % TILE
     mi = mask.astype(jnp.int32)
     if pad:
         mi = jnp.concatenate(
             [mi, jnp.zeros((R, pad), jnp.int32)], axis=1)
-    if on_gpu():
-        pos, totals = _gpu.compact_positions_batched_gpu(mi)
-    else:
-        pos, totals = compact_positions_batched_pallas(
-            mi, interpret=not _on_tpu(), config=cfg)
+    pos, totals = compact_positions_batched_pallas(mi,
+                                                   interpret=not on_tpu())
     del mi                   # an (R, N) plane: free it before the scatter
     return _scatter_kept(mask.astype(bool), pos), totals.reshape(-1)
 
@@ -492,14 +439,13 @@ def _check_metrics_domain(n_records: int) -> None:
             "metrics path")
 
 
-def _metrics_padded(ss_list, max_range: int, cfg: tuning.TileConfig):
+def _metrics_padded(ss_list, max_range: int):
     """Stack ragged scale-stamp streams into the kernel's (S, N) layout."""
     S = len(ss_list)
     lengths = np.array([len(s) for s in ss_list], np.int64)
     _check_metrics_domain(int(lengths.max(initial=0)))
-    tile, block = cfg.record_tile, cfg.bucket_block
-    buckets = int(-(-max_range // block) * block)
-    N = max(int(-(-lengths.max(initial=1) // tile) * tile), tile)
+    buckets = int(-(-max_range // BUCKET_BLOCK) * BUCKET_BLOCK)
+    N = max(int(-(-lengths.max(initial=1) // TILE) * TILE), TILE)
     ssb = np.full((S, N), buckets, np.int32)     # padding id >= buckets
     for s, row in enumerate(ss_list):
         if len(row) and (row.min() < 0 or row.max() >= max_range):
@@ -533,17 +479,9 @@ def stream_metrics_batched(ss_seq, max_range: int):
         raise ValueError("need at least one stream")
     if max_range <= 0:
         raise ValueError("max_range must be positive")
-    cfg = tuning.config_for(
-        "metrics_fused", s=len(ss_list),
-        n=max(int(max(len(s) for s in ss_list)), 1), r=max_range)
-    ssb, buckets, lengths = _metrics_padded(ss_list, max_range, cfg)
-    if on_gpu():
-        hist, mom = _gpu.stream_metrics_gpu(jnp.asarray(ssb), buckets,
-                                            bucket_block=cfg.bucket_block)
-    else:
-        hist, mom = stream_metrics_pallas(jnp.asarray(ssb), buckets,
-                                          interpret=not _on_tpu(),
-                                          config=cfg)
+    ssb, buckets, lengths = _metrics_padded(ss_list, max_range)
+    hist, mom = stream_metrics_pallas(jnp.asarray(ssb), buckets,
+                                      interpret=not on_tpu())
     return hist[:, :max_range], mom, lengths
 
 
@@ -589,22 +527,15 @@ def stream_metrics_batched_device(ss: jnp.ndarray, valid_counts,
         raise ValueError("max_range must be positive")
     S, N = ss.shape
     _check_metrics_domain(N)
-    cfg = tuning.config_for("metrics_fused", s=S, n=max(N, 1), r=max_range)
-    tile, block = cfg.record_tile, cfg.bucket_block
-    buckets = int(-(-max_range // block) * block)
+    buckets = int(-(-max_range // BUCKET_BLOCK) * BUCKET_BLOCK)
     nvalid = jnp.asarray(valid_counts, jnp.int32).reshape(S, 1)
     ssb = jnp.where(jnp.arange(N, dtype=jnp.int32)[None, :] < nvalid,
                     ss.astype(jnp.int32), buckets)   # padding id >= buckets
-    pad = (-N) % tile
+    pad = (-N) % TILE
     if pad or N == 0:
         ssb = jnp.concatenate(
-            [ssb, jnp.full((S, pad or tile), buckets, jnp.int32)], axis=1)
-    if on_gpu():
-        hist, mom = _gpu.stream_metrics_gpu(ssb, buckets, bucket_block=block)
-    else:
-        hist, mom = stream_metrics_pallas(ssb, buckets,
-                                          interpret=not _on_tpu(),
-                                          config=cfg)
+            [ssb, jnp.full((S, pad or TILE), buckets, jnp.int32)], axis=1)
+    hist, mom = stream_metrics_pallas(ssb, buckets, interpret=not on_tpu())
     return hist[:, :max_range], mom
 
 
@@ -740,18 +671,12 @@ def trend_scan_batched(qs, window: int):
         raise ValueError("need at least one count series")
     _check_trend_domain(q_list)
     lengths = np.array([len(q) for q in q_list], np.int64)
-    cfg = tuning.config_for("trend_scan", s=len(q_list),
-                            n=int(lengths.max(initial=1)))
-    tile = cfg.record_tile
-    N = max(int(-(-lengths.max(initial=1) // tile) * tile), tile)
+    N = max(int(-(-lengths.max(initial=1) // TREND_TILE) * TREND_TILE),
+            TREND_TILE)
     qb = np.zeros((len(q_list), N), np.int32)
     for s, q in enumerate(q_list):
         qb[s, :len(q)] = q
-    if on_gpu():
-        psum = _gpu.trend_scan_gpu(jnp.asarray(qb))
-    else:
-        psum = trend_scan_pallas(jnp.asarray(qb), interpret=not _on_tpu(),
-                                 config=cfg)
+    psum = trend_scan_pallas(jnp.asarray(qb), interpret=not on_tpu())
     w_eff, half = _window_tables(lengths, window)
     trend = _trend_from_prefix(psum, jnp.asarray(lengths),
                                jnp.asarray(w_eff), jnp.asarray(half))
@@ -799,18 +724,12 @@ def trend_scan_batched_device(qmat: jnp.ndarray, lengths, window: int,
                 "total count exceeds the int32 prefix-sum domain "
                 f"(limit {_TREND_TOTAL_LIMIT}); use the numpy trend path")
     S, N = qmat.shape
-    cfg = tuning.config_for("trend_scan", s=S, n=max(N, 1))
-    tile = cfg.record_tile
-    pad = (-N) % tile
+    pad = (-N) % TREND_TILE
     if pad or N == 0:
         qmat = jnp.concatenate(
             [qmat.astype(jnp.int32),
-             jnp.zeros((S, pad or tile), jnp.int32)], axis=1)
-    if on_gpu():
-        psum = _gpu.trend_scan_gpu(qmat.astype(jnp.int32))
-    else:
-        psum = trend_scan_pallas(qmat.astype(jnp.int32),
-                                 interpret=not _on_tpu(), config=cfg)
+             jnp.zeros((S, pad or TREND_TILE), jnp.int32)], axis=1)
+    psum = trend_scan_pallas(qmat.astype(jnp.int32), interpret=not on_tpu())
     w_eff, half = _window_tables(lengths, window)
     trend = _trend_from_prefix(psum, jnp.asarray(lengths),
                                jnp.asarray(w_eff), jnp.asarray(half))
@@ -838,15 +757,11 @@ def trend_pair_stats(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     if x.ndim != 2 or x.shape[0] < 1:
         raise ValueError("x must be (S, K) with S >= 1")
     k = x.shape[1]
-    cfg = tuning.config_for("pair_stats", s=x.shape[0], n=max(k, 1))
-    pair_tile = cfg.bucket_block
-    pad = (-k) % pair_tile
+    pad = (-k) % PAIR_TILE
     if pad or k == 0:
         x = jnp.concatenate(
-            [x, jnp.zeros((x.shape[0], pad or pair_tile), x.dtype)], axis=1)
-    if on_gpu():
-        return _gpu.pair_stats_gpu(x)
-    return pair_stats_pallas(x, interpret=not _on_tpu(), config=cfg)
+            [x, jnp.zeros((x.shape[0], pad or PAIR_TILE), x.dtype)], axis=1)
+    return pair_stats_pallas(x, interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("n_points",))
@@ -1200,24 +1115,17 @@ def stream_metrics_chunk(carry: ChunkCarry, ss: jnp.ndarray, valid_counts,
                          f"{carry.hist.shape[1]}-bucket axis")
     S, N = ss.shape
     _check_metrics_domain(N)
-    cfg = tuning.config_for("metrics_fused", s=S, n=max(N, 1), r=cw)
-    tile, block = cfg.record_tile, cfg.bucket_block
-    buckets = int(-(-cw // block) * block)
+    buckets = int(-(-cw // BUCKET_BLOCK) * BUCKET_BLOCK)
     nvalid = jnp.asarray(valid_counts, jnp.int32).reshape(S, 1)
     local = ss.astype(jnp.int32) - jnp.int32(lo)     # chunk-local bucket ids
     ssb = jnp.where(jnp.arange(N, dtype=jnp.int32)[None, :] < nvalid,
                     local, buckets)                  # padding id >= buckets
-    pad = (-N) % tile
+    pad = (-N) % TILE
     if pad or N == 0:
         ssb = jnp.concatenate(
-            [ssb, jnp.full((S, pad or tile), buckets, jnp.int32)], axis=1)
-    if on_gpu():
-        hist_c, mom = _gpu.stream_metrics_carry_gpu(ssb, carry.mom, buckets,
-                                                    bucket_block=block)
-    else:
-        hist_c, mom = stream_metrics_carry_pallas(ssb, carry.mom, buckets,
-                                                  interpret=not _on_tpu(),
-                                                  config=cfg)
+            [ssb, jnp.full((S, pad or TILE), buckets, jnp.int32)], axis=1)
+    hist_c, mom = stream_metrics_carry_pallas(ssb, carry.mom, buckets,
+                                              interpret=not on_tpu())
     chunk_q = hist_c[:, :cw]
     hist = jax.lax.dynamic_update_slice(carry.hist, chunk_q, (0, lo))
     psum_tail = carry.psum_tail + jnp.sum(chunk_q, axis=1, dtype=jnp.int32)
@@ -1299,20 +1207,13 @@ def trend_scan_chunk(q_chunk: jnp.ndarray, window: int, *, tail=None,
     ext = jnp.concatenate([tail, q_chunk], axis=1)        # (S, w-1+c)
     base = psum_carry - jnp.sum(tail, axis=1, dtype=jnp.int32)
     n_ext = ext.shape[1]
-    cfg = tuning.config_for("trend_scan", s=S, n=max(n_ext, 1))
-    tile = cfg.record_tile
-    pad = (-n_ext) % tile
+    pad = (-n_ext) % TREND_TILE
     if pad or n_ext == 0:
         ext_p = jnp.concatenate(
-            [ext, jnp.zeros((S, pad or tile), jnp.int32)], axis=1)
+            [ext, jnp.zeros((S, pad or TREND_TILE), jnp.int32)], axis=1)
     else:
         ext_p = ext
-    if on_gpu():
-        cinc, _ = _gpu.trend_scan_carry_gpu(ext_p, base)
-    else:
-        cinc, _ = trend_scan_carry_pallas(ext_p, base,
-                                          interpret=not _on_tpu(),
-                                          config=cfg)
+    cinc, _ = trend_scan_carry_pallas(ext_p, base, interpret=not on_tpu())
     cinc = cinc[:, :n_ext]                  # inclusive global prefix sums
 
     half = (w - 1) // 2
@@ -1354,7 +1255,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.concatenate([k, zk], axis=1)
         v = jnp.concatenate([v, zk], axis=1)
     return flash_decode_pallas(q, k, v, lengths, block_s=block_s,
-                               interpret=not _on_tpu())
+                               interpret=not on_tpu())
 
 
 __all__ = [
@@ -1362,7 +1263,7 @@ __all__ = [
     "PallasDomainError", "bucket_hist",
     "chunk_carry_finalize", "chunk_carry_init", "compact_mask",
     "compact_mask_batched_device", "flash_decode",
-    "on_accelerator", "on_gpu", "on_tpu",
+    "on_tpu",
     "stream_metrics", "stream_metrics_chunk", "trend_scan_chunk",
     "stream_metrics_batched", "stream_metrics_batched_device",
     "stream_sample", "stream_sample_batched", "stream_sample_launch",

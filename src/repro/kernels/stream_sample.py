@@ -50,18 +50,15 @@ whose (1, width) row blocks sit in SMEM.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
-
 LANE = 128
 SUBLANE = 8
-TILE = LANE * SUBLANE  # records per grid step with the default TileConfig
+TILE = LANE * SUBLANE  # records per grid step
 
 # the +-1 snap correction is only guaranteed while the f32 normalize error
 # stays under one bucket: ~4 * max_range * 2^-24 < 1
@@ -91,10 +88,9 @@ def _lookup(tab_refs, bucket, lo, hi):
 
 
 def _kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
-            keep_ref, *, sublane: int):
-    tile = sublane * LANE
+            keep_ref):
     i = pl.program_id(1)
-    t = t_ref[...]                               # (sublane, LANE) f32
+    t = t_ref[...]                               # (SUBLANE, LANE) f32
     t_min = scalar_ref[0, 0]
     inv_span = scalar_ref[0, 1]                  # 1/span, precomputed
     nb_f = scalar_ref[0, 2]                      # this row's bucket count
@@ -104,9 +100,9 @@ def _kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
     g = jnp.floor((t - t_min) * inv_span * nb_f).astype(jnp.int32)
     g = jnp.clip(g, 0, nb - 1)
 
-    base = i * tile
-    row = jax.lax.broadcasted_iota(jnp.int32, (sublane, LANE), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (sublane, LANE), 1)
+    base = i * TILE
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, LANE), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, LANE), 1)
     gidx = base + row * LANE + col               # per-stream record index
 
     # --- snap the f32 guess to the bucket that actually contains gidx ---
@@ -125,22 +121,16 @@ def _kernel(t_ref, starts_ref, counts_ref, k_ref, scalar_ref, ss_ref,
     keep_ref[...] = keep.astype(jnp.int32)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("max_range", "interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("max_range", "interpret"))
 def stream_sample_pallas(t: jnp.ndarray, starts: jnp.ndarray,
                          counts: jnp.ndarray, ktab: jnp.ndarray,
                          scalars: jnp.ndarray, max_range: int, *,
-                         interpret: bool = False,
-                         config: Optional[TileConfig] = None):
+                         interpret: bool = False):
     """Batched fused NSA inner loop (range-padded rows).
 
     t       : (S, N) float32 per-stream rebased timestamps, sorted along the
-              record axis, N % record_tile == 0 (pad tails with any finite
-              value — padded keep bits are garbage; the wrapper masks by
-              length). ``config`` picks the record tile
-              (:class:`repro.kernels.tuning.TileConfig`; ``None`` = the
-              default 1024-record tile — bit-identical to the pre-tuner
-              kernel).
+              record axis, N % TILE == 0 (pad tails with any finite value —
+              padded keep bits are garbage; the wrapper masks by length).
     starts  : (S, max_range) int32 exact per-bucket start offsets; tail
               entries past a row's ``n_buckets`` must be the record count.
     counts  : (S, max_range) int32 exact per-bucket sizes (0 past
@@ -154,26 +144,23 @@ def stream_sample_pallas(t: jnp.ndarray, starts: jnp.ndarray,
     ``n_buckets`` scalar, so rows at different time ranges batch into one
     dispatch. Returns (scale_stamp int32 (S, N), keep int32 (S, N)).
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
     S, n = t.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad records to a multiple of {cfg.record_tile}"
+    assert n % TILE == 0, f"pad records to a multiple of {TILE}"
     assert max_range <= MAX_RANGE_LIMIT, \
         f"max_range {max_range} too large for the +-1 bucket snap"
     rows = n // LANE
     t3 = t.reshape(S, rows, LANE)
-    grid = (S, rows // sublane)
+    grid = (S, rows // SUBLANE)
     # per-row tables and scalars ride in SMEM as (1, width) blocks of an
     # (S, 1, width) layout; record tiles drop the row dim
-    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0))
+    tile_spec = pl.BlockSpec((None, SUBLANE, LANE), lambda s, i: (s, i, 0))
 
     def smem_row(width):
         return pl.BlockSpec((None, 1, width), lambda s, i: (s, 0, 0),
                             memory_space=pltpu.SMEM)
 
     ss, keep = pl.pallas_call(
-        functools.partial(_kernel, sublane=sublane),
+        _kernel,
         grid=grid,
         in_specs=[tile_spec, smem_row(max_range), smem_row(max_range),
                   smem_row(max_range), smem_row(3)],

@@ -50,7 +50,6 @@ the (8, 128) record tile (``trend_scan``) or of ``PAIR_TILE`` lanes
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,12 +57,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compact import tile_prefix_sum
-from repro.kernels.tuning import DEFAULT_CONFIG, TileConfig
 
 LANE = 128
 SUBLANE = 8
-TILE = LANE * SUBLANE   # time steps per grid step (default TileConfig)
-PAIR_TILE = 4 * LANE    # time steps per pair-stats step (default config)
+TILE = LANE * SUBLANE   # time steps per grid step
+PAIR_TILE = 4 * LANE    # time steps per pair-stats step
 
 
 def _scan_kernel(init_ref, q_ref, psum_ref, tail_ref, carry_ref):
@@ -73,7 +71,7 @@ def _scan_kernel(init_ref, q_ref, psum_ref, tail_ref, carry_ref):
     def _seed():                                     # carry-IN, not a reset
         carry_ref[0] = init_ref[0, 0]
 
-    q = q_ref[...].astype(jnp.int32)                 # (sublane, LANE)
+    q = q_ref[...].astype(jnp.int32)                 # (SUBLANE, LANE)
     carry = carry_ref[0]
     psum_ref[...] = carry + tile_prefix_sum(q)
     carry_ref[0] = carry + jnp.sum(q)
@@ -83,9 +81,8 @@ def _scan_kernel(init_ref, q_ref, psum_ref, tail_ref, carry_ref):
         tail_ref[...] = jnp.full(tail_ref.shape, carry_ref[0], jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "config"))
-def trend_scan_pallas(q: jnp.ndarray, *, interpret: bool = False,
-                      config: Optional[TileConfig] = None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def trend_scan_pallas(q: jnp.ndarray, *, interpret: bool = False):
     """Batched inclusive prefix sum over stacked per-second count series.
 
     q : (S, N) int32, N % TILE == 0 (pad time tails with 0).
@@ -96,13 +93,12 @@ def trend_scan_pallas(q: jnp.ndarray, *, interpret: bool = False,
     :func:`trend_scan_carry_pallas`.
     """
     return trend_scan_carry_pallas(q, jnp.zeros(q.shape[0], jnp.int32),
-                                   interpret=interpret, config=config)[0]
+                                   interpret=interpret)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def trend_scan_carry_pallas(q: jnp.ndarray, init: jnp.ndarray, *,
-                            interpret: bool = False,
-                            config: Optional[TileConfig] = None):
+                            interpret: bool = False):
     """Chunked form of :func:`trend_scan_pallas`: the SMEM running carry is
     *seeded* from a per-row carry-in instead of reset to zero, so prefix
     sums over consecutive time chunks compose exactly.
@@ -118,15 +114,12 @@ def trend_scan_carry_pallas(q: jnp.ndarray, init: jnp.ndarray, *,
     row's new running total — the ``init`` to feed the next chunk. Exact
     while the cumulative total stays below 2³¹ (ops-wrapper guarded).
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    sublane = cfg.sublane
     S, n = q.shape
-    assert n % cfg.record_tile == 0, \
-        f"pad time steps to a multiple of {cfg.record_tile}"
+    assert n % TILE == 0, f"pad time steps to a multiple of {TILE}"
     rows = n // LANE
     q3 = q.reshape(S, rows, LANE)
-    grid = (S, rows // sublane)
-    tile_spec = pl.BlockSpec((None, sublane, LANE), lambda s, i: (s, i, 0))
+    grid = (S, rows // SUBLANE)
+    tile_spec = pl.BlockSpec((None, SUBLANE, LANE), lambda s, i: (s, i, 0))
     psum, tail = pl.pallas_call(
         _scan_kernel,
         grid=grid,
@@ -162,9 +155,8 @@ def _pair_kernel(x_ref, sums_ref, gram_ref):
     gram_ref[...] += jnp.dot(x, x.T, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "config"))
-def pair_stats_pallas(x: jnp.ndarray, *, interpret: bool = False,
-                      config: Optional[TileConfig] = None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pair_stats_pallas(x: jnp.ndarray, *, interpret: bool = False):
     """All-pairs Pearson sufficient statistics over stacked trend series.
 
     x : (S, K) float32, K % PAIR_TILE == 0 (pad time tails with 0.0 —
@@ -176,16 +168,13 @@ def pair_stats_pallas(x: jnp.ndarray, *, interpret: bool = False,
     accumulated tile-by-tile with the (sums, gram) outputs VMEM-resident
     across the time grid.
     """
-    cfg = DEFAULT_CONFIG if config is None else config
-    pair_tile = cfg.bucket_block      # the pair-stats time tile knob
     S, k = x.shape
-    assert k % pair_tile == 0, \
-        f"pad time steps to a multiple of {pair_tile}"
-    grid = (k // pair_tile,)
+    assert k % PAIR_TILE == 0, f"pad time steps to a multiple of {PAIR_TILE}"
+    grid = (k // PAIR_TILE,)
     sums, gram = pl.pallas_call(
         _pair_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((S, pair_tile), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((S, PAIR_TILE), lambda i: (0, i))],
         out_specs=[
             pl.BlockSpec((S, 1), lambda i: (0, 0)),
             pl.BlockSpec((S, S), lambda i: (0, 0)),

@@ -144,8 +144,8 @@ class Controller:
     def run(self, dataset: str, max_range: int,
             consumer: Callable[[StreamQueue], Dict], *,
             scale: float = 1.0, seed: int = 0,
-            queue_size: int = 64, backend: str = "auto",
-            autotune: Optional[str] = None) -> SimulationReport:
+            queue_size: int = 64, backend: str = "auto"
+            ) -> SimulationReport:
         """Full pipeline: POSD -> NSA -> PSDA -> consumer (the SPS task).
 
         A thin driver: the scenario becomes a one-cell
@@ -171,12 +171,6 @@ class Controller:
             bit-identical across backends; metric statistics agree within
             the documented 1e-3 tolerance; out-of-domain inputs fall back
             to numpy automatically.
-        autotune : {None, "off", "cached", "force"}, optional
-            Kernel tile-tuning mode for every device leg (see
-            :mod:`repro.kernels.tuning`). ``None``/``"off"`` keep the
-            fixed default tiles (bit-identical to prior releases);
-            ``"cached"`` reuses measured winners persisted under the
-            store; ``"force"`` re-sweeps the candidate lattice on-device.
 
         Returns
         -------
@@ -197,7 +191,7 @@ class Controller:
                           scale=scale, seed=seed, n_hosts=1, host_index=0,
                           n_devices=1)
         result = engine.execute_sweep(plan, originals, self.store,
-                                      backend=backend, autotune=autotune)
+                                      backend=backend)
         sim = result.materialize()[(dataset, max_range)]
         consumer_metrics, t_prod = engine.replay_one(sim, consumer,
                                                      queue_size)
@@ -229,8 +223,7 @@ class Controller:
                  service_poll_s: float = 0.2,
                  lease_batch: int = 1,
                  worker_id: Optional[str] = None,
-                 service_deadline_s: Optional[float] = None,
-                 autotune: Optional[str] = None
+                 service_deadline_s: Optional[float] = None
                  ) -> List[SimulationReport]:
         """The Tables 1-3 scenario sweep (datasets × time ranges), planned
         and executed by the sweep engine.
@@ -442,7 +435,7 @@ class Controller:
                 if chunk_s:
                     runner = engine.ChunkedSweepRunner(
                         plan, originals, self.store, backend=backend,
-                        checkpoint=ckpt, autotune=autotune)
+                        checkpoint=ckpt)
                     new_reports, fidelity = engine.run_sweep_chunked(
                         runner, consumer, queue_size=queue_size,
                         fidelity_window_s=fidelity_window_s, t_pre=t_pre,
@@ -452,7 +445,7 @@ class Controller:
                 else:
                     result = engine.execute_sweep(
                         plan, originals, self.store, backend=backend,
-                        checkpoint=ckpt, autotune=autotune)
+                        checkpoint=ckpt)
                     self.last_placement = result.placement()
                     new_reports, fidelity = engine.run_sweep(
                         result, consumer, queue_size=queue_size,

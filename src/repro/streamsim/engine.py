@@ -195,16 +195,12 @@ class DeviceSweepResult:
     """
 
     def __init__(self, plan: SweepPlan, originals: Dict[str, Stream],
-                 store, backend: str, mode: str,
-                 autotune: Optional[str] = None):
+                 store, backend: str, mode: str):
         self.plan = plan
         self.originals = originals
         self.store = store
         self.backend = backend
         self.mode = mode
-        #: tile-tuning mode for every deferred device leg (fidelity,
-        #: host-group metrics) — the winners persist under the store
-        self.autotune = autotune
         self.nsa_s: Dict[Tuple[str, int], float] = {}
         self.shard_results: List[ShardResult] = []
         #: cache-hit sims (host mode: ALL sims), loaded/computed on host
@@ -251,7 +247,7 @@ class DeviceSweepResult:
             [self.originals[d] for d in datasets] +
             [self.host_sims[sc] for sc in cached],
             [None] * len(datasets) + [mr for _, mr in cached],
-            backend=self.backend, autotune=self.autotune)
+            backend=self.backend)
         self._om = dict(zip(datasets, ms[:len(datasets)]))
         self._cached_sm = dict(zip(cached, ms[len(datasets):]))
 
@@ -471,7 +467,7 @@ class DeviceSweepResult:
         """
         import jax.numpy as jnp
 
-        from repro.kernels import ops, tuning
+        from repro.kernels import ops
 
         datasets = list(self.plan.datasets)
         out = []
@@ -488,8 +484,7 @@ class DeviceSweepResult:
                 matrix = trend_correlation_matrix(
                     [self.om[d].counts for d in row_ds] +
                     [self.sm[(d, mr)].counts for d in row_ds],
-                    window_s=window_s, backend=self.backend,
-                    autotune=self.autotune)
+                    window_s=window_s, backend=self.backend)
             else:
                 try:
                     om_mat, om_trs, om_totals, didx = \
@@ -508,10 +503,8 @@ class DeviceSweepResult:
                     qmat = jnp.concatenate([om_sel, qb], axis=0)
                     lengths = np.concatenate([om_trs[sel], lb])
                     totals = np.concatenate([om_totals[sel], sim_totals])
-                    with tuning.tuner_context(self.autotune,
-                                              store=self.store or None):
-                        matrix = ops.trend_correlation_batched_device(
-                            qmat, lengths, window_s, totals=totals)
+                    matrix = ops.trend_correlation_batched_device(
+                        qmat, lengths, window_s, totals=totals)
                 except ops.PallasDomainError as err:
                     ops.warn_host_fallback(f"fidelity matrix at {mr}", err)
                     matrix = trend_correlation_matrix(
@@ -572,8 +565,8 @@ class DeviceSweepResult:
 
 def execute_sweep(plan: SweepPlan, originals: Dict[str, Stream], store, *,
                   backend: str = "auto", multiple_mode: str = "time",
-                  checkpoint: Optional[SweepCheckpoint] = None,
-                  autotune: Optional[str] = None) -> DeviceSweepResult:
+                  checkpoint: Optional[SweepCheckpoint] = None
+                  ) -> DeviceSweepResult:
     """Execute a plan's NSA + metrics stages (layer 2 of the sweep).
 
     Device mode (resolved ``"pallas"``): each shard runs ONE
@@ -601,10 +594,10 @@ def execute_sweep(plan: SweepPlan, originals: Dict[str, Stream], store, *,
     result = None
     if device_ok:
         result = _execute_device(plan, originals, store, backend,
-                                 multiple_mode, autotune)
+                                 multiple_mode)
     if result is None:
         result = _execute_host(plan, originals, store, backend,
-                               multiple_mode, autotune)
+                               multiple_mode)
     result.checkpoint = checkpoint
     if checkpoint is not None and result.mode == "host" and store:
         # host mode persists its sims eagerly inside _execute_host
@@ -613,20 +606,18 @@ def execute_sweep(plan: SweepPlan, originals: Dict[str, Stream], store, *,
     return result
 
 
-def _execute_device(plan, originals, store, backend, multiple_mode,
-                    autotune=None) -> Optional[DeviceSweepResult]:
+def _execute_device(plan, originals, store, backend,
+                    multiple_mode) -> Optional[DeviceSweepResult]:
     """The pallas path; returns None when a domain error demands the
     wholesale host fallback."""
-    from repro.kernels import ops, tuning
+    from repro.kernels import ops
 
-    result = DeviceSweepResult(plan, originals, store, backend, "device",
-                               autotune=autotune)
+    result = DeviceSweepResult(plan, originals, store, backend, "device")
     try:
-        with tuning.tuner_context(autotune, store=store or None):
-            if plan.shards:
-                with obs.span("nsa.leg") as leg:
-                    result.shard_results = _run_shards(plan, originals,
-                                                       multiple_mode)
+        if plan.shards:
+            with obs.span("nsa.leg") as leg:
+                result.shard_results = _run_shards(plan, originals,
+                                                   multiple_mode)
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("sweep", err)
         return None   # out-of-domain scenario: host mode, wholesale
@@ -697,11 +688,10 @@ def _run_shards(plan, originals, multiple_mode) -> List[ShardResult]:
     return out
 
 
-def _execute_host(plan, originals, store, backend, multiple_mode,
-                  autotune=None) -> DeviceSweepResult:
+def _execute_host(plan, originals, store, backend,
+                  multiple_mode) -> DeviceSweepResult:
     """The host path — the exact pre-plan ``run_many`` composition."""
-    result = DeviceSweepResult(plan, originals, store, backend, "host",
-                               autotune=autotune)
+    result = DeviceSweepResult(plan, originals, store, backend, "host")
     with obs.span("nsa.leg") as leg:
         for spec in plan.local_missing:
             result.host_sims[spec.scenario] = nsa(
@@ -1146,8 +1136,7 @@ class ChunkedSweepRunner:
     def __init__(self, plan: SweepPlan, originals: Dict[str, Stream],
                  store, *, backend: str = "auto",
                  multiple_mode: str = "time",
-                 checkpoint: Optional[SweepCheckpoint] = None,
-                 autotune: Optional[str] = None):
+                 checkpoint: Optional[SweepCheckpoint] = None):
         if plan.chunk_s <= 0:
             raise ValueError(
                 "plan has no chunk axis — build it with plan_sweep("
@@ -1158,7 +1147,6 @@ class ChunkedSweepRunner:
         self.backend = backend
         self.multiple_mode = multiple_mode
         self.checkpoint = checkpoint
-        self.autotune = autotune
         self.chunk_s = int(plan.chunk_s)
         self._specs = {s.scenario: s for s in plan.scenarios}
         self._shard_states: List[Dict] = []
@@ -1200,8 +1188,7 @@ class ChunkedSweepRunner:
                 cn = ChunkedNSA(
                     self.originals,
                     [(s.dataset, s.span_s) for s in shard.specs],
-                    multiple_mode=self.multiple_mode, device=dev,
-                    autotune=self.autotune)
+                    multiple_mode=self.multiple_mode, device=dev)
                 self._shard_states.append({
                     "shard": shard,
                     "nsa": cn,
@@ -1224,13 +1211,10 @@ class ChunkedSweepRunner:
         closed before re-raising (the producer side unblocks instead of
         deadlocking).
         """
-        from repro.kernels import tuning
         try:
-            with tuning.tuner_context(self.autotune,
-                                      store=self.store or None):
-                if self.mode == "device":
-                    return self._run_device(feeds)
-                return self._run_host(feeds)
+            if self.mode == "device":
+                return self._run_device(feeds)
+            return self._run_host(feeds)
         except BaseException:
             if feeds:
                 for f in feeds.values():

@@ -195,8 +195,7 @@ def volatility(stream: Stream, time_range: Optional[int] = None,
 def metrics_batched(streams: Sequence[Stream],
                     time_ranges: Sequence[Optional[int]],
                     *, use_scale_stamps: Optional[Sequence[Optional[bool]]]
-                    = None, backend: str = "auto",
-                    autotune: Optional[str] = None) -> List[StreamMetrics]:
+                    = None, backend: str = "auto") -> List[StreamMetrics]:
     """Counts + volatility for S streams from ONE batched engine call.
 
     Parameters
@@ -238,11 +237,10 @@ def metrics_batched(streams: Sequence[Stream],
     max_tr = max((tr for _, tr in series), default=0)
     if resolved != "pallas" or max_tr == 0 or not series:
         return [_numpy_metrics(b, tr) for b, tr in series]
-    from repro.kernels import ops, tuning
+    from repro.kernels import ops
     try:
-        with tuning.tuner_context(autotune):
-            hist, mom, _ = ops.stream_metrics_batched(
-                [b for b, _ in series], max_tr)
+        hist, mom, _ = ops.stream_metrics_batched([b for b, _ in series],
+                                                  max_tr)
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("metrics_batched", err)
         return [_numpy_metrics(b, tr) for b, tr in series]
@@ -410,8 +408,7 @@ def _corr_matrix_numpy(counts: Sequence[np.ndarray], window_s: int,
 def trend_correlation_matrix(counts: Sequence[np.ndarray],
                              window_s: int = 60, *,
                              n_points: Optional[int] = None,
-                             backend: str = "auto",
-                             autotune: Optional[str] = None) -> np.ndarray:
+                             backend: str = "auto") -> np.ndarray:
     """Pearson trend-correlation matrix for ALL S×S count-series pairs.
 
     The batched form of the Fig.-6 fidelity check: every series' sliding-
@@ -454,11 +451,9 @@ def trend_correlation_matrix(counts: Sequence[np.ndarray],
         raise ValueError("window_s must be >= 1")
     counts = [np.asarray(q).reshape(-1) for q in counts]
     if _resolve_backend(backend) == "pallas" and counts:
-        from repro.kernels import ops, tuning
+        from repro.kernels import ops
         try:
-            with tuning.tuner_context(autotune):
-                return ops.trend_correlation_batched(counts, window_s,
-                                                     n_points)
+            return ops.trend_correlation_batched(counts, window_s, n_points)
         except ops.PallasDomainError as err:
             ops.warn_host_fallback("trend_correlation_matrix", err)
     return _corr_matrix_numpy(counts, window_s, n_points)

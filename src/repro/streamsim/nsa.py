@@ -81,8 +81,8 @@ def _resolve_backend(backend: str) -> str:
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     if backend == "auto":
-        from repro.kernels.ops import on_accelerator
-        return "pallas" if on_accelerator() else "numpy"
+        from repro.kernels.ops import on_tpu
+        return "pallas" if on_tpu() else "numpy"
     return backend
 
 
@@ -144,8 +144,7 @@ def systematic_keep_mask(ss: np.ndarray, max_range: int, multiple: float,
 
 
 def nsa(stream: Stream, max_range: int, *, keep: str = "systematic",
-        multiple_mode: str = "time", backend: str = "numpy",
-        autotune: Optional[str] = None) -> Stream:
+        multiple_mode: str = "time", backend: str = "numpy") -> Stream:
     """Vectorized NSA (Algorithm 1): normalize + sample -> simulated stream Ds.
 
     Parameters
@@ -166,13 +165,7 @@ def nsa(stream: Stream, max_range: int, *, keep: str = "systematic",
     backend : {"numpy", "pallas", "auto"}
         ``"pallas"`` runs normalize → keep-mask → compaction → gather
         device-resident (two fused Pallas dispatches + one XLA scatter);
-        ``"auto"`` picks pallas on any real accelerator (TPU or GPU),
-        numpy otherwise.
-    autotune : {"off", "cached", "force"}, optional
-        Tile-tuning mode for the device dispatches
-        (:mod:`repro.kernels.tuning`); ``None``/``"off"`` keeps the
-        bit-for-bit heuristic defaults. Winners here stay in-memory —
-        persistence needs a store (the engine/controller layers').
+        ``"auto"`` picks pallas on the TPU, numpy otherwise.
 
     Returns
     -------
@@ -202,10 +195,9 @@ def nsa(stream: Stream, max_range: int, *, keep: str = "systematic",
     m = _multiple(len(stream), stream.time_range, max_range, multiple_mode)
     if (_resolve_backend(backend) == "pallas" and keep == "systematic"
             and len(stream) > 0):
-        from repro.kernels import ops, tuning
+        from repro.kernels import ops
         try:
-            with tuning.tuner_context(autotune):
-                return _nsa_pallas(stream, max_range, m)
+            return _nsa_pallas(stream, max_range, m)
         except ops.PallasDomainError as err:
             ops.warn_host_fallback(f"nsa({stream.name!r}, {max_range})",
                                    err)
@@ -254,8 +246,8 @@ def _compact_gather(stream: Stream, ss_dev, keep_dev) -> Stream:
 
 
 def nsa_batched(streams: Dict[str, Stream], max_range: int, *,
-                multiple_mode: str = "time", backend: str = "auto",
-                autotune: Optional[str] = None) -> Dict[str, Stream]:
+                multiple_mode: str = "time", backend: str = "auto"
+                ) -> Dict[str, Stream]:
     """NSA over many concurrent device streams — the IoT-realistic shape.
 
     Parameters
@@ -297,19 +289,18 @@ def nsa_batched(streams: Dict[str, Stream], max_range: int, *,
         return {name: nsa(s, max_range, multiple_mode=multiple_mode,
                           backend="numpy")
                 for name, s in streams.items()}
-    from repro.kernels import ops, tuning
+    from repro.kernels import ops
 
     names = list(streams)
     ts = [streams[n].t for n in names]
     mults = [_multiple(len(streams[n]), streams[n].time_range, max_range,
                        multiple_mode) for n in names]
     try:
-        with tuning.tuner_context(autotune):
-            ss_b, keep_b, lengths = ops.stream_sample_batched(
-                ts, max_range, mults)
-            return {name: _compact_gather(streams[name], ss_b[s],
-                                          keep_b[s, :lengths[s]])
-                    for s, name in enumerate(names)}
+        ss_b, keep_b, lengths = ops.stream_sample_batched(ts, max_range,
+                                                          mults)
+        return {name: _compact_gather(streams[name], ss_b[s],
+                                      keep_b[s, :lengths[s]])
+                for s, name in enumerate(names)}
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("nsa_batched", err)
         return {name: nsa(s, max_range, multiple_mode=multiple_mode,
@@ -319,8 +310,7 @@ def nsa_batched(streams: Dict[str, Stream], max_range: int, *,
 
 def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
               pairs: Optional[Sequence[Tuple[str, int]]] = None,
-              multiple_mode: str = "time", backend: str = "auto",
-              autotune: Optional[str] = None
+              multiple_mode: str = "time", backend: str = "auto"
               ) -> Dict[Tuple[str, int], Stream]:
     """NSA over the full (stream × max_range) scenario grid — ONE dispatch.
 
@@ -390,7 +380,7 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
     from repro.kernels import ops
     try:
         ss_b, idx_b, totals, _ = nsa_sweep_device(
-            streams, pairs, multiple_mode=multiple_mode, autotune=autotune)
+            streams, pairs, multiple_mode=multiple_mode)
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("nsa_sweep", err)
         return _host()
@@ -400,8 +390,7 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
 
 def nsa_sweep_device(streams: Dict[str, Stream],
                      pairs: Sequence[Tuple[str, int]], *,
-                     multiple_mode: str = "time", device=None,
-                     autotune: Optional[str] = None):
+                     multiple_mode: str = "time", device=None):
     """The device leg of the range-padded sweep — NO host gather.
 
     Dispatches ONE ``stream_sample`` launch plus ONE batched compaction
@@ -439,15 +428,14 @@ def nsa_sweep_device(streams: Dict[str, Stream],
         When any scenario falls outside the kernels' exactness domain —
         callers fall back to the numpy path wholesale.
     """
-    from repro.kernels import ops, tuning
+    from repro.kernels import ops
 
     ts = [streams[name].t for name, _ in pairs]
     mults = [_multiple(len(streams[name]), streams[name].time_range, mr,
                        multiple_mode) for name, mr in pairs]
-    with tuning.tuner_context(autotune):
-        ss_b, keep_b, lengths = ops.stream_sample_batched(
-            ts, [mr for _, mr in pairs], mults, device=device)
-        idx_b, totals = ops.compact_mask_batched_device(keep_b)
+    ss_b, keep_b, lengths = ops.stream_sample_batched(
+        ts, [mr for _, mr in pairs], mults, device=device)
+    idx_b, totals = ops.compact_mask_batched_device(keep_b)
     return ss_b, idx_b, totals, lengths
 
 
@@ -539,13 +527,10 @@ class ChunkedNSA:
 
     def __init__(self, streams: Dict[str, Stream],
                  pairs: Sequence[Tuple[str, int]], *,
-                 multiple_mode: str = "time", device=None,
-                 autotune: Optional[str] = None):
+                 multiple_mode: str = "time", device=None):
         import jax
         import jax.numpy as jnp
         from repro.kernels import ops
-
-        self.autotune = autotune
 
         self.pairs = [(name, int(rng)) for name, rng in pairs]
         if not self.pairs:
@@ -600,7 +585,7 @@ class ChunkedNSA:
         :func:`materialize_sweep_chunk` one pipeline step later.
         """
         import jax.numpy as jnp
-        from repro.kernels import ops, tuning
+        from repro.kernels import ops
 
         lo, hi = int(lo), int(hi)
         if not 0 <= lo < hi <= self.width:
@@ -609,22 +594,19 @@ class ChunkedNSA:
         a = self._starts_np[:, lo]
         b = self.lengths if hi >= self.width else self._starts_np[:, hi]
         m = b - a
-        with tuning.tuner_context(self.autotune):
-            cfg = tuning.config_for("stream_sample", s=len(self.pairs),
-                                    n=max(int(m.max()), 1), r=self.width)
-            tile = cfg.record_tile
-            Nc = max(int(-(-max(int(m.max()), 1) // tile) * tile), tile)
-            a_dev = self._dev(a.astype(np.int32))
-            # rebase the bucket tables by the slice offset: local rank ==
-            # global rank, so the keep bits match the monolithic launch
-            starts_reb = self._starts - a_dev[:, None]
-            ss, keep = ops.stream_sample_launch(
-                ops.slice_records(self._t, a_dev, Nc), starts_reb,
-                self._counts, self._ktab, self._scal, self.width, cfg)
-            j = jnp.arange(Nc, dtype=jnp.int32)[None, :]
-            keep = keep.astype(bool) & \
-                (j < self._dev(m.astype(np.int32))[:, None])
-            idx, totals = ops.compact_mask_batched_device(keep)
+        tile = ops.TILE
+        Nc = max(int(-(-max(int(m.max()), 1) // tile) * tile), tile)
+        a_dev = self._dev(a.astype(np.int32))
+        # rebase the bucket tables by the slice offset: local rank ==
+        # global rank, so the keep bits match the monolithic launch
+        starts_reb = self._starts - a_dev[:, None]
+        ss, keep = ops.stream_sample_launch(
+            ops.slice_records(self._t, a_dev, Nc), starts_reb,
+            self._counts, self._ktab, self._scal, self.width)
+        j = jnp.arange(Nc, dtype=jnp.int32)[None, :]
+        keep = keep.astype(bool) & \
+            (j < self._dev(m.astype(np.int32))[:, None])
+        idx, totals = ops.compact_mask_batched_device(keep)
         # only the first ``kept`` columns of each row hold kept records
         kept = int((self._kept_cum[:, hi] - self._kept_cum[:, lo]).max())
         idx = idx[:, :max(int(-(-kept // tile) * tile), tile)]

@@ -18,7 +18,8 @@ def _sorted_times(n, span, seed, dtype=np.float64):
 
 
 class TestStreamSample:
-    @pytest.mark.parametrize("n", [64, 1024, 4096, 10_000])
+    @pytest.mark.parametrize("n", [64, TILE - 1, 1024, TILE + 1, 4096,
+                                   10_000])
     @pytest.mark.parametrize("max_range", [16, 128, 600])
     def test_matches_oracle(self, n, max_range):
         t = _sorted_times(n, 86_400.0, seed=n + max_range)
@@ -82,6 +83,26 @@ class TestStreamSample:
         np.testing.assert_array_equal(
             np.asarray(keep), systematic_keep_mask(np.zeros(n, np.int64),
                                                    600, 144.0))
+
+    def test_smem_table_budget_binds_only_for_the_tpu(self, monkeypatch):
+        from repro.kernels.stream_sample import MAX_TABLE_WIDTH
+        t = np.arange(100, dtype=np.float64)
+        wide = MAX_TABLE_WIDTH + 1
+        assert len(ops._nsa_tables(t, wide, 2.0)[0]) == wide
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        with pytest.raises(ops.PallasDomainError, match="SMEM"):
+            ops._nsa_tables(t, wide, 2.0)
+        assert len(ops._nsa_tables(t, MAX_TABLE_WIDTH, 2.0)[0]) == \
+            MAX_TABLE_WIDTH
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_backend_auto_resolves_to_pallas_iff_the_tpu(tpu, monkeypatch):
+    # the kernels compile only for the TPU; elsewhere "auto" keeps to the
+    # host path (interpret mode is correct but slower than numpy)
+    from repro.streamsim.nsa import _resolve_backend
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    assert _resolve_backend("auto") == ("pallas" if tpu else "numpy")
 
 
 def _starts_by_searchsorted(t64, max_range, width):
@@ -175,6 +196,23 @@ class TestStreamSampleBatched:
         with pytest.raises(ValueError):
             ops.stream_sample_batched([np.zeros(0)], 10, 1.0)
 
+    def test_range_padded_kernel_matches_ref(self):
+        # rows at different max_range in one launch, tables padded to the
+        # widest: the kernel against its pure-jnp oracle, bit for bit
+        from repro.kernels.stream_sample import stream_sample_pallas
+        rng = np.random.default_rng(29)
+        ts = [np.sort(rng.uniform(0, 900.0, 2 * TILE)) for _ in range(2)]
+        width = 600
+        tables = [ops._nsa_tables(t, r, 3.0, width)
+                  for t, r in zip(ts, (600, 240))]
+        args = [jnp.asarray(np.stack([ops._rebase(t) for t in ts]))] + \
+            [jnp.asarray(np.stack(col)) for col in zip(*tables)]
+        args[4] = args[4].astype(jnp.float32)
+        ss_k, keep_k = stream_sample_pallas(*args, width, interpret=True)
+        ss_r, keep_r = ref.stream_sample_ref(*args, width)
+        np.testing.assert_array_equal(np.asarray(ss_k), np.asarray(ss_r))
+        np.testing.assert_array_equal(np.asarray(keep_k), np.asarray(keep_r))
+
     @pytest.mark.parametrize("path", ["batched", "chunked"])
     def test_rows_sharing_an_array_match_copies(self, path):
         # rows holding one array object share its rebase and upload; rows
@@ -221,8 +259,9 @@ class TestStreamSampleBatched:
 
 
 class TestCompact:
-    @pytest.mark.parametrize("n", [1, 100, TILE, 4 * TILE, 10_000])
-    @pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
+    @pytest.mark.parametrize("n", [1, 100, TILE - 1, TILE, TILE + 1,
+                                   4 * TILE, 10_000])
+    @pytest.mark.parametrize("density", [0.0, 0.3, 0.5, 1.0])
     def test_matches_oracle_and_nonzero(self, n, density):
         rng = np.random.default_rng(n + int(density * 7))
         mask = (rng.random(n) < density)
@@ -282,6 +321,16 @@ class TestCompactBatched:
             assert np.all(np.asarray(idx_b[r, totals[r]:]) == n), \
                 "sentinel tail"
 
+    def test_kernel_positions_match_cumsum(self):
+        from repro.kernels.compact import compact_positions_batched_pallas
+        mask = (np.random.default_rng(29).random((5, 2 * TILE)) < 0.35) \
+            .astype(np.int32)
+        pos, tot = compact_positions_batched_pallas(jnp.asarray(mask),
+                                                    interpret=True)
+        incl = np.cumsum(mask, axis=1)
+        np.testing.assert_array_equal(np.asarray(pos), incl - mask)
+        np.testing.assert_array_equal(np.asarray(tot).ravel(), incl[:, -1])
+
     def test_carry_resets_between_rows(self):
         # identical all-kept rows: a leaking carry would shift row 1's
         # positions by row 0's total
@@ -303,7 +352,8 @@ class TestStreamMetrics:
 
     @pytest.mark.parametrize("n,max_range", [(1, 16), (512, 16), (4096, 128),
                                              (20_000, 600), (1024, 3600),
-                                             (4096, 86_400)])
+                                             (4096, 86_400), (TILE - 1, 600),
+                                             (TILE + 1, 600)])
     def test_matches_oracle(self, n, max_range):
         rng = np.random.default_rng(n + max_range)
         ss = np.sort(rng.integers(0, max_range, n)).astype(np.int32)
@@ -315,6 +365,56 @@ class TestStreamMetrics:
         q = np.asarray(h_o, np.float64)
         np.testing.assert_allclose(np.asarray(m_k),
                                    [q.sum(), (q * q).sum()], rtol=1e-5)
+
+    def test_kernel_batched_matches_ref(self):
+        # several rows in one dispatch: counts bit-exact, moments within
+        # the Kahan fold's 1e-5 of the reference
+        from repro.kernels.metrics_fused import stream_metrics_pallas
+        ss = np.sort(np.random.default_rng(29).integers(0, 1500, (4, 2048)),
+                     axis=1).astype(np.int32)
+        h_k, m_k = stream_metrics_pallas(jnp.asarray(ss), 1536,
+                                         interpret=True)
+        h_r, m_r = ref.stream_metrics_ref(jnp.asarray(ss), 1536)
+        np.testing.assert_array_equal(np.asarray(h_k), np.asarray(h_r))
+        np.testing.assert_allclose(np.asarray(m_k), np.asarray(m_r),
+                                   rtol=1e-5, atol=1e-2)
+
+    def test_kernel_padding_ids_count_nowhere(self):
+        from repro.kernels.metrics_fused import stream_metrics_pallas
+        ss = np.full((2, TILE), 10_000, np.int32)        # all padding ids
+        ss[0, :5] = [0, 1, 1, 2, 511]
+        h, m = stream_metrics_pallas(jnp.asarray(ss), 512, interpret=True)
+        h = np.asarray(h)
+        assert h[0].sum() == 5 and h[1].sum() == 0
+        assert h[0][1] == 2
+        np.testing.assert_array_equal(np.asarray(m)[1], [0.0, 0.0])
+
+    def test_carry_kernel_composes_across_chunks(self):
+        # zero carry-in: the plain kernel's output, bit for bit; two
+        # chained chunks: the running [Σq, Σq²] of both histograms
+        from repro.kernels.metrics_fused import (stream_metrics_carry_pallas,
+                                                 stream_metrics_pallas)
+        rng = np.random.default_rng(29)
+        a, b = (np.sort(rng.integers(0, 1024, (3, TILE)), axis=1)
+                .astype(np.int32) for _ in range(2))
+        zero = jnp.zeros((3, 4), jnp.float32)
+        h1, c1 = stream_metrics_carry_pallas(jnp.asarray(a), zero, 1024,
+                                             interpret=True)
+        h2, c2 = stream_metrics_carry_pallas(jnp.asarray(b), c1, 1024,
+                                             interpret=True)
+        h0, m0 = stream_metrics_pallas(jnp.asarray(a), 1024, interpret=True)
+        np.testing.assert_array_equal(np.asarray(h1), np.asarray(h0))
+        np.testing.assert_array_equal(np.asarray(c1)[:, ::2],
+                                      np.asarray(m0))
+        for h, x in ((h1, a), (h2, b)):
+            np.testing.assert_array_equal(
+                np.asarray(h), [np.bincount(r, minlength=1024) for r in x])
+        q = np.concatenate([np.asarray(h1), np.asarray(h2)], axis=1) \
+            .astype(np.float64)
+        np.testing.assert_allclose(
+            np.asarray(c2)[:, ::2],
+            np.stack([q.sum(axis=1), (q * q).sum(axis=1)], axis=1),
+            rtol=1e-6)
 
     def test_unsorted_input_still_exact(self):
         # sortedness only narrows the kernel's data-adaptive bucket-block
@@ -379,7 +479,9 @@ class TestStreamMetrics:
 
 class TestBucketHist:
     @pytest.mark.parametrize("n,max_range", [(512, 16), (4096, 128),
-                                             (20_000, 600), (1024, 3600)])
+                                             (20_000, 600), (1024, 3600),
+                                             (TILE - 1, 128),
+                                             (TILE + 1, 3600)])
     def test_matches_oracle(self, n, max_range):
         rng = np.random.default_rng(n)
         ss = np.sort(rng.integers(0, max_range, n)).astype(np.int32)

@@ -180,53 +180,3 @@ class TestEventDetectProperties:
                             threshold=threshold)(_queue_of(shuffled))
         assert sorted(a["task_events"]) == sorted(b["task_events"])
 
-
-class TestTileChooserProperties:
-    """The autotuner's heuristic chooser must emit only configs the
-    kernels can actually dispatch: lane-aligned bucket blocks that
-    divide the padded bucket axis, sublane-aligned record tiles, and a
-    bounded VMEM footprint — for every shape and device kind."""
-
-    @settings(max_examples=120, deadline=None)
-    @given(s=st.integers(1, 512),
-           n=st.integers(1, 1 << 22),
-           r=st.integers(0, 1 << 20),
-           kind=st.sampled_from(["cpu-interpret", "tpu-v4", "tpu-v5e",
-                                 "gpu-a100", "gpu-h100", "gpu-mi300x"]),
-           kernel=st.sampled_from(["stream_sample", "metrics_fused",
-                                   "trend_scan", "pair_stats", "compact"]))
-    def test_heuristic_config_invariants(self, s, n, r, kind, kernel):
-        from repro.kernels import tuning
-        key = tuning.TuneKey.from_shape(kernel, s=s, n=n, r=r)
-        cfg = tuning.heuristic_config(key, kind)
-        # record tile: positive (sublane, LANE) multiple
-        assert cfg.record_tile > 0
-        assert cfg.record_tile % tuning.MIN_RECORD_TILE == 0
-        assert cfg.sublane % 8 == 0
-        # bucket block: lane multiple that divides the padded bucket
-        # axis (ops pads the axis to a bucket_block multiple, so this
-        # is exactly "padded % block == 0")
-        assert cfg.bucket_block % tuning.LANE == 0
-        if r > 0:
-            padded = -(-r // cfg.bucket_block) * cfg.bucket_block
-            assert padded % cfg.bucket_block == 0
-            assert padded >= r
-        # VMEM bound: the one-hot (record_tile, bucket_block) i32 tile
-        # fits the budget
-        assert cfg.record_tile * cfg.bucket_block * 4 \
-            <= tuning.VMEM_BUDGET_BYTES
-
-    @settings(max_examples=60, deadline=None)
-    @given(s=st.integers(1, 64), n=st.integers(1, 1 << 20),
-           r=st.integers(0, 1 << 18), kind=st.sampled_from(
-               ["cpu-interpret", "tpu-v4", "gpu-a100"]))
-    def test_candidate_lattice_all_dispatchable(self, s, n, r, kind):
-        from repro.kernels import tuning
-        key = tuning.TuneKey.from_shape("metrics_fused", s=s, n=n, r=r)
-        cands = tuning.candidate_lattice(key, kind)
-        assert cands, "lattice always contains the heuristic default"
-        assert len(set(cands)) == len(cands), "no duplicate candidates"
-        for cfg in cands:
-            assert cfg.record_tile % tuning.MIN_RECORD_TILE == 0
-            assert cfg.bucket_block % tuning.LANE == 0
-            assert cfg.vmem_bytes() <= tuning.VMEM_BUDGET_BYTES

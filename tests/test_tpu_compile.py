@@ -5,9 +5,12 @@ accepts layouts and ops the TPU compiler (Mosaic) refuses. These tests lower
 and compile each kernel with ``interpret=False`` for a v5e chip that is only
 described, never attached, at the shapes ``chip_smoke.py`` dispatches: the
 paper's full-day Tables 1-3 sweep of 18 scenario rows, up to ~10.5 M
-records per row and 3600-entry bucket tables. Nothing runs; a refused
-layout, an unsupported op or a program that overflows the chip's memory
-fails here instead of on the chip.
+records per row and 3600-entry bucket tables. The kernels' tiles are fixed
+module constants, so the same kernels are compiled again at the shapes of
+the other benchmark cells: each shard of the four-chip grid, and the widest
+chunk of the multi-day stream. Nothing runs; a refused layout, an
+unsupported op or a program that overflows the chip's memory fails here
+instead of on the chip.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler library.
@@ -46,6 +49,25 @@ BUCKETS = 4096
 DAY = 87_040
 #: v5e high-bandwidth memory per chip
 HBM_BYTES = 16 * 1024 ** 3
+#: the four-chip grid's shard layouts (bench/configs/paper_t123_day_4chip
+#: .json, planned by plan_sweep over 4 devices): three shards of 2
+#: UserBehavior rows, the widest kept width at max_range 3600, and one of
+#: the 12 SogouQ and Traffic rows; (rows, padded records, kept width)
+SHARDS = {"4chip_ub": (2, 10_580_992, 441_344),
+          "4chip_small": (12, 2_213_888, 93_184)}
+#: the multi-day stream's widest chunk (bench/configs/taobao_ub_stream.json:
+#: 6 rows over 2 days in 600 s chunks), as ChunkedNSA.chunk dispatches it:
+#: the records it launches, its kept width and its bucket-table width
+#: (2 days at max_range 3600); the same for every seed
+CHUNK_ROWS = 6
+CHUNK_RECORDS = 10_637_312
+CHUNK_KEPT = 113_664
+CHUNK_TABLE = 7_200
+#: one 600 s chunk's histogram width, padded to the 512-bucket block
+CHUNK_BUCKETS = 1024
+#: the trend carry's window: the report's 59-bucket tail plus the 600 s
+#: chunk, padded to the 1024-step tile
+CHUNK_TREND = 1024
 
 
 @pytest.fixture(scope="module")
@@ -104,6 +126,39 @@ def _cases():
         "trend_scan_carry": (trend_scan_carry_pallas,
                              [((ROWS, BUCKETS), i32), ((ROWS,), i32)]),
         "pair_stats": (pair_stats_pallas, [((2 * 3, BUCKETS), f32)]),
+        "stream_sample_chunk": (
+            functools.partial(stream_sample_pallas, max_range=CHUNK_TABLE),
+            [((CHUNK_ROWS, CHUNK_RECORDS), f32)] +
+            [((CHUNK_ROWS, CHUNK_TABLE), i32)] * 3 +
+            [((CHUNK_ROWS, 3), f32)]),
+        "compact_batched_chunk": (compact_positions_batched_pallas,
+                                  [((CHUNK_ROWS, CHUNK_RECORDS), i32)]),
+        "stream_metrics_carry_chunk": (
+            functools.partial(stream_metrics_carry_pallas,
+                              buckets=CHUNK_BUCKETS),
+            [((CHUNK_ROWS, CHUNK_KEPT), i32), ((CHUNK_ROWS, 4), f32)]),
+        "trend_scan_carry_chunk": (
+            trend_scan_carry_pallas,
+            [((CHUNK_ROWS, CHUNK_TREND), i32), ((CHUNK_ROWS,), i32)]),
+        **{f"{kernel}_{name}": case
+           for name, (rows, records, kept) in SHARDS.items()
+           for kernel, case in _shard_cases(rows, records, kept).items()},
+    }
+
+
+def _shard_cases(rows, records, kept):
+    """The grid's NSA and metrics kernels at one shard's layout."""
+    i32, f32 = jnp.int32, jnp.float32
+    return {
+        "stream_sample": (
+            functools.partial(stream_sample_pallas, max_range=WIDTH),
+            [((rows, records), f32)] + [((rows, WIDTH), i32)] * 3 +
+            [((rows, 3), f32)]),
+        "compact_batched": (compact_positions_batched_pallas,
+                            [((rows, records), i32)]),
+        "stream_metrics": (
+            functools.partial(stream_metrics_pallas, buckets=BUCKETS),
+            [((rows, kept), i32)]),
     }
 
 

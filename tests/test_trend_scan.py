@@ -14,6 +14,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.trend_scan import (PAIR_TILE, TILE, pair_stats_pallas,
+                                      trend_scan_carry_pallas,
                                       trend_scan_pallas)
 from repro.streamsim import Controller, trend_correlation_matrix
 from repro.streamsim.metrics import (_corr_matrix_numpy, sliding_mean,
@@ -25,7 +26,8 @@ def _counts(n, seed=0, lam=25.0):
 
 
 class TestScanKernel:
-    @pytest.mark.parametrize("S,tiles", [(1, 1), (1, 3), (4, 2), (7, 1)])
+    @pytest.mark.parametrize("S,tiles", [(1, 1), (1, 3), (4, 2), (7, 1),
+                                         (6, 2)])
     def test_prefix_sum_bit_exact_vs_oracle(self, S, tiles):
         rng = np.random.default_rng(S * 10 + tiles)
         q = rng.integers(0, 1000, (S, tiles * TILE)).astype(np.int32)
@@ -43,7 +45,21 @@ class TestScanKernel:
         got = np.asarray(trend_scan_pallas(jnp.asarray(q), interpret=True))
         np.testing.assert_array_equal(got[1], np.arange(1, 2 * TILE + 1))
 
-    @pytest.mark.parametrize("S,k_tiles", [(1, 1), (2, 2), (5, 3)])
+    def test_carry_in_seeds_the_scan(self):
+        # each row's scan starts from its carry-in; the tail is the new
+        # running total, the next chunk's carry-in
+        import jax.numpy as jnp
+        rng = np.random.default_rng(29)
+        q = rng.integers(0, 9, (4, TILE)).astype(np.int32)
+        init = rng.integers(0, 1000, 4).astype(np.int32)
+        psum, tail = trend_scan_carry_pallas(jnp.asarray(q),
+                                             jnp.asarray(init),
+                                             interpret=True)
+        exp = init[:, None] + np.cumsum(q, axis=1)
+        np.testing.assert_array_equal(np.asarray(psum), exp)
+        np.testing.assert_array_equal(np.asarray(tail), exp[:, -1])
+
+    @pytest.mark.parametrize("S,k_tiles", [(1, 1), (2, 2), (5, 3), (5, 4)])
     def test_pair_stats_vs_oracle(self, S, k_tiles):
         rng = np.random.default_rng(S + k_tiles)
         x = rng.normal(0, 3, (S, k_tiles * PAIR_TILE)).astype(np.float32)
