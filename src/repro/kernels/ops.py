@@ -423,6 +423,15 @@ def gather_kept(ss: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.take_along_axis(ss, jnp.clip(idx, 0, max(N - 1, 0)), axis=1)
 
 
+def kept_width(totals, n: int) -> int:
+    """Columns of a compacted ``(R, n)`` index plane that hold any kept
+    record: the largest of the host ``totals`` rounded up to ``TILE``,
+    within ``[TILE, n]``. Rounding keeps the gather and the metrics
+    compiling once per tile count, not once per total."""
+    k = int(np.max(totals, initial=0))
+    return min(max(-(-k // TILE) * TILE, TILE), n)
+
+
 # -------------------------------------------------------- metrics engine
 # int32 histogram accumulation: exact while every bucket count < 2**31
 # (the seed's f32 one-hot kernel silently rounded past 2**24)

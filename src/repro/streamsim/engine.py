@@ -170,16 +170,17 @@ class ShardResult:
     """One shard's device-resident NSA + metrics output.
 
     ``idx`` is the :func:`~repro.streamsim.nsa.nsa_sweep_device` kept
-    indices and ``ss_kept`` the kept stamps gathered by them (both still
-    on the shard's device); ``hist`` is the fused metrics engine's
-    per-second count matrix, also device-resident. Only ``totals`` and
-    ``mom`` — O(rows) report scalars — live on host.
+    indices cut to the kept width ``K`` (:func:`repro.kernels.ops.
+    kept_width` of ``totals``) and ``ss_kept`` the kept stamps gathered
+    by them (both still on the shard's device); ``hist`` is the fused
+    metrics engine's per-second count matrix, also device-resident. Only
+    ``totals`` and ``mom`` — O(rows) report scalars — live on host.
     """
 
     shard: Shard
     pairs: Tuple[Tuple[str, int], ...]
-    ss_kept: object          # (R, N) int32 device
-    idx: object              # (R, N) int32 device
+    ss_kept: object          # (R, K) int32 device, K = kept width
+    idx: object              # (R, K) int32 device, K = kept width
     totals: np.ndarray       # (R,) int64 host
     hist: object             # (R, max_range) int32 device
     mom: np.ndarray          # (R, 2) float64 host
@@ -643,8 +644,9 @@ def _run_shards(plan, originals, multiple_mode) -> List[ShardResult]:
     1. per shard: host tables, uploads, sampling and compaction (its
        totals stay on the device), so shard ``k+1``'s host tables run
        while shard ``k``'s device works;
-    2. every shard's kept totals in one read, then each shard's kept-stamp
-       gather and its metrics on the kept-width column slice;
+    2. every shard's kept totals in one read, then each shard's index
+       plane cut to its kept width, the kept-stamp gather by that cut and
+       the metrics on the gathered stamps;
     3. every shard's moments in one read.
 
     With one shard this is the single chain's own order.
@@ -664,23 +666,24 @@ def _run_shards(plan, originals, multiple_mode) -> List[ShardResult]:
         obs.count("nsa.shard_rows", len(pairs))
         obs.count("nsa.padded_cells", len(pairs) * ss.shape[1])
         stamps.append(ss)
-        # ss_kept, totals, hist and mom are filled in below
+        # idx is cut, and ss_kept, totals, hist and mom filled in, below
         out.append(ShardResult(shard=shard, pairs=pairs, ss_kept=None,
                                idx=idx, totals=totals, hist=None, mom=None))
+    del ss, idx     # only the lists hold the full planes, until the cut
     with obs.span("nsa.totals_wait"):
         totals = jax.device_get([sr.totals for sr in out])
-    for sr, ss, t in zip(out, stamps, totals):
+    for sr, t in zip(out, totals):
         sr.totals = np.asarray(t, np.int64).reshape(-1)
-        sr.ss_kept = ops.gather_kept(ss, sr.idx)
-        # compaction packed every row's kept stamps to the front, so the
-        # metrics dispatch only needs the kept-width column slice (device
-        # slice — kept counts are far below the padded source width after
-        # compression)
-        n_kept = int(-(-max(int(sr.totals.max(initial=1)), 1)
-                       // ops.TILE) * ops.TILE)
+        # compaction packed every row's kept indices to the front, so the
+        # gather, the metrics and the host download only need the
+        # kept-width column slice (kept counts are far below the padded
+        # source width after compression); the full planes die here
+        k = ops.kept_width(sr.totals, sr.idx.shape[1])
+        sr.idx = sr.idx[:, :k]
+        sr.ss_kept = ops.gather_kept(stamps.pop(0), sr.idx)
+        obs.count("nsa.gather_cells", len(sr.pairs) * k)
         sr.hist, sr.mom = ops.stream_metrics_batched_device(
-            sr.ss_kept[:, :min(n_kept, sr.ss_kept.shape[1])], sr.totals,
-            sr.shard.max_range)
+            sr.ss_kept, sr.totals, sr.shard.max_range)
     with obs.span("nsa.device_wait"):
         moms = jax.device_get([sr.mom for sr in out])
     for sr, m in zip(out, moms):

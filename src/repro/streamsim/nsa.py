@@ -384,8 +384,10 @@ def nsa_sweep(streams: Dict[str, Stream], max_ranges: Sequence[int], *,
     except ops.PallasDomainError as err:
         ops.warn_host_fallback("nsa_sweep", err)
         return _host()
+    totals = np.asarray(totals, np.int64)
+    idx_b = idx_b[:, :ops.kept_width(totals, idx_b.shape[1])]
     return materialize_sweep(streams, pairs, ops.gather_kept(ss_b, idx_b),
-                             idx_b, np.asarray(totals, np.int64))
+                             idx_b, totals)
 
 
 def nsa_sweep_device(streams: Dict[str, Stream],
@@ -397,10 +399,11 @@ def nsa_sweep_device(streams: Dict[str, Stream],
     for the given scenario rows and returns device-resident handles
     without waiting for the device, so a caller (the sweep engine) can
     dispatch every shard's chain before reading any of them back. The
-    caller then takes the kept scale stamps with
-    :func:`repro.kernels.ops.gather_kept` and chains them straight into
-    the fused metrics engine; the payload gather is deferred to
-    :func:`materialize_sweep`.
+    caller then reads ``totals``, cuts ``idx`` to the kept width
+    (:func:`repro.kernels.ops.kept_width`), gathers the kept scale stamps
+    by that cut with :func:`repro.kernels.ops.gather_kept` and chains
+    them straight into the fused metrics engine; the payload gather is
+    deferred to :func:`materialize_sweep`.
 
     Parameters
     ----------
@@ -417,7 +420,8 @@ def nsa_sweep_device(streams: Dict[str, Stream],
         ``ss`` int32 ``(R, N)`` device — every record's scale stamp
         (``gather_kept(ss, idx)`` puts row ``r``'s kept stamps in its
         first ``totals[r]`` entries). ``idx`` int32 ``(R, N)`` device —
-        kept-record indices, sentinel ``N`` past each row's total.
+        kept-record indices, sentinel ``N`` past each row's total, so
+        only its first ``kept_width(totals, N)`` columns hold any.
         ``totals`` int32 ``(R,)`` device (the O(R) kept counts; reading
         them waits for the compaction); ``lengths`` int64 ``(R,)`` host
         source lengths.

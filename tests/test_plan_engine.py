@@ -298,6 +298,69 @@ class TestShardedEngine:
             assert all(f"/sim{fr.max_range}" in lb
                        for lb in fr.labels[D:])
 
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_gather_is_cut_to_the_kept_width(self, tmp_path, n_devices):
+        # each shard keeps only its first K = TILE-rounded largest total
+        # columns of the index plane, gathers the stamps by that cut, and
+        # still yields the numpy path's streams and the full-width
+        # gather's histograms and moments
+        from repro.kernels import ops
+        from repro.streamsim import engine, nsa
+        from repro.streamsim.nsa import nsa_sweep_device
+        from repro.streamsim.store import StreamStore
+
+        originals = _kept_width_streams()
+        store = StreamStore(str(tmp_path / "store"))
+        plan = plan_sweep(store, list(originals), [60, 600, 3600],
+                          {k: len(v) for k, v in originals.items()},
+                          n_devices=n_devices, host_index=0, n_hosts=1)
+        assert len(plan.shards) == n_devices
+        result = engine.execute_sweep(plan, originals, store,
+                                      backend="pallas")
+        assert result.mode == "device"
+        widest_not_first = False
+        for sr in result.shard_results:
+            ss, idx, totals, _ = nsa_sweep_device(originals, sr.pairs)
+            np.testing.assert_array_equal(sr.totals, np.asarray(totals))
+            K = -(-int(sr.totals.max()) // ops.TILE) * ops.TILE
+            assert sr.idx.shape == sr.ss_kept.shape == (len(sr.pairs), K)
+            assert K < idx.shape[1]
+            widest_not_first |= int(np.argmax(sr.totals)) != 0
+            # what a gather over the whole plane fed the metrics
+            hist, mom = ops.stream_metrics_batched_device(
+                ops.gather_kept(ss, idx)[:, :K], sr.totals,
+                sr.shard.max_range)
+            np.testing.assert_array_equal(np.asarray(sr.hist),
+                                          np.asarray(hist))
+            np.testing.assert_array_equal(
+                sr.mom, np.asarray(mom, np.float64))
+        assert widest_not_first
+        _assert_streams_match_numpy(originals, result.materialize())
+
+    def test_nsa_sweep_gathers_the_kept_width(self, monkeypatch):
+        # the public one-dispatch sweep cuts its index plane the same way
+        from repro.kernels import ops
+        from repro.streamsim.nsa import nsa_sweep
+
+        nsa_mod = sys.modules[nsa_sweep.__module__]
+        originals = _kept_width_streams()
+        seen = []
+        real = nsa_mod.materialize_sweep
+
+        def recording(streams, pairs, ss_kept, idx_b, totals):
+            seen.append((ss_kept.shape, idx_b.shape, totals))
+            return real(streams, pairs, ss_kept, idx_b, totals)
+
+        monkeypatch.setattr(nsa_mod, "materialize_sweep", recording)
+        sims = nsa_sweep(originals, [60, 600, 3600], backend="pallas")
+        [(ss_shape, idx_shape, totals)] = seen
+        K = -(-int(totals.max()) // ops.TILE) * ops.TILE
+        N = -(-max(map(len, originals.values())) // ops.TILE) * ops.TILE
+        assert ss_shape == idx_shape == (len(sims), K)
+        assert K < N
+        assert int(np.argmax(totals)) != 0
+        _assert_streams_match_numpy(originals, sims)
+
     def test_materialize_persists_after_earlier_peek(self, tmp_path):
         # materialize(store=False) then materialize() must still persist
         from repro.streamsim import engine
@@ -350,12 +413,42 @@ class TestShardedEngine:
         assert parents["nsa.tables"] == "nsa.shard"
         counts = rec.counts()
         assert counts["nsa.shard_rows"] == 6
+        # the kernels' plane: each shard's longest source, TILE-padded
+        from repro.kernels.ops import TILE
         assert counts["nsa.padded_cells"] == sum(
+            len(sr.pairs) * -(-max(len(originals[d]) for d, _ in sr.pairs)
+                              // TILE) * TILE
+            for sr in result.shard_results)
+        # the gather and the download touch only the kept width
+        assert counts["nsa.gather_cells"] == sum(
             len(sr.pairs) * sr.ss_kept.shape[1]
             for sr in result.shard_results)
+        assert counts["nsa.gather_cells"] < counts["nsa.padded_cells"]
         # every co-simulated scenario reports the one leg's seconds
         leg_s = rec.spans()["nsa.leg"]
         assert all(result.nsa_s[sc] == leg_s for sc in result.nsa_s)
+
+
+def _kept_width_streams():
+    """Three day-long sources of different lengths: swept at 60, 600 and
+    3600 s their kept counts differ by row, and the largest stays far
+    below the longest source."""
+    return {d: preprocess(make_stream(d, scale=0.002, seed=3))
+            for d in ("sogouq", "traffic", "userbehavior")}
+
+
+def _assert_streams_match_numpy(originals, sims):
+    from repro.streamsim import nsa
+
+    assert sims
+    for (name, mr), sim in sims.items():
+        ref = nsa(originals[name], mr, backend="numpy")
+        for got, want in [(sim.t, ref.t),
+                          (sim.scale_stamp, ref.scale_stamp)] + \
+                [(sim.payload[k], v) for k, v in ref.payload.items()]:
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+        assert sim.payload.keys() == ref.payload.keys()
 
 
 # ----------------------------------------------------------- replay errors
